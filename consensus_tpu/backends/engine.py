@@ -4,9 +4,8 @@ table backed by a paged KV cache.
 The legacy :class:`~consensus_tpu.backends.batching.BatchingBackend` model
 is flush-snapshot: worker calls queue until EVERY active session blocks (or
 a quiescence window expires), then one merged batch dispatches and the
-cycle restarts.  That barrier is the dominant throughput loss BENCH_r05's
-``mfu_accounting`` names — rows pad to the widest bucket, and the device
-idles between flushes while stragglers finish host work.
+cycle restarts.  At that barrier rows pad to the widest bucket, and the
+device idles between flushes while stragglers finish host work.
 
 This engine replaces the barrier with ITERATION-LEVEL batching (Orca, Yu
 et al., OSDI '22): a persistent loop over a fixed table of ``n_slots``
@@ -562,11 +561,17 @@ class DecodeEngine:
             self._search_sessions += 1
             self._search_slots += spec.n_slots
         orig_close = session.close
+        tracked = True
 
         def close():
+            # Sessions close more than once (explicitly, then again from
+            # ``__del__``); only the first call leaves the pressure surface.
+            nonlocal tracked
             with self._lock:
-                self._search_sessions -= 1
-                self._search_slots -= spec.n_slots
+                if tracked:
+                    tracked = False
+                    self._search_sessions -= 1
+                    self._search_slots -= spec.n_slots
             orig_close()
 
         session.close = close

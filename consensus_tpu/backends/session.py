@@ -441,12 +441,24 @@ def open_token_search(backend, spec: SearchSpec):
     implementation at all — the full-prefix fallback runs over ``backend``
     ITSELF, so e.g. a batching wrapper keeps merging the fallback's calls
     through its queue."""
+    from consensus_tpu.obs.metrics import get_registry
+
+    opened = get_registry().counter(
+        "token_search_sessions_total",
+        "Token-search sessions opened, by implementation: fused (persistent "
+        "device caches, one program per step) or prefix (the full-prefix "
+        "fallback, taken when a backend has no fused session or declines).",
+        labels=("kind",),
+    )
     maker = getattr(backend, "open_fused_token_search", None)
     if maker is not None:
         try:
-            return maker(spec)
+            session = maker(spec)
+            opened.labels("fused").inc()
+            return session
         except FusedSessionUnavailable:
             pass
+    opened.labels("prefix").inc()
     session = PrefixTokenSearchSession(backend, spec)
     # Continuous-batching seam: over an engine-mode batching adapter the
     # fallback's per-step calls already land in the engine's iteration loop

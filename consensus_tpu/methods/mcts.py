@@ -47,7 +47,7 @@ sequence — golden-pinned in tests/test_token_decoders.py); sweep configs set
 
 Observability (docs/ARCHITECTURE.md §Observability): per-backend counters
 ``mcts_device_dispatches_total`` / ``mcts_statements_total`` (dispatches per
-statement = the de-RTT headline), ``mcts_wave_selections_total``, the
+statement is the headline), ``mcts_wave_selections_total``, the
 ``mcts_wave_width`` histogram, and ``mcts_virtual_loss_collisions_total``
 (duplicate-leaf selections that produced no fresh child — the price of
 batching selections before their rewards land).

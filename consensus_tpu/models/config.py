@@ -54,6 +54,12 @@ class ModelConfig:
     # Use the pallas fused decode-attention kernel in the session step's
     # trunk-tail path (ops/decode_attention.py) instead of the einsum pair.
     use_decode_attention: bool = False
+    # Ids at or above this are never sampled or proposed: the serving
+    # tokenizer cannot decode them (models/sampling.py:ban_undecodable).
+    # The backend derives it from its tokenizer; None = the whole
+    # vocabulary.  Teacher-forced scoring ignores it and keeps the
+    # full-vocabulary logsumexp.
+    sample_vocab: Optional[int] = None
 
     @property
     def q_scale(self) -> float:
@@ -145,10 +151,12 @@ MODEL_CONFIGS = {
         ffn_hidden=14336,
         rope_scaling=(8.0, 1.0, 4.0, 8192),
     ),
-    # Tiny variants for tests / CPU smoke runs.
+    # Tiny variants for tests / CPU smoke runs.  Their vocabulary is the
+    # byte tokenizer's 268 rows (models/tokenizer.py), the only tokenizer
+    # they are ever served with.
     "tiny-gemma2": _gemma2(
         "tiny-gemma2",
-        vocab_size=512,
+        vocab_size=268,
         d_model=64,
         n_layers=4,
         n_heads=4,
@@ -160,7 +168,7 @@ MODEL_CONFIGS = {
     ),
     "tiny-llama3": _llama3(
         "tiny-llama3",
-        vocab_size=512,
+        vocab_size=268,
         d_model=64,
         n_layers=2,
         n_heads=4,

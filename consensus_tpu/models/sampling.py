@@ -15,6 +15,19 @@ import jax
 import jax.numpy as jnp
 
 
+def ban_undecodable(logits: jax.Array, config) -> jax.Array:
+    """``-inf`` at the ids the serving tokenizer cannot decode
+    (``config.sample_vocab`` and above), so that no sampler or proposer
+    ever picks one.  Traces to nothing when the whole vocabulary is
+    decodable.  Only sampling sites call this: teacher-forced scoring
+    normalizes over the full vocabulary."""
+    n = config.sample_vocab
+    if n is None or n >= logits.shape[-1]:
+        return logits
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return jnp.where(ids < n, logits, -jnp.inf)
+
+
 def _top_k_filter(logits: jax.Array, k: int) -> jax.Array:
     if k <= 0 or k >= logits.shape[-1]:
         return logits

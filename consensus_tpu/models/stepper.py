@@ -57,7 +57,7 @@ from consensus_tpu.models.transformer import (
     apply_rope,
     _softcap,
 )
-from consensus_tpu.models.sampling import sample_tokens
+from consensus_tpu.models.sampling import ban_undecodable, sample_tokens
 from consensus_tpu.ops.decode_attention import paged_attention
 from consensus_tpu.ops.welfare import (
     DEFAULT_REWARD,
@@ -99,7 +99,7 @@ def _propose_and_score(
 ) -> jax.Array:
     logits = project_logits(params, config, hidden_last)  # (Rows, V) f32
     per_beam = logits.reshape(n_beams, n_roles, -1)
-    ref_logits = per_beam[:, 0, :]  # (B, V)
+    ref_logits = ban_undecodable(per_beam[:, 0, :], config)  # (B, V)
     if ref_bias is not None:
         ref_logits = ref_logits + ref_bias[None, :]
     ref_lp = jax.nn.log_softmax(ref_logits, axis=-1)
@@ -360,10 +360,11 @@ def rollout_scored(
         logits_last, cache_t, pos, done = carry
         lp = jax.nn.log_softmax(logits_last.astype(jnp.float32), axis=-1)
         key = jax.random.fold_in(rollout_key, t)
+        ref_lp = ban_undecodable(lp[0], config)
         sampled = jax.random.categorical(
-            key, lp[0] / jnp.maximum(temperature, 1e-6)
+            key, ref_lp / jnp.maximum(temperature, 1e-6)
         )
-        token = jnp.where(temperature <= 0.0, jnp.argmax(lp[0]), sampled)
+        token = jnp.where(temperature <= 0.0, jnp.argmax(ref_lp), sampled)
         is_eos = (
             jnp.any(token == eos_ids)
             if eos_ids.shape[0]
@@ -474,7 +475,7 @@ def rollout_scored_many(
             logits.reshape(n_paths, n_roles, -1).astype(jnp.float32), axis=-1
         )
         keys = jax.vmap(lambda kk: jax.random.fold_in(kk, t))(rollout_keys)
-        ref_lp = lp[:, 0, :]
+        ref_lp = ban_undecodable(lp[:, 0, :], config)
         sampled = jax.vmap(jax.random.categorical)(
             keys, ref_lp / jnp.maximum(temperature, 1e-6)
         )
@@ -598,7 +599,7 @@ def rollout_verify_many(
             jnp.arange(depth)
         )
     )(rollout_keys)  # (P, depth, 2)
-    ref_lp = lp[:, 0, :, :]  # (P, depth, V)
+    ref_lp = ban_undecodable(lp[:, 0, :, :], config)  # (P, depth, V)
     sampled = jax.vmap(jax.vmap(jax.random.categorical))(
         keys, ref_lp / jnp.maximum(temperature, 1e-6)
     )
@@ -958,7 +959,8 @@ def paged_decode_steps(
         pairs = jax.vmap(jax.random.split)(keys)
         keys, sub = pairs[:, 0], pairs[:, 1]
         token = sample_tokens(
-            sub, logits, temperature=temperature, top_k=top_k, top_p=top_p,
+            sub, ban_undecodable(logits, config),
+            temperature=temperature, top_k=top_k, top_p=top_p,
             logit_bias=logit_bias,
             presence=pres, rep_penalty=rep_penalty if use_rp else None,
         )
@@ -1159,7 +1161,8 @@ def paged_verify_steps(
         pairs = jax.vmap(jax.random.split)(keys)
         keys = jnp.where(real[:, None], pairs[:, 0], keys)
         token = sample_tokens(
-            pairs[:, 1], logits_t, temperature=temperature, top_k=top_k,
+            pairs[:, 1], ban_undecodable(logits_t, config),
+            temperature=temperature, top_k=top_k,
             top_p=top_p, logit_bias=logit_bias,
             presence=pres, rep_penalty=rep_penalty if use_rp else None,
         )

@@ -423,8 +423,8 @@ def forward_trunk_tail(
     Beam-search slots all contain the identical prompt prefix — replicating
     it per (slot x role) row (5+ GB for a wide beam on a 2B model) is pure
     waste, and gathering those replicas on every beam reorder doubles peak
-    HBM when buffer donation isn't honored (the remote-compile OOM this
-    function exists to fix).  Here the prefix lives ONCE per role and
+    HBM whenever the gather is not done in place.  Here the prefix lives
+    ONCE per role and
     broadcasts against all slots inside the attention einsum; only the
     <=max_steps-column per-row TAIL (the generated tokens) is slot-local
     state.  Tail columns <= ``write_col`` are visible (the current token
@@ -433,10 +433,9 @@ def forward_trunk_tail(
     ``frozen_*``: optional read-only KV blocks holding tokens the row
     generated in EARLIER decode segments (models/generate.py's segmented
     decode), one block per frozen segment, in chronological order.  The
-    live tail rides the while_loop carry, which the remote AOT compiler
-    double-buffers — copying the full (Rows, Ts) tail every step dominates
-    long decodes (measured 44 ms/step at 64x768 vs a ~6 ms roofline,
-    scripts/decode_step_bench.py).  Frozen blocks are plain operands: read
+    live tail rides the while_loop carry, state the compiler may copy every
+    step (scripts/decode_step_bench.py times it; not measured on this
+    toolchain).  Frozen blocks are plain operands: read
     once per step by attention, never copied, never concatenated (the
     per-block list replaces round 3's single concatenated block, whose
     append transient dominated the segmented HBM row allowance), and always

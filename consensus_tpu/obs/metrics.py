@@ -49,7 +49,7 @@ def exponential_buckets(start: float, factor: float, count: int) -> Tuple[float,
 
 
 #: 100 µs .. ~52 s in powers of two — covers a fused-step dispatch through
-#: a cold remote compile.
+#: a cold compile.
 DEFAULT_TIME_BUCKETS = exponential_buckets(1e-4, 2.0, 20)
 #: 1 .. 2048 in powers of two — batch fills, rows, merged request counts.
 DEFAULT_COUNT_BUCKETS = exponential_buckets(1.0, 2.0, 12)

@@ -261,6 +261,7 @@ def decode_attention(
             pltpu.VMEM((qp_pad, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(scalars, qr, kf, vf)
 
     # (R·KV, P·reps, hd) -> (Rows, H, hd)

@@ -201,6 +201,7 @@ def flash_attention(
             pltpu.VMEM((block_q, head_dim), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(lens, offs, qf, kf, vf)
 
     out = out.reshape(batch, heads, padded, head_dim).transpose(0, 2, 1, 3)

@@ -26,18 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 exposes shard_map at top level with the replication check
-# renamed check_vma; older jax carries it in jax.experimental with
-# check_rep.  Same semantics either way (the check stays off: the ring
-# accumulator is deliberately unreplicated).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KWARGS = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KWARGS = {"check_rep": False}
-
 SEQ_AXIS = "sequence"
 
 _NEG_INF = -1e30
@@ -141,12 +129,14 @@ def ring_self_attention(
         _ring_attention_local, axis_name=axis_name, scale=scale,
         causal=causal, n_shards=int(mesh.shape[axis_name]),
     )
-    sharded = _shard_map(
+    # The replication check stays off: the ring accumulator is deliberately
+    # unreplicated.
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_2d, spec_2d, spec_2d, spec_2d),
         out_specs=spec_qkv,
-        **_CHECK_KWARGS,
+        check_vma=False,
     )
     return sharded(q, k, v, positions, positions, valid, valid)
 

@@ -207,12 +207,17 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGINT, handle_signal)
     signal.signal(signal.SIGTERM, handle_signal)
 
+    from consensus_tpu.serve.http_frontend import backend_device_info
+
     server.start()
     print(json.dumps({
         "serving": server.base_url,
         "endpoints": ["POST /v1/consensus", "GET /healthz", "GET /metrics",
                       "GET /v1/trace/<request_id>", "GET /v1/slo"],
         "backend": args.backend,
+        # What the backend's programs run on, as JAX reports it (null for
+        # backends that run none).
+        "device": backend_device_info(server.scheduler.inner_backend),
         "max_queue_depth": args.max_queue_depth,
         "max_inflight": args.max_inflight,
         "brownout": args.brownout or args.target_p95_ms is not None,

@@ -92,6 +92,18 @@ def _mint_request_id(payload: Any) -> str:
     return f"srv-{next(_MINT_SEQ):06d}-{digest}"
 
 
+def backend_device_info(backend: Any) -> Optional[Dict[str, Any]]:
+    """The device a model backend's programs run on (platform, device kind,
+    count), found under any fault-injection or supervision wrappers; None
+    for backends that run no device programs (fake, api)."""
+    while backend is not None:
+        info = getattr(backend, "device_info", None)
+        if info is not None:
+            return dict(info)
+        backend = getattr(backend, "inner", None)
+    return None
+
+
 class ConsensusHTTPServer(ThreadingHTTPServer):
     """ThreadingHTTPServer carrying the scheduler + registry for handlers."""
 
@@ -337,6 +349,9 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
             "model": getattr(inner, "model_name", ""),
             "alive": stats["workers_alive"] > 0,
         }
+        device = backend_device_info(inner)
+        if device is not None:
+            stats["backend"]["device"] = device
         engine = self.server.slo_engine
         if engine is not None:
             engine.evaluate()

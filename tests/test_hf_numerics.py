@@ -40,7 +40,7 @@ def _save_hf_model(model, tmp_path):
 
 def _hf_tiny_gemma2():
     cfg = transformers.Gemma2Config(
-        vocab_size=512,
+        vocab_size=268,
         hidden_size=64,
         intermediate_size=128,
         num_hidden_layers=4,
@@ -67,7 +67,7 @@ def _hf_tiny_gemma2():
 
 def _hf_tiny_llama3(rope_scaling=None):
     cfg = transformers.LlamaConfig(
-        vocab_size=512,
+        vocab_size=268,
         hidden_size=64,
         intermediate_size=128,
         num_hidden_layers=2,

@@ -158,7 +158,7 @@ def _hf_tiny_gemma2_long():
     """tiny-gemma2's exact structure, but with a 1024-position window —
     the reference prompt templates alone are ~500 byte-tokens."""
     cfg = transformers.Gemma2Config(
-        vocab_size=512,
+        vocab_size=268,
         hidden_size=64,
         intermediate_size=128,
         num_hidden_layers=4,
@@ -190,8 +190,8 @@ def stacks(tmp_path_factory):
     model = _hf_tiny_gemma2_long()
     ckpt = _save_hf_model(model, tmp_path_factory.mktemp("ckpt"))
     torch_backend = TorchRefBackend(model)
-    # vocab 512 (checkpoint) exceeds the byte tokenizer's id range, so both
-    # stacks index the same rows of the same embedding matrix.
+    # The checkpoint has the preset's 268 rows, the byte tokenizer's id
+    # range: both stacks index the same rows of the same embedding matrix.
     jax_backend = TPUBackend(
         model="tiny-gemma2", checkpoint=ckpt, dtype="float32", max_context=1024
     )
