@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from consensus_tpu.backends.base import (
     BackendLostError,
@@ -70,7 +70,9 @@ from consensus_tpu.obs.metrics import (
 from consensus_tpu.obs.trace import (
     IterationLedger,
     get_flight_recorder,
+    span,
     trace_current,
+    use_trace,
 )
 from consensus_tpu.ops.kv_pages import BlockTable, PagePool, PrefixCache
 
@@ -375,21 +377,6 @@ class DecodeEngine:
             "Seconds since the decode engine's iteration loop last proved "
             "liveness (sampled by the watchdog monitor thread).",
         )
-        self._m_mfu_device = reg.gauge(
-            "engine_mfu_device_fraction",
-            "Fraction of engine wall time spent inside inner-backend device "
-            "dispatches (iteration-ledger aggregate).",
-        )
-        self._m_mfu_host = reg.gauge(
-            "engine_mfu_host_fraction",
-            "Fraction of engine wall time spent in host-side iteration "
-            "bookkeeping (sweep/admit/prefill/cohort/merge/other) — the "
-            "per-iteration host round-trip loss.",
-        )
-        self._m_mfu_idle = reg.gauge(
-            "engine_mfu_idle_fraction",
-            "Fraction of engine wall time spent idle between iterations.",
-        )
         self._m_tokens_dispatch = reg.histogram(
             "engine_tokens_per_dispatch",
             "Generated tokens returned by one device dispatch (one K-step "
@@ -506,7 +493,11 @@ class DecodeEngine:
             item.trace = trace
             item.span = trace.begin(
                 f"engine_{kind}", parent=parent, rows=len(item.requests))
-        with self._work:
+        # Tokenising every row's prompt for the page accounting happens
+        # under the condition the loop waits on: the span says how long.
+        with use_trace(item.trace, item.span), span(
+            "engine.enqueue", kind=kind, rows=len(item.requests)
+        ), self._work:
             if self._stopped:
                 raise RuntimeError("decode engine is closed")
             if kind == "generate":
@@ -528,6 +519,13 @@ class DecodeEngine:
         if item.error is not None:
             raise item.error
         return item.result
+
+    @staticmethod
+    def _item_traces(items) -> List[Tuple[Any, int]]:
+        """(trace, span) of every traced call among ``items``, each once:
+        what one merged dispatch writes its span into."""
+        seen = {id(item): item for item in items if item.trace is not None}
+        return [(item.trace, item.span) for item in seen.values()]
 
     @staticmethod
     def _trace_row_event(row: _Row, name: str, **attrs: Any) -> None:
@@ -690,7 +688,8 @@ class DecodeEngine:
         while True:
             with self._work:
                 while not self._stopped and not self._has_work():
-                    self._work.wait()
+                    with span("engine.idle"):
+                        self._work.wait()
                 if self._stopped:
                     self._fail_all(RuntimeError("decode engine closed"))
                     return
@@ -733,6 +732,10 @@ class DecodeEngine:
     def run_iteration(self) -> None:
         """One scheduler iteration.  Public so tests can step the engine
         deterministically (construct with ``auto_start=False``)."""
+        with span("engine.iteration"):
+            self._iterate()
+
+    def _iterate(self) -> None:
         self._heartbeat = time.monotonic()
         t_start = time.perf_counter()
         idle_s = (
@@ -749,11 +752,14 @@ class DecodeEngine:
             t0 = time.perf_counter()
             self._process_cancellations()
             t1 = time.perf_counter()
-            self._admit()
+            with span("engine.admit"):
+                self._admit()
             t2 = time.perf_counter()
-            self._advance_prefill()
+            with span("engine.prefill"):
+                self._advance_prefill()
             t3 = time.perf_counter()
-            cohort = self._decode_cohort()
+            with span("engine.cohort"):
+                cohort = self._decode_cohort()
             t4 = time.perf_counter()
             occupied = sum(1 for s in self._slots if s is not None)
             occ = occupied / self.n_slots
@@ -823,13 +829,11 @@ class DecodeEngine:
             )
             self._last_iter_end = t_end
             get_flight_recorder().record_iteration(row)
-            mfu = self.ledger.mfu_attribution()
-            self._m_mfu_device.set(mfu["device_fraction"])
-            self._m_mfu_host.set(mfu["host_fraction"])
-            self._m_mfu_idle.set(mfu["idle_fraction"])
-            if mfu["tokens"]:
+            # (The ledger's shares are computed where they are read:
+            # stats()["mfu_attribution"], which /healthz serves.)
+            if self.decoded_tokens:
                 self._m_host_iter_per_token.set(
-                    self.iterations / mfu["tokens"]
+                    self.iterations / self.decoded_tokens
                 )
 
     def _watchdog_loop(self) -> None:
@@ -1032,7 +1036,10 @@ class DecodeEngine:
         batch_error: Optional[BaseException] = None
         t_dev = time.perf_counter()
         try:
-            results = self.inner.generate(requests)
+            with span("engine.dispatch", traces=self._item_traces(
+                slot.row.item for slot in cohort
+            ), kind="generate", rows=len(requests)):
+                results = self.inner.generate(requests)
         except PartialBatchError as exc:
             results = list(exc.results)
             row_errors = dict(exc.row_errors)
@@ -1044,7 +1051,7 @@ class DecodeEngine:
         self._iter_block_s += time.perf_counter() - t_dev
 
         t_merge = time.perf_counter()
-        with self._lock:
+        with span("engine.merge"), self._lock:
             tokens = 0
             for i, slot in enumerate(cohort):
                 self._retire(slot)
@@ -1088,16 +1095,21 @@ class DecodeEngine:
                 decode_steps=self.decode_steps)
         t_disp = time.perf_counter()
         try:
-            if self.speculative:
-                stream = self.inner.generate_stream(
-                    requests, decode_steps=self.decode_steps,
-                    speculative=True,
-                )
-            else:
-                stream = self.inner.generate_stream(
-                    requests, decode_steps=self.decode_steps
-                )
-            stream.dispatch()
+            # The stream's prefill and first window; later windows are one
+            # a token or a few, and stay out of the requests' span trees.
+            with span("engine.dispatch", traces=self._item_traces(
+                slot.row.item for slot in cohort
+            ), kind="generate_stream", rows=len(requests)):
+                if self.speculative:
+                    stream = self.inner.generate_stream(
+                        requests, decode_steps=self.decode_steps,
+                        speculative=True,
+                    )
+                else:
+                    stream = self.inner.generate_stream(
+                        requests, decode_steps=self.decode_steps
+                    )
+                stream.dispatch()
         except Exception as exc:
             self._iter_dispatch_s += time.perf_counter() - t_disp
             if isinstance(exc, BackendLostError):
@@ -1143,7 +1155,7 @@ class DecodeEngine:
         self._iter_spec_accepted += spec_accepted - seen_a
 
         t_merge = time.perf_counter()
-        with self._lock:
+        with span("engine.merge"), self._lock:
             tokens = sum(row_tokens)
             self._iter_tokens += tokens
             self._m_tokens_iter.observe(tokens)
@@ -1238,15 +1250,12 @@ class DecodeEngine:
         if kind == "score_matrix":
             reserved = self._reserve_matrix_pages(merged)
         self.dispatch_counts[kind] += 1
-        for item in items:
-            if item.trace is not None:
-                item.trace.event(
-                    item.span, "engine_dispatch", kind=kind,
-                    batch=len(dispatch))
         try:
             t_dev = time.perf_counter()
             try:
-                results = fn(dispatch)
+                with span("engine.dispatch", traces=self._item_traces(items),
+                          kind=kind, rows=len(dispatch)):
+                    results = fn(dispatch)
             finally:
                 self._iter_block_s += time.perf_counter() - t_dev
             if mapping is not None:

@@ -52,6 +52,7 @@ from consensus_tpu.backends.base import (
 )
 from consensus_tpu.models.config import ModelConfig, get_model_config
 from consensus_tpu.obs.backends import BackendInstruments
+from consensus_tpu.obs.trace import span
 from consensus_tpu.models.generate import generate_tokens, next_token_topk
 from consensus_tpu.models.tokenizer import get_tokenizer
 from consensus_tpu.models.transformer import (
@@ -542,7 +543,8 @@ class TPUBackend:
         ``data``.  Rows that don't divide dp (sessions with odd role counts)
         stay uncommitted — jit replicates them, still correct.  Single-device
         backends pass through."""
-        with self.instruments.time_h2d():
+        with span("backend.h2d", arrays=len(arrays)), \
+                self.instruments.time_h2d():
             if self._dp > 1 and all(a.shape[0] % self._dp == 0 for a in arrays):
                 from consensus_tpu.parallel.mesh import shard_batch
 
@@ -552,14 +554,17 @@ class TPUBackend:
 
     def _fetch(self, *arrays):
         """np.asarray with D2H timing.  Under async dispatch the fetch
-        blocks on device work still in flight, so this reading is an upper
-        bound that includes device execution, not pure transfer.  Arrays
-        already on host (the segmented decode loop returns numpy) pass
-        through without polluting the histogram with zero samples."""
+        blocks on device work still in flight, so this reading (and the
+        ``backend.d2h`` span) is an upper bound that includes device
+        execution, not pure transfer: time inside it is the host waiting
+        for the device.  Arrays already on host (the segmented decode loop
+        returns numpy) pass through without polluting the histogram with
+        zero samples."""
         if all(isinstance(a, np.ndarray) for a in arrays):
             out = arrays
         else:
-            with self.instruments.time_d2h():
+            with span("backend.d2h", arrays=len(arrays)), \
+                    self.instruments.time_d2h():
                 out = tuple(np.asarray(a) for a in arrays)
         return out if len(arrays) > 1 else out[0]
 
@@ -814,10 +819,11 @@ class TPUBackend:
             return []
 
         if token_lists is None:
-            token_lists = [
-                self.tokenizer.encode(self._render_prompt(r), add_bos=True)
-                for r in requests
-            ]
+            with span("backend.tokenize", rows=len(requests)):
+                token_lists = [
+                    self.tokenizer.encode(self._render_prompt(r), add_bos=True)
+                    for r in requests
+                ]
         if self.shared_trunk_generation:
             groups: Dict[Tuple[int, ...], List[int]] = {}
             for i, ids in enumerate(token_lists):
@@ -925,52 +931,54 @@ class TPUBackend:
                 )
             return out
 
-        self.call_counts["generate"] += len(requests)
-        (target, pad_rows, temperatures, bias_table, bias_index, keys,
-         eos_ids, rep_penalty) = self._prep_generation_rows(requests, allowed)
-        self.instruments.record_padding(
-            "generate_trunk", 1, width, len(prompt_ids)
-        )
-        self.instruments.record_launch(
-            "generate_shared",
-            (target, width, max_new, int(segmented), int(bias_table is not None)),
-        )
-
-        pad = self.tokenizer.pad_id
-        tokens = np.full((1, width), pad, np.int32)
-        valid = np.zeros((1, width), bool)
-        tokens[0, width - len(prompt_ids):] = prompt_ids
-        valid[0, width - len(prompt_ids):] = True
-
-        # Bucket-pad rows start done (they'd otherwise sample real tokens
-        # from the real prompt and pin the early exit at the full budget).
-        init_done = np.zeros((target,), bool)
-        init_done[len(requests):] = True
-        kwargs = dict(
-            max_new_tokens=max_new,
-            temperature=temperatures,
-            eos_ids=jnp.asarray(eos_ids, jnp.int32),
-            bias_table=bias_table,
-            bias_index=bias_index,
-            pad_id=self.tokenizer.pad_id,
-            init_done=jnp.asarray(init_done),
-        )
-        if rep_penalty is not None:
-            kwargs["rep_penalty"] = rep_penalty
-        if segmented:
-            from consensus_tpu.models.generate import (
-                generate_tokens_shared_trunk_segmented as fn,
+        with span("backend.layout", rows=len(requests), width=width):
+            self.call_counts["generate"] += len(requests)
+            (target, pad_rows, temperatures, bias_table, bias_index, keys,
+             eos_ids, rep_penalty) = self._prep_generation_rows(requests, allowed)
+            self.instruments.record_padding(
+                "generate_trunk", 1, width, len(prompt_ids)
+            )
+            self.instruments.record_launch(
+                "generate_shared",
+                (target, width, max_new, int(segmented), int(bias_table is not None)),
             )
 
-            kwargs["seg_len"] = seg_len
-            kwargs["dp_align"] = self._dp  # compaction keeps dp-divisible rows
-            kwargs["kv_quant"] = self.kv_quant
-        else:
-            fn = generate_tokens_shared_trunk
-        out = fn(
-            self.params, self.config,
-            jnp.asarray(tokens), jnp.asarray(valid), target, keys, **kwargs,
-        )
+            pad = self.tokenizer.pad_id
+            tokens = np.full((1, width), pad, np.int32)
+            valid = np.zeros((1, width), bool)
+            tokens[0, width - len(prompt_ids):] = prompt_ids
+            valid[0, width - len(prompt_ids):] = True
+
+            # Bucket-pad rows start done (they'd otherwise sample real tokens
+            # from the real prompt and pin the early exit at the full budget).
+            init_done = np.zeros((target,), bool)
+            init_done[len(requests):] = True
+            kwargs = dict(
+                max_new_tokens=max_new,
+                temperature=temperatures,
+                eos_ids=jnp.asarray(eos_ids, jnp.int32),
+                bias_table=bias_table,
+                bias_index=bias_index,
+                pad_id=self.tokenizer.pad_id,
+                init_done=jnp.asarray(init_done),
+            )
+            if rep_penalty is not None:
+                kwargs["rep_penalty"] = rep_penalty
+            if segmented:
+                from consensus_tpu.models.generate import (
+                    generate_tokens_shared_trunk_segmented as fn,
+                )
+
+                kwargs["seg_len"] = seg_len
+                kwargs["dp_align"] = self._dp  # compaction keeps dp-divisible rows
+                kwargs["kv_quant"] = self.kv_quant
+            else:
+                fn = generate_tokens_shared_trunk
+        with span("backend.launch", program=fn.__name__):
+            out = fn(
+                self.params, self.config,
+                jnp.asarray(tokens), jnp.asarray(valid), target, keys, **kwargs,
+            )
         return self._finish_generation(requests, out, rows=target, max_new=max_new)
 
     def _generate_classic(
@@ -1016,40 +1024,42 @@ class TPUBackend:
                 )
             return out
 
-        self.call_counts["generate"] += len(requests)
-        (target, pad_rows, temperatures, bias_table, bias_index, keys,
-         eos_ids, rep_penalty) = self._prep_generation_rows(requests, allowed)
-        self.instruments.record_padding(
-            "generate_prompt", target, width,
-            sum(min(len(t), width) for t in token_lists),
-        )
-        self.instruments.record_launch(
-            "generate",
-            (target, width, max_new, int(segmented), int(bias_table is not None)),
-        )
-        token_lists = list(token_lists) + [[]] * pad_rows
-        tokens, valid = self._left_pad_batch(token_lists)
-        kwargs = dict(
-            max_new_tokens=max_new,
-            temperature=temperatures,
-            eos_ids=jnp.asarray(eos_ids, jnp.int32),
-            bias_table=bias_table,
-            bias_index=bias_index,
-            pad_id=self.tokenizer.pad_id,
-        )
-        if rep_penalty is not None:
-            kwargs["rep_penalty"] = rep_penalty
-        if segmented:
-            from consensus_tpu.models.generate import (
-                generate_tokens_segmented as fn,
+        with span("backend.layout", rows=len(requests), width=width):
+            self.call_counts["generate"] += len(requests)
+            (target, pad_rows, temperatures, bias_table, bias_index, keys,
+             eos_ids, rep_penalty) = self._prep_generation_rows(requests, allowed)
+            self.instruments.record_padding(
+                "generate_prompt", target, width,
+                sum(min(len(t), width) for t in token_lists),
             )
+            self.instruments.record_launch(
+                "generate",
+                (target, width, max_new, int(segmented), int(bias_table is not None)),
+            )
+            token_lists = list(token_lists) + [[]] * pad_rows
+            tokens, valid = self._left_pad_batch(token_lists)
+            kwargs = dict(
+                max_new_tokens=max_new,
+                temperature=temperatures,
+                eos_ids=jnp.asarray(eos_ids, jnp.int32),
+                bias_table=bias_table,
+                bias_index=bias_index,
+                pad_id=self.tokenizer.pad_id,
+            )
+            if rep_penalty is not None:
+                kwargs["rep_penalty"] = rep_penalty
+            if segmented:
+                from consensus_tpu.models.generate import (
+                    generate_tokens_segmented as fn,
+                )
 
-            kwargs["seg_len"] = seg_len
-            kwargs["dp_align"] = self._dp  # compaction keeps dp-divisible rows
-            kwargs["kv_quant"] = self.kv_quant
-        else:
-            fn = generate_tokens
-        out = fn(self.params, self.config, tokens, valid, keys, **kwargs)
+                kwargs["seg_len"] = seg_len
+                kwargs["dp_align"] = self._dp  # compaction keeps dp-divisible rows
+                kwargs["kv_quant"] = self.kv_quant
+            else:
+                fn = generate_tokens
+        with span("backend.launch", program=fn.__name__):
+            out = fn(self.params, self.config, tokens, valid, keys, **kwargs)
         return self._finish_generation(requests, out, rows=target, max_new=max_new)
 
     def _finish_generation(
@@ -1070,32 +1080,33 @@ class TPUBackend:
             "generate_decode", rows, max_new, int(counts[: len(requests)].sum())
         )
 
-        results = []
-        for row, request in enumerate(requests):
-            emitted = int(counts[row])
-            ids = [int(t) for t in generated[row, :emitted]]
-            ids = ids[: request.max_tokens]
-            text = self.tokenizer.decode(ids)
-            # "stop" only if EOS arrived within the request's OWN cap; an EOS
-            # beyond max_tokens means the cap truncated the text ("length"),
-            # even though the bucketed decode window saw an EOS later.
-            finish = "stop" if (hit_eos[row] and emitted <= request.max_tokens) else "length"
-            truncated = False
-            if not self.pin_generation_budget:
-                for stop in request.stop:
-                    idx = text.find(stop)
-                    if idx >= 0:
-                        text = text[:idx]
-                        finish = "stop"
-                        truncated = True
-            if truncated:
-                # Keep token_ids consistent with the truncated text so token
-                # counts/ids downstream match what the caller sees.
-                ids = self.tokenizer.encode(text)
-            self.token_counts["generated"] += len(ids)
-            results.append(
-                GenerationResult(text=text, token_ids=tuple(ids), finish_reason=finish)
-            )
+        with span("backend.detokenize", rows=len(requests)):
+            results = []
+            for row, request in enumerate(requests):
+                emitted = int(counts[row])
+                ids = [int(t) for t in generated[row, :emitted]]
+                ids = ids[: request.max_tokens]
+                text = self.tokenizer.decode(ids)
+                # "stop" only if EOS arrived within the request's OWN cap; an EOS
+                # beyond max_tokens means the cap truncated the text ("length"),
+                # even though the bucketed decode window saw an EOS later.
+                finish = "stop" if (hit_eos[row] and emitted <= request.max_tokens) else "length"
+                truncated = False
+                if not self.pin_generation_budget:
+                    for stop in request.stop:
+                        idx = text.find(stop)
+                        if idx >= 0:
+                            text = text[:idx]
+                            finish = "stop"
+                            truncated = True
+                if truncated:
+                    # Keep token_ids consistent with the truncated text so token
+                    # counts/ids downstream match what the caller sees.
+                    ids = self.tokenizer.encode(text)
+                self.token_counts["generated"] += len(ids)
+                results.append(
+                    GenerationResult(text=text, token_ids=tuple(ids), finish_reason=finish)
+                )
         return results
 
     # -- score ---------------------------------------------------------------
@@ -1438,14 +1449,15 @@ class TPUBackend:
 
         # Tokenize once per unique rendered agent prefix (agents routinely
         # share the issue framing) and once per candidate.
-        prefix_ids: Dict[str, List[int]] = {}
-        agent_prefixes: List[str] = []
-        for agent in request.agents:
-            prefix = self._score_prefix(agent.to_score_request(""))
-            if prefix not in prefix_ids:
-                prefix_ids[prefix] = self.tokenizer.encode(prefix, add_bos=True)
-            agent_prefixes.append(prefix)
-        cont_ids = [self.tokenizer.encode(c) for c in request.candidates]
+        with span("backend.tokenize", rows=n_candidates + n_agents):
+            prefix_ids: Dict[str, List[int]] = {}
+            agent_prefixes: List[str] = []
+            for agent in request.agents:
+                prefix = self._score_prefix(agent.to_score_request(""))
+                if prefix not in prefix_ids:
+                    prefix_ids[prefix] = self.tokenizer.encode(prefix, add_bos=True)
+                agent_prefixes.append(prefix)
+            cont_ids = [self.tokenizer.encode(c) for c in request.candidates]
         max_cont = max(len(c) for c in cont_ids)
         if any(
             len(ids) + max_cont > self.max_context
@@ -1453,68 +1465,70 @@ class TPUBackend:
         ):
             return None  # per-call scorer owns truncation semantics
 
-        # Shared page layout: each unique context owns the pages below its
-        # last full page boundary; the remaining 1..ps-token tail is
-        # re-fed per row so the hidden state at the final context position
-        # exists to teacher-force the first candidate token.
-        shared: Dict[str, Tuple[int, int, int]] = {}  # prefix -> (first, npg, n0)
-        next_page = 0
-        for prefix, ids in prefix_ids.items():
-            n0 = ((len(ids) - 1) // ps) * ps
-            shared[prefix] = (next_page, n0 // ps, n0)
-            next_page += n0 // ps
-        shared_total = next_page
+        with span("backend.layout", rows=n_candidates * n_agents):
+            # Shared page layout: each unique context owns the pages below its
+            # last full page boundary; the remaining 1..ps-token tail is
+            # re-fed per row so the hidden state at the final context position
+            # exists to teacher-force the first candidate token.
+            shared: Dict[str, Tuple[int, int, int]] = {}  # prefix -> (first, npg, n0)
+            next_page = 0
+            for prefix, ids in prefix_ids.items():
+                n0 = ((len(ids) - 1) // ps) * ps
+                shared[prefix] = (next_page, n0 // ps, n0)
+                next_page += n0 // ps
+            shared_total = next_page
 
-        # Flattened candidate-major rows; q block = context tail + all but
-        # the last candidate token (targets are the NEXT stream token).
-        rows = []  # (prefix, cont, q_len, n_private)
-        max_q = 1
-        max_private = 1
-        max_blocks = 1
-        for cont in cont_ids:
-            for prefix in agent_prefixes:
-                ids = prefix_ids[prefix]
-                _, npg, n0 = shared[prefix]
-                q_len = (len(ids) - n0) + max(len(cont) - 1, 0)
-                n_private = (n0 + q_len - 1) // ps - n0 // ps + 1
-                rows.append((prefix, cont, q_len, n_private))
-                max_q = max(max_q, q_len)
-                max_private = max(max_private, n_private)
-                max_blocks = max(max_blocks, npg + n_private)
+            # Flattened candidate-major rows; q block = context tail + all but
+            # the last candidate token (targets are the NEXT stream token).
+            rows = []  # (prefix, cont, q_len, n_private)
+            max_q = 1
+            max_private = 1
+            max_blocks = 1
+            for cont in cont_ids:
+                for prefix in agent_prefixes:
+                    ids = prefix_ids[prefix]
+                    _, npg, n0 = shared[prefix]
+                    q_len = (len(ids) - n0) + max(len(cont) - 1, 0)
+                    n_private = (n0 + q_len - 1) // ps - n0 // ps + 1
+                    rows.append((prefix, cont, q_len, n_private))
+                    max_q = max(max_q, q_len)
+                    max_private = max(max_private, n_private)
+                    max_blocks = max(max_blocks, npg + n_private)
 
-        # Chunk the row batch under the live-session HBM budget: pow2 row
-        # buckets so the compiled-variant space stays small, halved until
-        # the page pool (shared + per-row private + sink) fits.
-        dtype = jnp.dtype(self.params["embed"].dtype)
-        page_bytes = (
-            self.config.n_layers * ps * self.config.n_kv_heads
-            * self.config.head_dim * dtype.itemsize * 2
-        )
+            # Chunk the row batch under the live-session HBM budget: pow2 row
+            # buckets so the compiled-variant space stays small, halved until
+            # the page pool (shared + per-row private + sink) fits.
+            dtype = jnp.dtype(self.params["embed"].dtype)
+            page_bytes = (
+                self.config.n_layers * ps * self.config.n_kv_heads
+                * self.config.head_dim * dtype.itemsize * 2
+            )
 
-        def pool_bytes(n_rows: int) -> int:
-            return (shared_total + n_rows * max_private + 1) * page_bytes
+            def pool_bytes(n_rows: int) -> int:
+                return (shared_total + n_rows * max_private + 1) * page_bytes
 
-        total_rows = len(rows)
-        chunk_rows = min(
-            _bucket(total_rows, minimum=8),
-            _bucket(max(self.max_batch_rows, 64), minimum=8),
-        )
-        budget = self._session_budget.cap
-        while chunk_rows > 1 and pool_bytes(chunk_rows) > budget:
-            chunk_rows //= 2
-        if pool_bytes(chunk_rows) > budget:
-            return None  # even one row over-commits; per-call path chunks finer
-        chunk_rows = max(chunk_rows, self._dp)
-        width = _bucket(max_q, minimum=ps)
-        num_pages = shared_total + chunk_rows * max_private
-        sink = num_pages
+            total_rows = len(rows)
+            chunk_rows = min(
+                _bucket(total_rows, minimum=8),
+                _bucket(max(self.max_batch_rows, 64), minimum=8),
+            )
+            budget = self._session_budget.cap
+            while chunk_rows > 1 and pool_bytes(chunk_rows) > budget:
+                chunk_rows //= 2
+            if pool_bytes(chunk_rows) > budget:
+                return None  # even one row over-commits; per-call path chunks finer
+            chunk_rows = max(chunk_rows, self._dp)
+            width = _bucket(max_q, minimum=ps)
+            num_pages = shared_total + chunk_rows * max_private
+            sink = num_pages
 
         nbytes = pool_bytes(chunk_rows)
         self._session_budget.acquire(nbytes)
         try:
-            state = make_page_state(
-                self.config, num_pages, ps, dtype=dtype, mesh=mesh
-            )
+            with span("backend.launch", program="make_page_state"):
+                state = make_page_state(
+                    self.config, num_pages, ps, dtype=dtype, mesh=mesh
+                )
             state = self._prefill_shared_pages(state, prefix_ids, shared, sink, mesh)
             chunk_stats = []
             for start in range(0, total_rows, chunk_rows):
@@ -1525,15 +1539,16 @@ class TPUBackend:
                 )
                 chunk_stats.append(tuple(s[: len(chunk)] for s in stats))
                 self.matrix_stats["chunks"] += 1
-            stats = tuple(
-                jnp.concatenate([cs[i] for cs in chunk_stats])
-                for i in range(4)
-            )
-            utilities, welfare_vals, aux = utility_matrix(
-                stats, n_candidates, n_agents,
-                stat=request.stat, rule=request.welfare_rule,
-                default=request.default,
-            )
+            with span("backend.launch", program="utility_matrix"):
+                stats = tuple(
+                    jnp.concatenate([cs[i] for cs in chunk_stats])
+                    for i in range(4)
+                )
+                utilities, welfare_vals, aux = utility_matrix(
+                    stats, n_candidates, n_agents,
+                    stat=request.stat, rule=request.welfare_rule,
+                    default=request.default,
+                )
             fetched = self._fetch(
                 *([utilities, welfare_vals] + ([aux] if aux is not None else []))
             )
@@ -1576,39 +1591,41 @@ class TPUBackend:
         tables[len(pre):] = tables[0]
         pad_id = self.tokenizer.pad_id
         for k in range(0, max_n0, chunk):
-            tokens = np.full((n_rows, chunk), pad_id, np.int32)
-            valid = np.zeros((n_rows, chunk), bool)
-            lengths = np.zeros((n_rows,), np.int32)
-            write_pages = np.full((n_rows, chunk), sink, np.int32)
-            write_offsets = np.zeros((n_rows, chunk), np.int32)
-            for r, p in enumerate(pre):
-                ids = prefix_ids[p]
-                first, _, n0 = shared[p]
-                hi = min(n0, k + chunk)
-                lengths[r] = hi  # == n0 once the row is complete
-                if hi <= k:
-                    continue
-                span = ids[k:hi]
-                valid[r, : len(span)] = True
-                tokens[r, : len(span)] = span
-                for j in range(len(span)):
-                    write_pages[r, j] = first + (k + j) // ps
-                    write_offsets[r, j] = (k + j) % ps
-            # Pad rows ride row 0's shape (valid positions, table) but
-            # write only to the sink — never a real page.
-            tokens[len(pre):] = tokens[0]
-            valid[len(pre):] = valid[0]
-            lengths[len(pre):] = lengths[0]
-            self.instruments.record_launch("score_matrix_prefill", (n_rows, chunk))
+            with span("backend.layout", rows=n_rows, width=chunk):
+                tokens = np.full((n_rows, chunk), pad_id, np.int32)
+                valid = np.zeros((n_rows, chunk), bool)
+                lengths = np.zeros((n_rows,), np.int32)
+                write_pages = np.full((n_rows, chunk), sink, np.int32)
+                write_offsets = np.zeros((n_rows, chunk), np.int32)
+                for r, p in enumerate(pre):
+                    ids = prefix_ids[p]
+                    first, _, n0 = shared[p]
+                    hi = min(n0, k + chunk)
+                    lengths[r] = hi  # == n0 once the row is complete
+                    if hi <= k:
+                        continue
+                    piece = ids[k:hi]
+                    valid[r, : len(piece)] = True
+                    tokens[r, : len(piece)] = piece
+                    for j in range(len(piece)):
+                        write_pages[r, j] = first + (k + j) // ps
+                        write_offsets[r, j] = (k + j) % ps
+                # Pad rows ride row 0's shape (valid positions, table) but
+                # write only to the sink — never a real page.
+                tokens[len(pre):] = tokens[0]
+                valid[len(pre):] = valid[0]
+                lengths[len(pre):] = lengths[0]
+                self.instruments.record_launch("score_matrix_prefill", (n_rows, chunk))
             # lengths is rank-1: jit's in-program constraint shards it.
             placed = self._place_batch(
                 tokens, valid, tables, write_pages, write_offsets
             )
-            _, state = paged_prefill_chunk(
-                self.params, self.config, placed[0], placed[1], state,
-                placed[2], jnp.asarray(lengths), placed[3], placed[4],
-                mesh=mesh,
-            )
+            with span("backend.launch", program="paged_prefill_chunk"):
+                _, state = paged_prefill_chunk(
+                    self.params, self.config, placed[0], placed[1], state,
+                    placed[2], jnp.asarray(lengths), placed[3], placed[4],
+                    mesh=mesh,
+                )
         return state
 
     def _score_matrix_chunk(
@@ -1620,58 +1637,60 @@ class TPUBackend:
 
         ps = self._SCORE_PAGE_SIZE
         pad_id = self.tokenizer.pad_id
-        tokens = np.full((n_rows, width), pad_id, np.int32)
-        targets = np.zeros((n_rows, width), np.int32)
-        score_mask = np.zeros((n_rows, width), bool)
-        chunk_valid = np.zeros((n_rows, width), bool)
-        tables = np.full((n_rows, max_blocks), -1, np.int32)
-        lengths = np.zeros((n_rows,), np.int32)
-        write_pages = np.full((n_rows, width), sink, np.int32)
-        write_offsets = np.zeros((n_rows, width), np.int32)
-        for r, (prefix, cont, q_len, n_private) in enumerate(chunk):
-            ids = prefix_ids[prefix]
-            first, npg, n0 = shared[prefix]
-            stream = ids + cont
-            block = stream[n0 : n0 + q_len]
-            tokens[r, : q_len] = block
-            chunk_valid[r, : q_len] = True
-            lengths[r] = n0 + q_len
-            tables[r, :npg] = np.arange(first, first + npg, dtype=np.int32)
-            base = shared_total + r * max_private
-            tables[r, npg : npg + n_private] = np.arange(
-                base, base + n_private, dtype=np.int32
+        with span("backend.layout", rows=n_rows, width=width):
+            tokens = np.full((n_rows, width), pad_id, np.int32)
+            targets = np.zeros((n_rows, width), np.int32)
+            score_mask = np.zeros((n_rows, width), bool)
+            chunk_valid = np.zeros((n_rows, width), bool)
+            tables = np.full((n_rows, max_blocks), -1, np.int32)
+            lengths = np.zeros((n_rows,), np.int32)
+            write_pages = np.full((n_rows, width), sink, np.int32)
+            write_offsets = np.zeros((n_rows, width), np.int32)
+            for r, (prefix, cont, q_len, n_private) in enumerate(chunk):
+                ids = prefix_ids[prefix]
+                first, npg, n0 = shared[prefix]
+                stream = ids + cont
+                block = stream[n0 : n0 + q_len]
+                tokens[r, : q_len] = block
+                chunk_valid[r, : q_len] = True
+                lengths[r] = n0 + q_len
+                tables[r, :npg] = np.arange(first, first + npg, dtype=np.int32)
+                base = shared_total + r * max_private
+                tables[r, npg : npg + n_private] = np.arange(
+                    base, base + n_private, dtype=np.int32
+                )
+                for j in range(q_len):
+                    pos = n0 + j
+                    write_pages[r, j] = base + pos // ps - n0 // ps
+                    write_offsets[r, j] = pos % ps
+                    if pos + 1 < len(stream):
+                        targets[r, j] = stream[pos + 1]
+                lo = len(ids) - 1 - n0
+                score_mask[r, lo : lo + len(cont)] = bool(cont)
+            # Pad rows duplicate row 0 (well-defined positions/attention) but
+            # write to the sink and score nothing.
+            n_real = len(chunk)
+            tokens[n_real:] = tokens[0]
+            targets[n_real:] = targets[0]
+            chunk_valid[n_real:] = chunk_valid[0]
+            lengths[n_real:] = lengths[0]
+            tables[n_real:] = tables[0]
+            self.instruments.record_padding(
+                "score_matrix", n_rows, width,
+                sum(q for (_, _, q, _) in chunk),
             )
-            for j in range(q_len):
-                pos = n0 + j
-                write_pages[r, j] = base + pos // ps - n0 // ps
-                write_offsets[r, j] = pos % ps
-                if pos + 1 < len(stream):
-                    targets[r, j] = stream[pos + 1]
-            lo = len(ids) - 1 - n0
-            score_mask[r, lo : lo + len(cont)] = bool(cont)
-        # Pad rows duplicate row 0 (well-defined positions/attention) but
-        # write to the sink and score nothing.
-        n_real = len(chunk)
-        tokens[n_real:] = tokens[0]
-        targets[n_real:] = targets[0]
-        chunk_valid[n_real:] = chunk_valid[0]
-        lengths[n_real:] = lengths[0]
-        tables[n_real:] = tables[0]
-        self.instruments.record_padding(
-            "score_matrix", n_rows, width,
-            sum(q for (_, _, q, _) in chunk),
-        )
-        self.instruments.record_launch("score_matrix", (n_rows, width))
+            self.instruments.record_launch("score_matrix", (n_rows, width))
         # lengths is rank-1: jit's in-program constraint shards it.
         placed = self._place_batch(
             tokens, targets, score_mask, chunk_valid, tables,
             write_pages, write_offsets,
         )
-        return paged_score_chunk(
-            self.params, self.config, placed[0], placed[1], placed[2],
-            placed[3], state, placed[4], jnp.asarray(lengths), placed[5],
-            placed[6], mesh=mesh,
-        )
+        with span("backend.launch", program="paged_score_chunk"):
+            return paged_score_chunk(
+                self.params, self.config, placed[0], placed[1], placed[2],
+                placed[3], state, placed[4], jnp.asarray(lengths), placed[5],
+                placed[6], mesh=mesh,
+            )
 
     # -- next-token distribution ----------------------------------------------
 
@@ -1785,19 +1804,21 @@ class TPUBackend:
 
     def _embed_impl(self, texts: Sequence[str]) -> np.ndarray:
         self.call_counts["embed"] += len(texts)
-        token_lists = [self.tokenizer.encode(t, add_bos=True) for t in texts]
-        pad_rows = _bucket(len(texts), minimum=8) - len(texts)
-        token_lists += [[]] * pad_rows
-        tokens, valid = self._left_pad_batch(token_lists)
-        width = int(tokens.shape[1])
-        self.instruments.record_padding(
-            "embed", len(token_lists), width,
-            sum(min(len(t), width) for t in token_lists[: len(texts)]),
-        )
-        self.instruments.record_launch("embed", (len(token_lists), width))
-        hidden = self._fetch(
-            _embed_forward(self.params, self.config, tokens, valid)
-        )[: len(texts)]
+        with span("backend.tokenize", rows=len(texts)):
+            token_lists = [self.tokenizer.encode(t, add_bos=True) for t in texts]
+        with span("backend.layout", rows=len(texts)):
+            pad_rows = _bucket(len(texts), minimum=8) - len(texts)
+            token_lists += [[]] * pad_rows
+            tokens, valid = self._left_pad_batch(token_lists)
+            width = int(tokens.shape[1])
+            self.instruments.record_padding(
+                "embed", len(token_lists), width,
+                sum(min(len(t), width) for t in token_lists[: len(texts)]),
+            )
+            self.instruments.record_launch("embed", (len(token_lists), width))
+        with span("backend.launch", program="_embed_forward"):
+            pooled = _embed_forward(self.params, self.config, tokens, valid)
+        hidden = self._fetch(pooled)[: len(texts)]
         norms = np.linalg.norm(hidden, axis=1, keepdims=True)
         return hidden / np.maximum(norms, 1e-12)
 

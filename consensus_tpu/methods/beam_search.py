@@ -40,6 +40,7 @@ from consensus_tpu.backends.session import (
 from consensus_tpu.methods.base import BaseGenerator
 from consensus_tpu.methods.brushup import brushup_statement_ending
 from consensus_tpu.methods.prompts import agent_prompt, reference_prompt
+from consensus_tpu.obs.trace import span
 
 #: Token strings that complete a sequence (reference beam_search.py:26-35).
 EOS_TOKENS = frozenset(
@@ -106,11 +107,13 @@ class BeamSearchGenerator(BaseGenerator):
         if clock.expired():
             return self._degrade()
 
-        system, user = reference_prompt(issue, agent_opinions, variant="beam_search")
-        agent_prompts = tuple(
-            agent_prompt(issue, opinion, variant="beam_search")
-            for _, opinion in agents
-        )
+        with span("method.render"):
+            system, user = reference_prompt(
+                issue, agent_opinions, variant="beam_search")
+            agent_prompts = tuple(
+                agent_prompt(issue, opinion, variant="beam_search")
+                for _, opinion in agents
+            )
         session = open_token_search(
             self.backend,
             SearchSpec(

@@ -26,6 +26,7 @@ import numpy as np
 from consensus_tpu.backends.base import GenerationRequest, ScoreRequest
 from consensus_tpu.methods.base import BaseGenerator
 from consensus_tpu.methods.prompts import agent_prompt, clean_statement, reference_prompt
+from consensus_tpu.obs.trace import span
 from consensus_tpu.ops.welfare import (
     DEFAULT_REWARD,
     egalitarian_welfare,
@@ -67,8 +68,9 @@ class BestOfNGenerator(BaseGenerator):
             return self._degrade()
 
         utilities = self.score_candidates(issue, agent_opinions, candidates)
-        welfare = egalitarian_welfare(sanitize_utilities(utilities), axis=1)
-        best = int(np.argmax(np.asarray(welfare)))
+        with span("method.select", candidates=len(candidates)):
+            welfare = egalitarian_welfare(sanitize_utilities(utilities), axis=1)
+            best = int(np.argmax(np.asarray(welfare)))
         self._checkpoint(
             candidates[best],
             welfare=float(np.asarray(welfare)[best]),
@@ -92,26 +94,29 @@ class BestOfNGenerator(BaseGenerator):
         temperature: float,
         seed,
     ) -> List[str]:
-        system, user = reference_prompt(issue, agent_opinions)
-        requests = [
-            GenerationRequest(
-                user_prompt=user,
-                system_prompt=system,
-                max_tokens=max_tokens,
-                temperature=temperature,
-                seed=(seed + i) if seed is not None else None,
-                chat=True,
-            )
-            for i in range(n)
-        ]
-        results = self.backend.generate(requests)
+        with span("method.render"):
+            system, user = reference_prompt(issue, agent_opinions)
+            requests = [
+                GenerationRequest(
+                    user_prompt=user,
+                    system_prompt=system,
+                    max_tokens=max_tokens,
+                    temperature=temperature,
+                    seed=(seed + i) if seed is not None else None,
+                    chat=True,
+                )
+                for i in range(n)
+            ]
+        with span("method.generate", rows=n):
+            results = self.backend.generate(requests)
         candidates = []
-        for result in results:
-            if not result.ok:
-                continue
-            cleaned = clean_statement(result.text)
-            if cleaned:
-                candidates.append(cleaned)
+        with span("method.select", rows=len(results)):
+            for result in results:
+                if not result.ok:
+                    continue
+                cleaned = clean_statement(result.text)
+                if cleaned:
+                    candidates.append(cleaned)
         return candidates
 
     def score_candidates(
@@ -133,38 +138,43 @@ class BestOfNGenerator(BaseGenerator):
             )
 
             contexts = []
-            for _, opinion in agents:
-                system, user = agent_prompt(issue, opinion)
-                contexts.append(
-                    AgentContext(context=user, system_prompt=system, chat=True)
-                )
-            result = score_matrix_many(
-                self.backend,
-                [
-                    ScoreMatrixRequest(
-                        agents=tuple(contexts),
-                        candidates=tuple(candidates),
-                        stat="mean",
-                        default=DEFAULT_REWARD,
+            with span("method.render"):
+                for _, opinion in agents:
+                    system, user = agent_prompt(issue, opinion)
+                    contexts.append(
+                        AgentContext(
+                            context=user, system_prompt=system, chat=True)
                     )
-                ],
-            )[0]
+            with span("method.score", rows=len(candidates) * len(agents)):
+                result = score_matrix_many(
+                    self.backend,
+                    [
+                        ScoreMatrixRequest(
+                            agents=tuple(contexts),
+                            candidates=tuple(candidates),
+                            stat="mean",
+                            default=DEFAULT_REWARD,
+                        )
+                    ],
+                )[0]
             return np.asarray(result.utilities, dtype=np.float32).reshape(
                 len(candidates), len(agents)
             )
         requests = []
-        for candidate in candidates:
-            for _, opinion in agents:
-                system, user = agent_prompt(issue, opinion)
-                requests.append(
-                    ScoreRequest(
-                        context=user,
-                        continuation=candidate,
-                        system_prompt=system,
-                        chat=True,
+        with span("method.render"):
+            for candidate in candidates:
+                for _, opinion in agents:
+                    system, user = agent_prompt(issue, opinion)
+                    requests.append(
+                        ScoreRequest(
+                            context=user,
+                            continuation=candidate,
+                            system_prompt=system,
+                            chat=True,
+                        )
                     )
-                )
-        results = self.backend.score(requests)
+        with span("method.score", rows=len(requests)):
+            results = self.backend.score(requests)
         means = [r.mean(default=DEFAULT_REWARD) for r in results]
         return np.asarray(means, dtype=np.float32).reshape(
             len(candidates), len(agents)

@@ -14,6 +14,7 @@ from typing import Optional
 
 from consensus_tpu.backends.base import Backend, GenerationRequest
 from consensus_tpu.methods.prompts import clean_statement
+from consensus_tpu.obs.trace import span
 
 _BRUSHUP_INSTRUCTIONS = (
     "Fix ONLY the ending of the statement below. If the final sentence is "
@@ -34,18 +35,19 @@ def brushup_statement_ending(
     """Return the statement with a repaired ending, or unchanged on failure."""
     if not statement or not statement.strip():
         return statement
-    result = backend.generate(
-        [
-            GenerationRequest(
-                user_prompt=f"Statement:\n{statement}",
-                system_prompt=_BRUSHUP_INSTRUCTIONS,
-                max_tokens=max_tokens,
-                temperature=temperature,
-                seed=seed,
-                chat=True,
-            )
-        ]
-    )[0]
+    with span("method.generate", rows=1):
+        result = backend.generate(
+            [
+                GenerationRequest(
+                    user_prompt=f"Statement:\n{statement}",
+                    system_prompt=_BRUSHUP_INSTRUCTIONS,
+                    max_tokens=max_tokens,
+                    temperature=temperature,
+                    seed=seed,
+                    chat=True,
+                )
+            ]
+        )[0]
     if not result.ok:
         return statement
     cleaned = clean_statement(result.text)

@@ -44,6 +44,7 @@ from consensus_tpu.methods.base import BaseGenerator
 from consensus_tpu.methods.beam_search import BIAS_AGAINST_TOKENS
 from consensus_tpu.methods.brushup import brushup_statement_ending
 from consensus_tpu.methods.prompts import agent_prompt, reference_prompt
+from consensus_tpu.obs.trace import span
 
 #: Tokens that terminate a lookahead path / the whole statement
 #: (reference finite_lookahead.py:141-144, 350-355).
@@ -91,13 +92,14 @@ class FiniteLookaheadGenerator(BaseGenerator):
         if clock.expired():
             return self._degrade()
 
-        system, user = reference_prompt(
-            issue, agent_opinions, variant="finite_lookahead"
-        )
-        agent_prompts = tuple(
-            agent_prompt(issue, opinion, variant="finite_lookahead")
-            for _, opinion in agents
-        )
+        with span("method.render"):
+            system, user = reference_prompt(
+                issue, agent_opinions, variant="finite_lookahead"
+            )
+            agent_prompts = tuple(
+                agent_prompt(issue, opinion, variant="finite_lookahead")
+                for _, opinion in agents
+            )
         session = open_token_search(
             self.backend,
             SearchSpec(

@@ -49,6 +49,7 @@ import numpy as np
 
 from consensus_tpu.backends.base import GenerationRequest
 from consensus_tpu.methods.base import BaseGenerator
+from consensus_tpu.obs.trace import span
 from consensus_tpu.social_choice.parsing import (
     extract_statement,
     process_ranking_response,
@@ -324,7 +325,9 @@ class HabermasMachineGenerator(BaseGenerator):
             )
             for prompt, seed in zip(prompts, seeds)
         ]
-        return [r.text if r.ok else "" for r in self.backend.generate(requests)]
+        with span("method.generate", rows=len(requests)):
+            results = self.backend.generate(requests)
+        return [r.text if r.ok else "" for r in results]
 
     def _draft_candidates(
         self, issue: str, opinions: List[str], n: int

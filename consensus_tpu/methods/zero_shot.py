@@ -14,6 +14,7 @@ from typing import Dict
 from consensus_tpu.backends.base import GenerationRequest
 from consensus_tpu.methods.base import BaseGenerator
 from consensus_tpu.methods.prompts import clean_statement, reference_prompt
+from consensus_tpu.obs.trace import span
 
 
 class ZeroShotGenerator(BaseGenerator):
@@ -21,19 +22,18 @@ class ZeroShotGenerator(BaseGenerator):
     method_name = "zero_shot"
 
     def generate_statement(self, issue: str, agent_opinions: Dict[str, str]) -> str:
-        system, user = reference_prompt(issue, agent_opinions)
-        result = self.backend.generate(
-            [
-                GenerationRequest(
-                    user_prompt=user,
-                    system_prompt=system,
-                    max_tokens=int(self.config.get("max_tokens", 50)),
-                    temperature=float(self.config.get("temperature", 1.0)),
-                    seed=self.seed,
-                    chat=True,
-                )
-            ]
-        )[0]
+        with span("method.render"):
+            system, user = reference_prompt(issue, agent_opinions)
+            request = GenerationRequest(
+                user_prompt=user,
+                system_prompt=system,
+                max_tokens=int(self.config.get("max_tokens", 50)),
+                temperature=float(self.config.get("temperature", 1.0)),
+                seed=self.seed,
+                chat=True,
+            )
+        with span("method.generate", rows=1):
+            result = self.backend.generate([request])[0]
         if not result.ok:
             return f"[ERROR: zero-shot generation failed: {result.text}]"
         return clean_statement(result.text)

@@ -4,3 +4,22 @@ from consensus_tpu.models.transformer import (  # noqa: F401
     init_params,
     make_cache,
 )
+
+#: The outer scopes of a generation program: the shared prompt's prefill and
+#: the decode loop.  An operation carries one of them beside its own scope.
+MODEL_PHASES = ("prefill", "decode_step")
+
+#: Every name a ``jax.named_scope`` under ``consensus_tpu/`` gives: what a
+#: profile shows an operation under, and what the benchmark's kernel metrics
+#: group device time by.  ``attn_qkv`` is the norm, the three projections and
+#: the rotary embedding; ``attention`` the logits, mask, softmax and values
+#: (with the page gather where there is one); ``vocab_projection`` the head
+#: product; ``logsumexp`` the log-softmax and the target's gather; ``sample``
+#: temperature, bias, penalties, Gumbel noise and the top-k or argmax.
+#: ``layers`` is the scan over the layers, and names what no scope of the
+#: layer body covers: the loop's own slicing of a layer's weights and cache
+#: out of the stacked arrays, and the stacking of what the layer returns.
+MODEL_SCOPES = (
+    "embed", "layers", "attn_qkv", "kv_write", "attention", "attn_out", "ffn",
+    "final_norm", "vocab_projection", "logsumexp", "sample",
+) + MODEL_PHASES

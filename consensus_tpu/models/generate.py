@@ -235,14 +235,16 @@ def _prefill_shared(
     prompt_valid: jax.Array,  # (1, S_ctx) bool
 ):
     """Prefill one shared prompt row: (next_logits (1, V), trunk, last_pos)."""
-    trunk = make_cache(config, 1, prompt_tokens.shape[1], params["embed"].dtype)
-    positions = left_pad_positions(prompt_valid)
-    hidden, trunk = forward(
-        params, config, prompt_tokens, positions, prompt_valid, trunk, 0,
-        return_hidden=True,
-    )
-    next_logits = project_logits(params, config, hidden[:, -1, :])
-    return next_logits, trunk, positions[0, -1]
+    with jax.named_scope("prefill"):
+        trunk = make_cache(
+            config, 1, prompt_tokens.shape[1], params["embed"].dtype)
+        positions = left_pad_positions(prompt_valid)
+        hidden, trunk = forward(
+            params, config, prompt_tokens, positions, prompt_valid, trunk, 0,
+            return_hidden=True,
+        )
+        next_logits = project_logits(params, config, hidden[:, -1, :])
+        return next_logits, trunk, positions[0, -1]
 
 
 @functools.partial(
@@ -390,7 +392,8 @@ def _decode_segment(
         jnp.asarray(0, jnp.int32), next_logits, tail_k, tail_v,
         done, keys, cur_pos, tokens_buf, emitted_buf,
     ) + ((presence,) if use_rp else ())
-    final = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("decode_step"):
+        final = jax.lax.while_loop(cond, body, init)
     (_, next_logits, tail_k, tail_v, done, keys, _, tokens_buf, emitted_buf) = final[:9]
     presence = final[9] if use_rp else None
     return (
@@ -635,17 +638,18 @@ def _prefill_classic(
     prompt_valid: jax.Array,  # (B, S_ctx) bool
 ):
     """Prefill per-row prompts: (next_logits (B, V), trunk, last_pos (B,))."""
-    trunk = make_cache(
-        config, prompt_tokens.shape[0], prompt_tokens.shape[1],
-        params["embed"].dtype,
-    )
-    positions = left_pad_positions(prompt_valid)
-    hidden, trunk = forward(
-        params, config, prompt_tokens, positions, prompt_valid, trunk, 0,
-        return_hidden=True,
-    )
-    next_logits = project_logits(params, config, hidden[:, -1, :])
-    return next_logits, trunk, positions[:, -1]
+    with jax.named_scope("prefill"):
+        trunk = make_cache(
+            config, prompt_tokens.shape[0], prompt_tokens.shape[1],
+            params["embed"].dtype,
+        )
+        positions = left_pad_positions(prompt_valid)
+        hidden, trunk = forward(
+            params, config, prompt_tokens, positions, prompt_valid, trunk, 0,
+            return_hidden=True,
+        )
+        next_logits = project_logits(params, config, hidden[:, -1, :])
+        return next_logits, trunk, positions[:, -1]
 
 
 def generate_tokens_segmented(

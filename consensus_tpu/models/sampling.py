@@ -24,8 +24,9 @@ def ban_undecodable(logits: jax.Array, config) -> jax.Array:
     n = config.sample_vocab
     if n is None or n >= logits.shape[-1]:
         return logits
-    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    return jnp.where(ids < n, logits, -jnp.inf)
+    with jax.named_scope("sample"):
+        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        return jnp.where(ids < n, logits, -jnp.inf)
 
 
 def _top_k_filter(logits: jax.Array, k: int) -> jax.Array:
@@ -82,25 +83,26 @@ def sample_tokens(
     a request's output is then independent of batch composition, matching
     the reference's per-request seed semantics (SURVEY §7.4).
     """
-    logits = logits.astype(jnp.float32)
-    if presence is not None and rep_penalty is not None:
-        logits = apply_repetition_penalty(logits, presence, rep_penalty)
-    if logit_bias is not None:
-        logits = logits + logit_bias
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        if presence is not None and rep_penalty is not None:
+            logits = apply_repetition_penalty(logits, presence, rep_penalty)
+        if logit_bias is not None:
+            logits = logits + logit_bias
 
-    greedy = jnp.argmax(logits, axis=-1)
+        greedy = jnp.argmax(logits, axis=-1)
 
-    filtered = _top_k_filter(logits, top_k)
-    filtered = _top_p_filter(filtered, top_p)
-    temp = jnp.asarray(temperature, jnp.float32)
-    if temp.ndim == 1:  # per-row temperatures (B,) -> broadcast over vocab
-        temp = temp[:, None]
-    safe_temp = jnp.maximum(temp, 1e-6)
-    scaled = filtered / safe_temp
-    if key.ndim == 2:
-        sampled = jax.vmap(jax.random.categorical)(key, scaled)
-    else:
-        sampled = jax.random.categorical(key, scaled, axis=-1)
+        filtered = _top_k_filter(logits, top_k)
+        filtered = _top_p_filter(filtered, top_p)
+        temp = jnp.asarray(temperature, jnp.float32)
+        if temp.ndim == 1:  # per-row temperatures (B,) -> broadcast over vocab
+            temp = temp[:, None]
+        safe_temp = jnp.maximum(temp, 1e-6)
+        scaled = filtered / safe_temp
+        if key.ndim == 2:
+            sampled = jax.vmap(jax.random.categorical)(key, scaled)
+        else:
+            sampled = jax.random.categorical(key, scaled, axis=-1)
 
-    use_greedy = jnp.any(temp <= 0.0, axis=-1) if temp.ndim else temp <= 0.0
-    return jnp.where(use_greedy, greedy, sampled).astype(jnp.int32)
+        use_greedy = jnp.any(temp <= 0.0, axis=-1) if temp.ndim else temp <= 0.0
+        return jnp.where(use_greedy, greedy, sampled).astype(jnp.int32)

@@ -45,9 +45,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from consensus_tpu.models.config import ModelConfig
 from consensus_tpu.models.generate import left_pad_positions
-from consensus_tpu.models.quant import matmul, take_rows
+from consensus_tpu.models.quant import matmul
 from consensus_tpu.models.transformer import (
     KVCache,
+    attn_out_block,
+    embed_tokens,
+    ffn_block,
+    final_norm,
     forward,
     forward_shared_trunk,
     forward_trunk_tail,
@@ -726,25 +730,25 @@ def _paged_forward(
     through its slot's block table.  Returns (hidden (B, S, D), state)."""
     b, s = tokens.shape
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    x = take_rows(params["embed"], tokens)
-    if c.scale_embeddings:
-        x = x * jnp.asarray(c.d_model**0.5, x.dtype)
+    x = embed_tokens(params, c, tokens)
     local_flags = jnp.asarray(c.local_flags)
 
     def layer_step(x, scanned):
         lp, kp_l, vp_l, is_local = scanned
-        attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-        q = matmul(attn_in, lp["wq"]).reshape(b, s, h, hd)
-        k = matmul(attn_in, lp["wk"]).reshape(b, s, kv, hd)
-        v = matmul(attn_in, lp["wv"]).reshape(b, s, kv, hd)
-        q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
-        k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
+        with jax.named_scope("attn_qkv"):
+            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
+            q = matmul(attn_in, lp["wq"]).reshape(b, s, h, hd)
+            k = matmul(attn_in, lp["wk"]).reshape(b, s, kv, hd)
+            v = matmul(attn_in, lp["wv"]).reshape(b, s, kv, hd)
+            q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
+            k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
 
         # Scatter the fresh K/V into their pages.  Cursor pairs are unique
         # across rows (slots own disjoint pages) except the sink, which is
         # never read, so duplicate-index order doesn't matter.
-        kp_l = kp_l.at[write_pages, write_offsets].set(k)
-        vp_l = vp_l.at[write_pages, write_offsets].set(v)
+        with jax.named_scope("kv_write"):
+            kp_l = kp_l.at[write_pages, write_offsets].set(k)
+            vp_l = vp_l.at[write_pages, write_offsets].set(v)
 
         def attend(window):
             return paged_attention(
@@ -752,37 +756,25 @@ def _paged_forward(
                 scale=c.q_scale, softcap=c.attn_softcap, window=window,
             )
 
-        if c.sliding_window is None:
-            attn = attend(None)
-        else:
-            attn = jax.lax.cond(
-                is_local,
-                lambda _: attend(c.sliding_window),
-                lambda _: attend(None),
-                None,
-            )
-        attn = matmul(attn.reshape(b, s, h * hd), lp["wo"])
-        if c.use_post_norms:
-            attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + attn
+        with jax.named_scope("attention"):
+            if c.sliding_window is None:
+                attn = attend(None)
+            else:
+                attn = jax.lax.cond(
+                    is_local,
+                    lambda _: attend(c.sliding_window),
+                    lambda _: attend(None),
+                    None,
+                )
+        x = attn_out_block(c, lp, x, attn.reshape(b, s, h * hd))
+        return ffn_block(c, lp, x), (kp_l, vp_l)
 
-        ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        gate = matmul(ffn_in, lp["w_gate"])
-        if c.activation == "geglu":
-            gate = jax.nn.gelu(gate, approximate=True)
-        else:
-            gate = jax.nn.silu(gate)
-        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
-        if c.use_post_norms:
-            ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + ffn
-        return x, (kp_l, vp_l)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], state.k_pages, state.v_pages, local_flags)
-    )
-    x = rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
-    return x, PagedSlotState(new_k, new_v)
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_step, x,
+            (params["layers"], state.k_pages, state.v_pages, local_flags),
+        )
+    return final_norm(params, c, x), PagedSlotState(new_k, new_v)
 
 
 @functools.partial(
@@ -994,9 +986,10 @@ def paged_decode_steps(
     init = (logits, state, lengths, keys, done, budgets, hit_eos) + (
         (presence,) if use_rp else ()
     )
-    final, (tokens_steps, emitted_steps) = jax.lax.scan(
-        step, init, None, length=num_steps
-    )
+    with jax.named_scope("decode_step"):
+        final, (tokens_steps, emitted_steps) = jax.lax.scan(
+            step, init, None, length=num_steps
+        )
     (logits, state, lengths, keys, done, budgets, hit_eos) = final[:7]
     presence = final[7] if use_rp else None
     return (
@@ -1286,15 +1279,16 @@ def paged_score_chunk(
         h_col, t_col, m_col = xs  # (B, D), (B,), (B,)
         logits = project_logits(params, config, h_col)  # (B, V) f32
         logits = _constrain(logits, mesh, "data", "model")
-        lp = jax.nn.log_softmax(logits, axis=-1)
-        t_lp = jnp.take_along_axis(lp, t_col[:, None], axis=1)[:, 0]
-        sum_lp, last_lp, sum_exp, counts = carry
-        return (
-            sum_lp + jnp.where(m_col, t_lp, 0.0),
-            jnp.where(m_col, t_lp, last_lp),
-            sum_exp + jnp.where(m_col, jnp.exp(t_lp), 0.0),
-            counts + m_col.astype(jnp.int32),
-        ), None
+        with jax.named_scope("logsumexp"):
+            lp = jax.nn.log_softmax(logits, axis=-1)
+            t_lp = jnp.take_along_axis(lp, t_col[:, None], axis=1)[:, 0]
+            sum_lp, last_lp, sum_exp, counts = carry
+            return (
+                sum_lp + jnp.where(m_col, t_lp, 0.0),
+                jnp.where(m_col, t_lp, last_lp),
+                sum_exp + jnp.where(m_col, jnp.exp(t_lp), 0.0),
+                counts + m_col.astype(jnp.int32),
+            ), None
 
     init = (
         jnp.zeros((b,), jnp.float32),

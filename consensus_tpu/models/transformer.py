@@ -170,6 +170,53 @@ def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# Layer pieces every layer body shares.  Each runs under the
+# ``jax.named_scope`` that names it in a profile (models.MODEL_SCOPES): a
+# scope changes the operations' metadata and nothing else.  The scan over
+# the layers runs under ``layers``, which is left to what the loop itself
+# does: slicing a layer's weights and cache out of the stacked arrays and
+# stacking what the layer returns.
+# ---------------------------------------------------------------------------
+
+
+def embed_tokens(params: Params, c: ModelConfig, tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        x = take_rows(params["embed"], tokens)
+        if c.scale_embeddings:
+            x = x * jnp.asarray(c.d_model**0.5, x.dtype)
+        return x
+
+
+def attn_out_block(c: ModelConfig, lp, x: jax.Array, attn: jax.Array) -> jax.Array:
+    """Output projection of the heads' values (..., H*hd), and the residual."""
+    with jax.named_scope("attn_out"):
+        attn = matmul(attn, lp["wo"])
+        if c.use_post_norms:
+            attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
+        return x + attn
+
+
+def ffn_block(c: ModelConfig, lp, x: jax.Array) -> jax.Array:
+    """Norm, gated feed-forward, and the residual."""
+    with jax.named_scope("ffn"):
+        ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
+        gate = matmul(ffn_in, lp["w_gate"])
+        if c.activation == "geglu":
+            gate = jax.nn.gelu(gate, approximate=True)
+        else:
+            gate = jax.nn.silu(gate)
+        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
+        if c.use_post_norms:
+            ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
+        return x + ffn
+
+
+def final_norm(params: Params, c: ModelConfig, x: jax.Array) -> jax.Array:
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
+
+
+# ---------------------------------------------------------------------------
 # KV cache
 # ---------------------------------------------------------------------------
 
@@ -250,9 +297,7 @@ def forward(
     materialize a full (B, S, V) logits tensor for 256k-vocab models.
     """
     c = config
-    x = take_rows(params["embed"], tokens)
-    if c.scale_embeddings:
-        x = x * jnp.asarray(c.d_model**0.5, x.dtype)
+    x = embed_tokens(params, c, tokens)
 
     if cache is None:
         k_positions, k_valid = positions, valid
@@ -272,102 +317,95 @@ def forward(
     def layer_step(x, scanned):
         lp, k_cache_l, v_cache_l, is_local = scanned
 
-        attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-        q = matmul(attn_in, lp["wq"]).reshape(batch, span, h, hd)
-        k = matmul(attn_in, lp["wk"]).reshape(batch, span, kv, hd)
-        v = matmul(attn_in, lp["wv"]).reshape(batch, span, kv, hd)
-        q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
-        k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
+        with jax.named_scope("attn_qkv"):
+            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
+            q = matmul(attn_in, lp["wq"]).reshape(batch, span, h, hd)
+            k = matmul(attn_in, lp["wk"]).reshape(batch, span, kv, hd)
+            v = matmul(attn_in, lp["wv"]).reshape(batch, span, kv, hd)
+            q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
+            k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
 
         if k_cache_l is None:
             keys, values = k, v
         else:
-            keys = jax.lax.dynamic_update_slice(k_cache_l, k, (0, write_index, 0, 0))
-            values = jax.lax.dynamic_update_slice(v_cache_l, v, (0, write_index, 0, 0))
+            with jax.named_scope("kv_write"):
+                keys = jax.lax.dynamic_update_slice(
+                    k_cache_l, k, (0, write_index, 0, 0))
+                values = jax.lax.dynamic_update_slice(
+                    v_cache_l, v, (0, write_index, 0, 0))
 
         reps = h // kv
 
-        if c.use_flash_attention and cache is None:
-            # The pallas kernel takes equal q/kv head counts; expand here.
-            keys_r = jnp.repeat(keys, reps, axis=2)  # (B, T, H, hd)
-            values_r = jnp.repeat(values, reps, axis=2)
-            # Pallas blockwise kernel: no (B, H, S, S) logits in HBM.  The
-            # kernel's masking model is one contiguous valid span per row,
-            # described by (start, length) scalars — start=0 covers the
-            # right-padded scoring layout, start=argmax(valid) the
-            # left-padded next-token/embed layout (rows with no valid token
-            # get length 0 and an empty mask either way).
-            # ``is_local`` is a traced scan input, so window selection is a
-            # lax.cond between two statically-windowed kernel calls.
-            from consensus_tpu.ops.flash_attention import flash_attention
+        with jax.named_scope("attention"):
+            if c.use_flash_attention and cache is None:
+                # The pallas kernel takes equal q/kv head counts; expand here.
+                keys_r = jnp.repeat(keys, reps, axis=2)  # (B, T, H, hd)
+                values_r = jnp.repeat(values, reps, axis=2)
+                # Pallas blockwise kernel: no (B, H, S, S) logits in HBM.  The
+                # kernel's masking model is one contiguous valid span per row,
+                # described by (start, length) scalars — start=0 covers the
+                # right-padded scoring layout, start=argmax(valid) the
+                # left-padded next-token/embed layout (rows with no valid token
+                # get length 0 and an empty mask either way).
+                # ``is_local`` is a traced scan input, so window selection is a
+                # lax.cond between two statically-windowed kernel calls.
+                from consensus_tpu.ops.flash_attention import flash_attention
 
-            interp = jax.default_backend() == "cpu"
-            lengths = jnp.sum(valid.astype(jnp.int32), axis=1)
-            starts = jnp.argmax(valid, axis=1).astype(jnp.int32)
+                interp = jax.default_backend() == "cpu"
+                lengths = jnp.sum(valid.astype(jnp.int32), axis=1)
+                starts = jnp.argmax(valid, axis=1).astype(jnp.int32)
 
-            def call_flash(window):
-                def fn(operands):
-                    qq, kk, vv = operands
-                    return flash_attention(
-                        qq, kk, vv, lengths, starts,
-                        scale=c.q_scale, softcap=c.attn_softcap,
-                        window=window, causal=True, interpret=interp,
+                def call_flash(window):
+                    def fn(operands):
+                        qq, kk, vv = operands
+                        return flash_attention(
+                            qq, kk, vv, lengths, starts,
+                            scale=c.q_scale, softcap=c.attn_softcap,
+                            window=window, causal=True, interpret=interp,
+                        )
+                    return fn
+
+                operands = (q, keys_r, values_r)
+                if c.sliding_window is None:
+                    attn = call_flash(None)(operands)
+                else:
+                    attn = jax.lax.cond(
+                        is_local,
+                        call_flash(c.sliding_window),
+                        call_flash(None),
+                        operands,
                     )
-                return fn
-
-            operands = (q, keys_r, values_r)
-            if c.sliding_window is None:
-                attn = call_flash(None)(operands)
+                attn = attn.astype(x.dtype)
             else:
-                attn = jax.lax.cond(
-                    is_local,
-                    call_flash(c.sliding_window),
-                    call_flash(None),
-                    operands,
-                )
-            attn = attn.astype(x.dtype)
-        else:
-            # GQA without materializing repeated KV: group q heads by their
-            # kv head — on the decode path jnp.repeat would re-write the
-            # whole (B, T, H, hd) cache expansion every layer every step,
-            # doubling HBM traffic for nothing.
-            qg = q.reshape(batch, span, kv, reps, hd)
-            logits = jnp.einsum("bsgrd,btgd->bgrst", qg, keys).astype(jnp.float32)
-            logits = logits * c.q_scale
-            logits = _softcap(logits, c.attn_softcap)
-            mask = jnp.where(is_local, local_mask, global_mask)
-            logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
-            weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
-        attn = matmul(attn.reshape(batch, span, h * hd), lp["wo"])
-        if c.use_post_norms:
-            attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + attn
-
-        ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        gate = matmul(ffn_in, lp["w_gate"])
-        if c.activation == "geglu":
-            gate = jax.nn.gelu(gate, approximate=True)
-        else:
-            gate = jax.nn.silu(gate)
-        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
-        if c.use_post_norms:
-            ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + ffn
+                # GQA without materializing repeated KV: group q heads by their
+                # kv head — on the decode path jnp.repeat would re-write the
+                # whole (B, T, H, hd) cache expansion every layer every step,
+                # doubling HBM traffic for nothing.
+                qg = q.reshape(batch, span, kv, reps, hd)
+                logits = jnp.einsum("bsgrd,btgd->bgrst", qg, keys).astype(jnp.float32)
+                logits = logits * c.q_scale
+                logits = _softcap(logits, c.attn_softcap)
+                mask = jnp.where(is_local, local_mask, global_mask)
+                logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
+                weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+                attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
+        x = attn_out_block(c, lp, x, attn.reshape(batch, span, h * hd))
+        x = ffn_block(c, lp, x)
 
         return x, (keys if k_cache_l is not None else None,
                    values if k_cache_l is not None else None)
 
     layer_params = params["layers"]
     if cache is None:
-        x, _ = jax.lax.scan(
-            lambda carry, xs: (
-                layer_step(carry, (xs[0], None, None, xs[1]))[0],
-                None,
-            ),
-            x,
-            (layer_params, local_flags),
-        )
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(
+                lambda carry, xs: (
+                    layer_step(carry, (xs[0], None, None, xs[1]))[0],
+                    None,
+                ),
+                x,
+                (layer_params, local_flags),
+            )
         new_cache = None
     else:
         def scan_fn(carry, xs):
@@ -375,12 +413,13 @@ def forward(
             new_x, (nk, nv) = layer_step(carry, (lp, kc, vc, flag))
             return new_x, (nk, nv)
 
-        x, (new_k, new_v) = jax.lax.scan(
-            scan_fn, x, (layer_params, cache.k, cache.v, local_flags)
-        )
+        with jax.named_scope("layers"):
+            x, (new_k, new_v) = jax.lax.scan(
+                scan_fn, x, (layer_params, cache.k, cache.v, local_flags)
+            )
         new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions, key_valid=k_valid)
 
-    x = rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
+    x = final_norm(params, c, x)
     if return_hidden:
         return x, new_cache
     return project_logits(params, c, x), new_cache
@@ -471,9 +510,7 @@ def forward_trunk_tail(
     def block_width(block) -> int:
         return (block[0] if isinstance(block, tuple) else block).shape[2]
 
-    x = take_rows(params["embed"], tokens)  # (Rows, D)
-    if c.scale_embeddings:
-        x = x * jnp.asarray(c.d_model**0.5, x.dtype)
+    x = embed_tokens(params, c, tokens)  # (Rows, D)
 
     qp = positions.reshape(n_slots, n_roles)  # (P, R)
     # Trunk masks: (P, R, W0) — every valid trunk key precedes the query.
@@ -512,174 +549,164 @@ def forward_trunk_tail(
     def layer_step(x, scanned):
         lp, k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail, is_local = scanned
 
-        attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-        q = matmul(attn_in, lp["wq"]).reshape(rows, 1, h, hd)
-        k = matmul(attn_in, lp["wk"]).reshape(rows, 1, kv, hd)
-        v = matmul(attn_in, lp["wv"]).reshape(rows, 1, kv, hd)
-        q = apply_rope(q, positions[:, None], c.rope_theta, c.rope_scaling)
-        k = apply_rope(k, positions[:, None], c.rope_theta, c.rope_scaling)
+        with jax.named_scope("attn_qkv"):
+            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
+            q = matmul(attn_in, lp["wq"]).reshape(rows, 1, h, hd)
+            k = matmul(attn_in, lp["wk"]).reshape(rows, 1, kv, hd)
+            v = matmul(attn_in, lp["wv"]).reshape(rows, 1, kv, hd)
+            q = apply_rope(q, positions[:, None], c.rope_theta, c.rope_scaling)
+            k = apply_rope(k, positions[:, None], c.rope_theta, c.rope_scaling)
 
-        if tail_quantized:
-            qk, ks = quantize_kv(k)
-            qv, vs = quantize_kv(v)
-            new_k_tail = (
-                jax.lax.dynamic_update_slice(k_tail[0], qk, (0, write_col, 0, 0)),
-                jax.lax.dynamic_update_slice(k_tail[1], ks, (0, write_col, 0, 0)),
-            )
-            new_v_tail = (
-                jax.lax.dynamic_update_slice(v_tail[0], qv, (0, write_col, 0, 0)),
-                jax.lax.dynamic_update_slice(v_tail[1], vs, (0, write_col, 0, 0)),
-            )
-        else:
-            new_k_tail = jax.lax.dynamic_update_slice(
-                k_tail, k, (0, write_col, 0, 0)
-            )
-            new_v_tail = jax.lax.dynamic_update_slice(
-                v_tail, v, (0, write_col, 0, 0)
-            )
+        with jax.named_scope("kv_write"):
+            if tail_quantized:
+                qk, ks = quantize_kv(k)
+                qv, vs = quantize_kv(v)
+                new_k_tail = (
+                    jax.lax.dynamic_update_slice(k_tail[0], qk, (0, write_col, 0, 0)),
+                    jax.lax.dynamic_update_slice(k_tail[1], ks, (0, write_col, 0, 0)),
+                )
+                new_v_tail = (
+                    jax.lax.dynamic_update_slice(v_tail[0], qv, (0, write_col, 0, 0)),
+                    jax.lax.dynamic_update_slice(v_tail[1], vs, (0, write_col, 0, 0)),
+                )
+            else:
+                new_k_tail = jax.lax.dynamic_update_slice(
+                    k_tail, k, (0, write_col, 0, 0)
+                )
+                new_v_tail = jax.lax.dynamic_update_slice(
+                    v_tail, v, (0, write_col, 0, 0)
+                )
 
-        if (
-            c.use_decode_attention
-            and use_decode_kernel
-            and not frozen_k
-            and not tail_quantized
-            and not trunk_quantized
-        ):
-            # Fused pallas kernel (ops/decode_attention.py): one VMEM pass
-            # per (role, kv-head) instead of four einsums with an fp32
-            # logits intermediate.  Session call sites guarantee per-role
-            # query positions (slots advance in lockstep) — qpos from slot
-            # 0's rows; trunk spans from key_valid (left-padded prefills).
-            from consensus_tpu.ops.decode_attention import decode_attention
+        with jax.named_scope("attention"):
+            if (
+                c.use_decode_attention
+                and use_decode_kernel
+                and not frozen_k
+                and not tail_quantized
+                and not trunk_quantized
+            ):
+                # Fused pallas kernel (ops/decode_attention.py): one VMEM pass
+                # per (role, kv-head) instead of four einsums with an fp32
+                # logits intermediate.  Session call sites guarantee per-role
+                # query positions (slots advance in lockstep) — qpos from slot
+                # 0's rows; trunk spans from key_valid (left-padded prefills).
+                from consensus_tpu.ops.decode_attention import decode_attention
 
-            interp = jax.default_backend() == "cpu"
-            starts = jnp.argmax(trunk.key_valid, axis=1).astype(jnp.int32)
-            qpos_r = positions.reshape(n_slots, n_roles)[0]
+                interp = jax.default_backend() == "cpu"
+                starts = jnp.argmax(trunk.key_valid, axis=1).astype(jnp.int32)
+                qpos_r = positions.reshape(n_slots, n_roles)[0]
 
-            def call_decode(win):
-                def fn(operands):
-                    qq, tk, tv, lk, lv = operands
-                    return decode_attention(
-                        qq, tk, tv, lk, lv, starts, qpos_r, write_col,
-                        n_slots=n_slots, n_roles=n_roles, scale=c.q_scale,
-                        softcap=c.attn_softcap, window=win,
-                        interpret=interp,
+                def call_decode(win):
+                    def fn(operands):
+                        qq, tk, tv, lk, lv = operands
+                        return decode_attention(
+                            qq, tk, tv, lk, lv, starts, qpos_r, write_col,
+                            n_slots=n_slots, n_roles=n_roles, scale=c.q_scale,
+                            softcap=c.attn_softcap, window=win,
+                            interpret=interp,
+                        )
+                    return fn
+
+                operands = (q[:, 0], k_trunk, v_trunk, new_k_tail, new_v_tail)
+                if c.sliding_window is None:
+                    attn = call_decode(None)(operands)
+                else:
+                    attn = jax.lax.cond(
+                        is_local,
+                        call_decode(c.sliding_window),
+                        call_decode(None),
+                        operands,
                     )
-                return fn
-
-            operands = (q[:, 0], k_trunk, v_trunk, new_k_tail, new_v_tail)
-            if c.sliding_window is None:
-                attn = call_decode(None)(operands)
+                attn = attn.astype(x.dtype)
             else:
-                attn = jax.lax.cond(
-                    is_local,
-                    call_decode(c.sliding_window),
-                    call_decode(None),
-                    operands,
-                )
-            attn = attn.astype(x.dtype)
-        else:
-            qg = q.reshape(n_slots, n_roles, kv, reps, hd)
+                qg = q.reshape(n_slots, n_roles, kv, reps, hd)
 
-            def key_logits(block, width):
-                """(P,R,g,m,width) attention logits for one generated-KV
-                block, dequantizing int8 via the per-(token, head) scale."""
-                quantized = isinstance(block, tuple)
-                values = block[0] if quantized else block
-                kg = values.astype(x.dtype).reshape(
-                    n_slots, n_roles, width, kv, hd
-                )
-                lg = jnp.einsum("prgmd,prtgd->prgmt", qg, kg).astype(jnp.float32)
-                if quantized:
-                    # Scales are per (row, token, head): (Rows, F, g, 1) ->
-                    # (P, R, g, 1, F) against lg's (p, r, g, m, t).
-                    s = block[1].reshape(n_slots, n_roles, width, kv)
-                    lg = lg * s.transpose(0, 1, 3, 2)[:, :, :, None, :]
-                return lg
+                def key_logits(block, width):
+                    """(P,R,g,m,width) attention logits for one generated-KV
+                    block, dequantizing int8 via the per-(token, head) scale."""
+                    quantized = isinstance(block, tuple)
+                    values = block[0] if quantized else block
+                    kg = values.astype(x.dtype).reshape(
+                        n_slots, n_roles, width, kv, hd
+                    )
+                    lg = jnp.einsum("prgmd,prtgd->prgmt", qg, kg).astype(jnp.float32)
+                    if quantized:
+                        # Scales are per (row, token, head): (Rows, F, g, 1) ->
+                        # (P, R, g, 1, F) against lg's (p, r, g, m, t).
+                        s = block[1].reshape(n_slots, n_roles, width, kv)
+                        lg = lg * s.transpose(0, 1, 3, 2)[:, :, :, None, :]
+                    return lg
 
-            def value_attend(block, width, w):
-                """Weighted value sum for one generated-KV block; value
-                scales fold into the f32 weights, the dot runs int8."""
-                quantized = isinstance(block, tuple)
-                values = block[0] if quantized else block
-                vg = values.astype(x.dtype).reshape(
-                    n_slots, n_roles, width, kv, hd
+                def value_attend(block, width, w):
+                    """Weighted value sum for one generated-KV block; value
+                    scales fold into the f32 weights, the dot runs int8."""
+                    quantized = isinstance(block, tuple)
+                    values = block[0] if quantized else block
+                    vg = values.astype(x.dtype).reshape(
+                        n_slots, n_roles, width, kv, hd
+                    )
+                    if quantized:
+                        s = block[1].reshape(n_slots, n_roles, width, kv)
+                        w = (
+                            w.astype(jnp.float32)
+                            * s.transpose(0, 1, 3, 2)[:, :, :, None, :]
+                        ).astype(x.dtype)
+                    return jnp.einsum("prgmt,prtgd->prgmd", w, vg)
+
+                # Trunk attention broadcasts the shared (R, W0) keys over slots.
+                # A quantized trunk (classic-layout segmented decodes under
+                # kv_quant: the per-row prompt cache is the dominant per-step
+                # read) dequantizes exactly like the generated-KV blocks, with
+                # the (R, W0, kv) scales broadcast over slots.
+                if trunk_quantized:
+                    lt = jnp.einsum(
+                        "prgmd,rtgd->prgmt", qg, k_trunk[0].astype(x.dtype)
+                    ).astype(jnp.float32)
+                    st = k_trunk[1][..., 0]  # (R, W0, kv)
+                    lt = lt * st.transpose(0, 2, 1)[None, :, :, None, :]
+                else:
+                    lt = jnp.einsum(
+                        "prgmd,rtgd->prgmt", qg, k_trunk
+                    ).astype(jnp.float32)
+                # Chronological key order [trunk, frozen blocks..., tail].
+                widths = frozen_widths + [t_tail]
+                blocks = [lt] + [
+                    key_logits(b, w) for b, w in zip(froz_k, frozen_widths)
+                ] + [key_logits(new_k_tail, t_tail)]
+                masks = (
+                    [jnp.where(is_local, trunk_local, trunk_mask)]
+                    + [
+                        jnp.where(is_local, fl, fm)
+                        for fl, fm in zip(frozen_locals, frozen_masks)
+                    ]
+                    + [jnp.where(is_local, tail_local, tail_mask)]
                 )
-                if quantized:
-                    s = block[1].reshape(n_slots, n_roles, width, kv)
-                    w = (
-                        w.astype(jnp.float32)
-                        * s.transpose(0, 1, 3, 2)[:, :, :, None, :]
+                logits = jnp.concatenate(blocks, axis=-1) * c.q_scale
+                logits = _softcap(logits, c.attn_softcap)
+                mask = jnp.concatenate(masks, axis=-1)[:, :, None, None]
+                logits = jnp.where(mask, logits, MASK_FILL)
+                weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+                w0 = (k_trunk[0] if trunk_quantized else k_trunk).shape[1]
+                wt = weights[..., :w0]
+                if trunk_quantized:
+                    sv = v_trunk[1][..., 0]  # (R, W0, kv)
+                    wt = (
+                        wt.astype(jnp.float32)
+                        * sv.transpose(0, 2, 1)[None, :, :, None, :]
                     ).astype(x.dtype)
-                return jnp.einsum("prgmt,prtgd->prgmd", w, vg)
-
-            # Trunk attention broadcasts the shared (R, W0) keys over slots.
-            # A quantized trunk (classic-layout segmented decodes under
-            # kv_quant: the per-row prompt cache is the dominant per-step
-            # read) dequantizes exactly like the generated-KV blocks, with
-            # the (R, W0, kv) scales broadcast over slots.
-            if trunk_quantized:
-                lt = jnp.einsum(
-                    "prgmd,rtgd->prgmt", qg, k_trunk[0].astype(x.dtype)
-                ).astype(jnp.float32)
-                st = k_trunk[1][..., 0]  # (R, W0, kv)
-                lt = lt * st.transpose(0, 2, 1)[None, :, :, None, :]
-            else:
-                lt = jnp.einsum(
-                    "prgmd,rtgd->prgmt", qg, k_trunk
-                ).astype(jnp.float32)
-            # Chronological key order [trunk, frozen blocks..., tail].
-            widths = frozen_widths + [t_tail]
-            blocks = [lt] + [
-                key_logits(b, w) for b, w in zip(froz_k, frozen_widths)
-            ] + [key_logits(new_k_tail, t_tail)]
-            masks = (
-                [jnp.where(is_local, trunk_local, trunk_mask)]
-                + [
-                    jnp.where(is_local, fl, fm)
-                    for fl, fm in zip(frozen_locals, frozen_masks)
-                ]
-                + [jnp.where(is_local, tail_local, tail_mask)]
-            )
-            logits = jnp.concatenate(blocks, axis=-1) * c.q_scale
-            logits = _softcap(logits, c.attn_softcap)
-            mask = jnp.concatenate(masks, axis=-1)[:, :, None, None]
-            logits = jnp.where(mask, logits, MASK_FILL)
-            weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-            w0 = (k_trunk[0] if trunk_quantized else k_trunk).shape[1]
-            wt = weights[..., :w0]
-            if trunk_quantized:
-                sv = v_trunk[1][..., 0]  # (R, W0, kv)
-                wt = (
-                    wt.astype(jnp.float32)
-                    * sv.transpose(0, 2, 1)[None, :, :, None, :]
-                ).astype(x.dtype)
-                attn = jnp.einsum(
-                    "prgmt,rtgd->prgmd", wt, v_trunk[0].astype(x.dtype)
-                )
-            else:
-                attn = jnp.einsum("prgmt,rtgd->prgmd", wt, v_trunk)
-            offset = w0
-            for block, width in zip(tuple(froz_v) + (new_v_tail,), widths):
-                attn = attn + value_attend(
-                    block, width, weights[..., offset : offset + width]
-                )
-                offset += width
-        attn = matmul(attn.reshape(rows, h * hd), lp["wo"])
-        if c.use_post_norms:
-            attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + attn
-
-        ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        gate = matmul(ffn_in, lp["w_gate"])
-        if c.activation == "geglu":
-            gate = jax.nn.gelu(gate, approximate=True)
-        else:
-            gate = jax.nn.silu(gate)
-        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
-        if c.use_post_norms:
-            ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        return x + ffn, (new_k_tail, new_v_tail)
+                    attn = jnp.einsum(
+                        "prgmt,rtgd->prgmd", wt, v_trunk[0].astype(x.dtype)
+                    )
+                else:
+                    attn = jnp.einsum("prgmt,rtgd->prgmd", wt, v_trunk)
+                offset = w0
+                for block, width in zip(tuple(froz_v) + (new_v_tail,), widths):
+                    attn = attn + value_attend(
+                        block, width, weights[..., offset : offset + width]
+                    )
+                    offset += width
+        x = attn_out_block(c, lp, x, attn.reshape(rows, h * hd))
+        return ffn_block(c, lp, x), (new_k_tail, new_v_tail)
 
     # One scanned pytree serves every variant: lax.scan slices each leaf
     # along the layer axis, including nested (int8, scale) pairs and the
@@ -688,8 +715,9 @@ def forward_trunk_tail(
         params["layers"], trunk.k, trunk.v, frozen_k, frozen_v,
         tail_k, tail_v, local_flags,
     )
-    x, (new_tail_k, new_tail_v) = jax.lax.scan(layer_step, x, scanned)
-    x = rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
+    with jax.named_scope("layers"):
+        x, (new_tail_k, new_tail_v) = jax.lax.scan(layer_step, x, scanned)
+    x = final_norm(params, c, x)
     return x, new_tail_k, new_tail_v
 
 
@@ -727,9 +755,7 @@ def forward_shared_trunk(
     reps = h // kv
     n_roles = cache.key_valid.shape[0]
 
-    x = take_rows(params["embed"], suffix_tokens)  # (P, L, D)
-    if c.scale_embeddings:
-        x = x * jnp.asarray(c.d_model**0.5, x.dtype)
+    x = embed_tokens(params, c, suffix_tokens)  # (P, L, D)
     x = jnp.broadcast_to(x[:, None], (n_paths, n_roles) + x.shape[1:])  # (P,R,L,D)
 
     # Suffix positions continue each role's trunk: (R, L).
@@ -760,60 +786,51 @@ def forward_shared_trunk(
     def layer_step(x, scanned):
         lp, k_trunk, v_trunk, is_local = scanned  # k/v_trunk: (R, T, kv, hd)
 
-        attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-        flat = attn_in.reshape(n_paths * n_roles, span, -1)
-        q = matmul(flat, lp["wq"]).reshape(n_paths * n_roles, span, h, hd)
-        ks = matmul(flat, lp["wk"]).reshape(n_paths * n_roles, span, kv, hd)
-        vs = matmul(flat, lp["wv"]).reshape(n_paths * n_roles, span, kv, hd)
-        rope_pos = jnp.tile(positions, (n_paths, 1))  # (P*R, L)
-        q = apply_rope(q, rope_pos, c.rope_theta, c.rope_scaling)
-        ks = apply_rope(ks, rope_pos, c.rope_theta, c.rope_scaling)
-        qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
-        ks = ks.reshape(n_paths, n_roles, span, kv, hd)
-        vs = vs.reshape(n_paths, n_roles, span, kv, hd)
+        with jax.named_scope("attn_qkv"):
+            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
+            flat = attn_in.reshape(n_paths * n_roles, span, -1)
+            q = matmul(flat, lp["wq"]).reshape(n_paths * n_roles, span, h, hd)
+            ks = matmul(flat, lp["wk"]).reshape(n_paths * n_roles, span, kv, hd)
+            vs = matmul(flat, lp["wv"]).reshape(n_paths * n_roles, span, kv, hd)
+            rope_pos = jnp.tile(positions, (n_paths, 1))  # (P*R, L)
+            q = apply_rope(q, rope_pos, c.rope_theta, c.rope_scaling)
+            ks = apply_rope(ks, rope_pos, c.rope_theta, c.rope_scaling)
+            qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
+            ks = ks.reshape(n_paths, n_roles, span, kv, hd)
+            vs = vs.reshape(n_paths, n_roles, span, kv, hd)
 
-        # Trunk attention broadcasts the shared (R, T) keys over paths.
-        lt = jnp.einsum("prsgmd,rtgd->prgmst", qg, k_trunk).astype(jnp.float32)
-        ls = jnp.einsum("prsgmd,prtgd->prgmst", qg, ks).astype(jnp.float32)
-        logits = jnp.concatenate([lt, ls], axis=-1) * c.q_scale
-        logits = _softcap(logits, c.attn_softcap)
-        t_mask = jnp.where(is_local, trunk_local, trunk_mask)
-        s_mask = jnp.where(
-            is_local, suffix_local, jnp.broadcast_to(
-                suffix_causal[None], suffix_local.shape
+        with jax.named_scope("attention"):
+            # Trunk attention broadcasts the shared (R, T) keys over paths.
+            lt = jnp.einsum("prsgmd,rtgd->prgmst", qg, k_trunk).astype(jnp.float32)
+            ls = jnp.einsum("prsgmd,prtgd->prgmst", qg, ks).astype(jnp.float32)
+            logits = jnp.concatenate([lt, ls], axis=-1) * c.q_scale
+            logits = _softcap(logits, c.attn_softcap)
+            t_mask = jnp.where(is_local, trunk_local, trunk_mask)
+            s_mask = jnp.where(
+                is_local, suffix_local, jnp.broadcast_to(
+                    suffix_causal[None], suffix_local.shape
+                )
             )
-        )
-        mask = jnp.concatenate(
-            [t_mask, s_mask], axis=-1
-        )[None, :, None, None]  # (1, R, 1, 1, L, T+L)
-        logits = jnp.where(mask, logits, MASK_FILL)
-        weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        t_len = k_trunk.shape[1]
-        attn = jnp.einsum(
-            "prgmst,rtgd->prsgmd", weights[..., :t_len], v_trunk
-        ) + jnp.einsum(
-            "prgmst,prtgd->prsgmd", weights[..., t_len:], vs
-        )
-        attn = matmul(attn.reshape(n_paths, n_roles, span, h * hd), lp["wo"])
-        if c.use_post_norms:
-            attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
-        x = x + attn
+            mask = jnp.concatenate(
+                [t_mask, s_mask], axis=-1
+            )[None, :, None, None]  # (1, R, 1, 1, L, T+L)
+            logits = jnp.where(mask, logits, MASK_FILL)
+            weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+            t_len = k_trunk.shape[1]
+            attn = jnp.einsum(
+                "prgmst,rtgd->prsgmd", weights[..., :t_len], v_trunk
+            ) + jnp.einsum(
+                "prgmst,prtgd->prsgmd", weights[..., t_len:], vs
+            )
+        x = attn_out_block(
+            c, lp, x, attn.reshape(n_paths, n_roles, span, h * hd))
+        return ffn_block(c, lp, x), ((ks, vs) if return_suffix_kv else None)
 
-        ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        gate = matmul(ffn_in, lp["w_gate"])
-        if c.activation == "geglu":
-            gate = jax.nn.gelu(gate, approximate=True)
-        else:
-            gate = jax.nn.silu(gate)
-        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
-        if c.use_post_norms:
-            ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        return x + ffn, ((ks, vs) if return_suffix_kv else None)
-
-    x, suffix_kv = jax.lax.scan(
-        layer_step, x, (params["layers"], cache.k, cache.v, local_flags)
-    )
-    x = rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
+    with jax.named_scope("layers"):
+        x, suffix_kv = jax.lax.scan(
+            layer_step, x, (params["layers"], cache.k, cache.v, local_flags)
+        )
+    x = final_norm(params, c, x)
     if return_all_positions:
         out = x  # (P, R, L, D) — the shared-context scorer needs every slot
     else:
@@ -832,8 +849,9 @@ def project_logits(params: Params, config: ModelConfig, hidden: jax.Array) -> ja
     """Head-project hidden states (..., D) -> float32 logits (..., V), with
     the model's final softcap.  Callers slice hidden down (e.g. to the last
     position) BEFORE projecting so a (B, S, 256k) tensor never materializes."""
-    head = params["embed"] if config.tie_lm_head else params["lm_head"]
-    return _softcap(head_matmul(hidden, head), config.final_softcap)
+    with jax.named_scope("vocab_projection"):
+        head = params["embed"] if config.tie_lm_head else params["lm_head"]
+        return _softcap(head_matmul(hidden, head), config.final_softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -854,11 +872,12 @@ def token_logprobs(
     """
     positions = jnp.maximum(jnp.cumsum(valid.astype(jnp.int32), axis=1) - 1, 0)
     logits, _ = forward(params, config, tokens, positions, valid)
-    logprobs = jax.nn.log_softmax(logits, axis=-1)
-    gathered = jnp.take_along_axis(
-        logprobs[:, :-1, :], tokens[:, 1:, None], axis=-1
-    )[..., 0]
-    return jnp.pad(gathered, ((0, 0), (1, 0)))
+    with jax.named_scope("logsumexp"):
+        logprobs = jax.nn.log_softmax(logits, axis=-1)
+        gathered = jnp.take_along_axis(
+            logprobs[:, :-1, :], tokens[:, 1:, None], axis=-1
+        )[..., 0]
+        return jnp.pad(gathered, ((0, 0), (1, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("config", "vocab_chunk"))
@@ -906,25 +925,27 @@ def _streamed_target_logprobs(
 
     def tile_step(carry, i):
         run_max, run_sum = carry
-        start = jnp.maximum(jnp.minimum(i * vocab_chunk, vocab - vocab_chunk), 0)
-        rows, row_scales = slice_rows(head, start, min(vocab_chunk, vocab))
-        tile = jnp.einsum(
-            "bsd,vd->bsv",
-            x,
-            rows.astype(x.dtype) if row_scales is not None else rows,
-            preferred_element_type=jnp.float32,
-        )
-        if row_scales is not None:
-            tile = tile * row_scales[:, 0][None, None, :]
-        tile = _softcap(tile, c.final_softcap)
-        row_ids = start + jnp.arange(rows.shape[0])
-        fresh = (row_ids >= i * vocab_chunk) & (row_ids < vocab)
-        tile = jnp.where(fresh[None, None, :], tile, -jnp.inf)
-        tile_max = jnp.max(tile, axis=-1)
-        new_max = jnp.maximum(run_max, tile_max)
-        run_sum = run_sum * jnp.exp(run_max - new_max) + jnp.sum(
-            jnp.exp(tile - new_max[..., None]), axis=-1
-        )
+        with jax.named_scope("vocab_projection"):
+            start = jnp.maximum(jnp.minimum(i * vocab_chunk, vocab - vocab_chunk), 0)
+            rows, row_scales = slice_rows(head, start, min(vocab_chunk, vocab))
+            tile = jnp.einsum(
+                "bsd,vd->bsv",
+                x,
+                rows.astype(x.dtype) if row_scales is not None else rows,
+                preferred_element_type=jnp.float32,
+            )
+            if row_scales is not None:
+                tile = tile * row_scales[:, 0][None, None, :]
+            tile = _softcap(tile, c.final_softcap)
+        with jax.named_scope("logsumexp"):
+            row_ids = start + jnp.arange(rows.shape[0])
+            fresh = (row_ids >= i * vocab_chunk) & (row_ids < vocab)
+            tile = jnp.where(fresh[None, None, :], tile, -jnp.inf)
+            tile_max = jnp.max(tile, axis=-1)
+            new_max = jnp.maximum(run_max, tile_max)
+            run_sum = run_sum * jnp.exp(run_max - new_max) + jnp.sum(
+                jnp.exp(tile - new_max[..., None]), axis=-1
+            )
         return (new_max, run_sum), None
 
     init = (
@@ -932,11 +953,12 @@ def _streamed_target_logprobs(
         jnp.zeros((batch, span), jnp.float32),
     )
     (run_max, run_sum), _ = jax.lax.scan(tile_step, init, jnp.arange(n_chunks))
-    lse = run_max + jnp.log(run_sum)
-    target_logits = _softcap(
-        gather_target_logits(x, head, targets), c.final_softcap
-    )
-    return target_logits - lse
+    with jax.named_scope("logsumexp"):
+        lse = run_max + jnp.log(run_sum)
+        target_logits = _softcap(
+            gather_target_logits(x, head, targets), c.final_softcap
+        )
+        return target_logits - lse
 
 
 @functools.partial(jax.jit, static_argnames=("config", "vocab_chunk"))

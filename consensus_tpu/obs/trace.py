@@ -13,6 +13,11 @@ This module is the observability layer ISSUE-14 asks for:
   sites that cannot grow new parameters (``scheduler.submit``,
   ``engine.submit``) can pick up the active (trace, parent span) pair.
 
+* ``span(name)`` — the one primitive that names a stretch of host work:
+  a child span in the request's tree AND a ``jax.profiler.TraceAnnotation``
+  on the profiler's host plane, so a device trace and the span tree tell
+  the same story.  Names come from ``HOST_SPANS``.
+
 * ``TraceStore`` — a bounded LRU of recent traces backing
   ``GET /v1/trace/<id>``.
 
@@ -30,14 +35,16 @@ This module is the observability layer ISSUE-14 asks for:
 * ``RollingWindow`` — time-bucketed rps/p95/availability so loadgen can
   report recovery *curves* for chaos and elastic runs.
 
-Everything here is pure stdlib and thread-safe; nothing raises into the
-serving path.
+Everything here is pure stdlib at import (``span`` takes
+``jax.profiler`` only once JAX is loaded) and thread-safe; nothing raises
+into the serving path.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -49,6 +56,8 @@ __all__ = [
     "get_trace_store",
     "trace_current",
     "use_trace",
+    "span",
+    "HOST_SPANS",
     "IterationLedger",
     "FlightRecorder",
     "get_flight_recorder",
@@ -70,8 +79,30 @@ _PHASE_PRIORITY = (
     "prefill",
     "admission_wait",
     "score",
+    "engine_wait",
     "queue_wait",
     "failover_overhead",
+)
+
+#: Every name ``span()`` is called with, ``<layer>.<what>``.  The profiler's
+#: host plane and the benchmark's metric files use the same names; tests
+#: hold the call sites and the metric files to this tuple.
+HOST_SPANS = (
+    "serve.parse", "serve.respond", "serve.method", "serve.evaluate",
+    "method.render", "method.generate", "method.score", "method.select",
+    "engine.enqueue", "engine.idle", "engine.iteration", "engine.admit",
+    "engine.prefill", "engine.cohort", "engine.dispatch", "engine.merge",
+    "backend.tokenize", "backend.layout", "backend.h2d", "backend.launch",
+    "backend.d2h", "backend.detokenize",
+)
+
+#: The engine's spans (``engine_<kind>``) of calls that are scored, not
+#: decoded row by row.
+_SCORE_SPANS = (
+    "engine_score",
+    "engine_embed",
+    "engine_next_token",
+    "engine_score_matrix",
 )
 
 
@@ -260,21 +291,30 @@ class TraceContext:
                 events = {e["name"]: e["t"] for e in s["events"]}
                 admitted = events.get("slot_admitted")
                 prefilled = events.get("prefill_complete")
+                dispatched = events.get("decode_dispatch")
                 row_end = _end(s)
                 if admitted is not None:
                     _add("admission_wait", s["t_start"], admitted)
                     _add("prefill", admitted, prefilled if prefilled is not None else row_end)
                     if prefilled is not None:
-                        _add("decode", prefilled, row_end)
+                        # A prefilled row waits for its cohort's dispatch:
+                        # that wait is the engine's, not the device's.
+                        decoding = dispatched if dispatched is not None else prefilled
+                        _add("engine_wait", prefilled, decoding)
+                        _add("decode", decoding, row_end)
                 else:
                     _add("admission_wait", s["t_start"], row_end)
-            elif s["name"] in (
-                "engine_score",
-                "engine_embed",
-                "engine_next_token_logprobs",
-                "engine_score_matrix",
-            ):
-                _add("score", s["t_start"], _end(s))
+            elif s["name"] in _SCORE_SPANS:
+                # The call waits in the engine until a dispatch takes it up;
+                # only the dispatch itself is scoring.
+                runs = [
+                    c for c in children.get(s["id"], ())
+                    if c["name"] == "engine.dispatch"
+                ]
+                first = min((c["t_start"] for c in runs), default=_end(s))
+                _add("engine_wait", s["t_start"], first)
+                for c in runs:
+                    _add("score", c["t_start"], _end(c))
         if final is not None and len(dispatches) > 1:
             first = min(dispatches, key=lambda s: s["t_start"])
             _add("failover_overhead", first["t_start"], final["t_start"])
@@ -304,30 +344,84 @@ class TraceContext:
 
 _tls = threading.local()
 
+#: One (trace, parent span id) pair per request this thread is working for:
+#: one on a request's own threads, several on the engine thread inside a
+#: dispatch that was merged from several requests' calls.
+_Pairs = List[Tuple[TraceContext, Optional[int]]]
+
 
 def trace_current() -> Optional[Tuple[TraceContext, Optional[int]]]:
     """The active (trace, parent span id) pair for this thread, if any."""
-    return getattr(_tls, "active", None)
+    active = getattr(_tls, "active", None)
+    return active[0] if active else None
 
 
 @contextlib.contextmanager
-def use_trace(
-    trace: Optional[TraceContext], parent: Optional[int] = None
-) -> Iterator[None]:
+def _carry(pairs: _Pairs) -> Iterator[None]:
+    prev = getattr(_tls, "active", None)
+    _tls.active = pairs
+    try:
+        yield
+    finally:
+        _tls.active = prev
+
+
+def use_trace(trace: Optional[TraceContext], parent: Optional[int] = None):
     """Establish (trace, parent) as this thread's active trace context.
 
     A ``None`` trace makes this a passthrough, so call sites can wrap
     unconditionally.
     """
     if trace is None:
-        yield
-        return
-    prev = getattr(_tls, "active", None)
-    _tls.active = (trace, parent)
+        return contextlib.nullcontext()
+    return _carry([(trace, parent)])
+
+
+_annotation_class = None
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """A ``jax.profiler.TraceAnnotation``: with no profiler session, one
+    atomic load on enter.  JAX is taken from ``sys.modules`` and never
+    imported from here: a process that has not loaded it cannot be
+    profiling."""
+    global _annotation_class
+    if _annotation_class is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        from jax.profiler import TraceAnnotation
+
+        _annotation_class = TraceAnnotation
+    return _annotation_class(name, **attrs)
+
+
+@contextlib.contextmanager
+def span(name: str, traces: Optional[_Pairs] = None, **attrs: Any) -> Iterator[None]:
+    """Name the host work inside the ``with``: ``name`` is one of
+    ``HOST_SPANS``, ``attrs`` are numbers and short strings.
+
+    Where this thread works for a request (``use_trace``, or an enclosing
+    ``span``), a child span is begun and ended in that request's tree and
+    is the parent of whatever runs inside.  ``traces=[(trace, parent), ...]``
+    names the requests instead: the engine thread's merged dispatch serves
+    several at once and writes the same interval into each.  Always, the
+    stretch is a ``TraceAnnotation`` on the profiler's host plane; with no
+    profiler session that is the whole cost.
+    """
+    if traces is None:
+        traces = getattr(_tls, "active", None) or []
+    opened = [
+        (trace, trace.begin(name, parent=parent, **attrs), parent)
+        for trace, parent in traces
+    ]
     try:
-        yield
+        # A span the cap dropped (id 0) leaves its parent in charge.
+        with _carry([(t, sid or parent) for t, sid, parent in opened]), \
+                _annotation(name, attrs):
+            yield
     finally:
-        _tls.active = prev
+        for trace, sid, _ in opened:
+            trace.end(sid)
 
 
 # ---------------------------------------------------------------------------

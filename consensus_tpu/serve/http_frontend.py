@@ -48,7 +48,12 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
 from consensus_tpu.obs.metrics import Registry, get_registry
-from consensus_tpu.obs.trace import TraceContext, get_trace_store, use_trace
+from consensus_tpu.obs.trace import (
+    TraceContext,
+    get_trace_store,
+    span,
+    use_trace,
+)
 from consensus_tpu.serve.scheduler import (
     RequestScheduler,
     RequestTimeout,
@@ -182,37 +187,42 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
             self._send_error_json(404, "not_found",
                                   f"no route for POST {self.path}")
             return
-        try:
-            payload = self._read_json()
-        except ValueError as exc:
-            self._send_error_json(400, "bad_json", str(exc))
-            return
-        try:
-            request = parse_request(payload)
-        except RequestValidationError as exc:
-            # Even a rejected-at-the-door request gets a request id (the
-            # client's own, else a minted one): EVERY structured error
-            # response is trace-addressable.
-            supplied = (
-                str(payload.get("request_id") or "")
-                if isinstance(payload, dict) else ""
-            )
-            self._send_json(400, {"error": {
-                "type": "validation",
-                "message": "request failed validation",
-                "details": exc.errors,
-                "request_id": supplied or _mint_request_id(payload),
-            }})
-            return
+        # The trace starts before the body is read, so the root span covers
+        # what the client waits for; it gets its id once the request has one.
+        trace = TraceContext("")
+        root = trace.begin("http_request")
+        with use_trace(trace, root), span("serve.parse"):
+            try:
+                payload = self._read_json()
+            except ValueError as exc:
+                self._send_error_json(400, "bad_json", str(exc))
+                return
+            try:
+                request = parse_request(payload)
+            except RequestValidationError as exc:
+                # Even a rejected-at-the-door request gets a request id (the
+                # client's own, else a minted one): EVERY structured error
+                # response is trace-addressable.
+                supplied = (
+                    str(payload.get("request_id") or "")
+                    if isinstance(payload, dict) else ""
+                )
+                self._send_json(400, {"error": {
+                    "type": "validation",
+                    "message": "request failed validation",
+                    "details": exc.errors,
+                    "request_id": supplied or _mint_request_id(payload),
+                }})
+                return
         if not request.request_id:
             # Server-side mint: every response (success or error) carries a
             # request id, so every request is trace-addressable.
             request = dataclasses.replace(
                 request, request_id=_mint_request_id(payload))
         request_id = request.request_id
-        trace = TraceContext(request_id)
-        root = trace.begin(
-            "http_request", method=request.method, path=self.path,
+        trace.trace_id = request_id
+        trace.annotate(
+            root, method=request.method, path=self.path,
             request_id=request_id)
         get_trace_store().put(trace)
         scheduler = self.server.scheduler
@@ -272,17 +282,19 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
             status = 200
             degraded = isinstance(result, dict) and bool(
                 result.get("degraded"))
-            # End the root BEFORE snapshotting so the debug block's
-            # critical path covers the full served latency.
-            trace.end(root, status=200)
             if request.trace:
+                # End the root BEFORE snapshotting so the debug block's
+                # critical path covers the full served latency.  (Without
+                # the block the root ends after the response is written.)
+                trace.end(root, status=200)
                 result = dict(result)
                 result["trace"] = {
                     "trace_id": trace.trace_id,
                     "critical_path": trace.critical_path(),
                     "spans": trace.to_dict()["spans"],
                 }
-            self._send_json(200, result)
+            with use_trace(trace, root), span("serve.respond"):
+                self._send_json(200, result)
         finally:
             trace.end(root, status=status)
             engine = self.server.slo_engine

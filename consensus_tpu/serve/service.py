@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional
 from consensus_tpu.backends.base import Backend, RequestCancelled
 from consensus_tpu.methods import GENERATOR_MAP, get_method_generator
 from consensus_tpu.methods.anytime import BudgetClock
+from consensus_tpu.obs.trace import span
 
 #: Params that must be scalars of these types when present.
 _PARAM_SCALARS = (str, int, float, bool)
@@ -250,9 +251,10 @@ class ConsensusService:
         if budget_clock is not None:
             generator.budget_clock = budget_clock
         try:
-            statement = generator.generate_statement(
-                request.issue, request.agent_opinions
-            )
+            with span("serve.method", method=request.method):
+                statement = generator.generate_statement(
+                    request.issue, request.agent_opinions
+                )
         except RequestCancelled:
             # The batching layer dropped one of this request's device calls
             # (ticket cancelled before dispatch).  If a wave already
@@ -285,7 +287,9 @@ class ConsensusService:
             "deadline", "cancelled"
         ):
             try:
-                response.update(self._evaluate(request, statement, engine))
+                with span("serve.evaluate"):
+                    response.update(
+                        self._evaluate(request, statement, engine))
             except RequestCancelled:
                 response.setdefault("degraded", True)
                 response.setdefault("degraded_reason", "cancelled")

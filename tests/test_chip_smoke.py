@@ -140,6 +140,31 @@ def test_compile_cache_is_one_fixed_path_in_the_checkout(tmp_path, monkeypatch):
     assert proc.stdout.split() == [expected, expected], proc.stderr
 
 
+def test_compile_cache_keys_a_program_by_its_names_and_not_its_lines(monkeypatch):
+    """An executable read from the cache carries the names it was compiled
+    with, so the key holds them (a profile of a cache hit would otherwise
+    show an older build's scopes); it holds no file and no line, so moving
+    code compiles nothing again."""
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    compile_cache.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+
+    def program(scope):
+        def step(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x)
+        return jax.jit(step).lower(jnp.ones((8, 8)))
+
+    one, other = program("attention"), program("ffn")
+    assert one.as_text() == other.as_text()  # one program,
+    keyed = one.as_text(debug_info=True)  # two sets of names
+    assert keyed != other.as_text(debug_info=True)
+    assert "attention/dot_general" in keyed
+    assert ".py" not in keyed
+
+
 def test_peaks_table_has_no_default():
     assert mfu.device_peaks("TPU v5 lite").bf16_tflops == 197.0
     with pytest.raises(ValueError, match="no published peaks"):

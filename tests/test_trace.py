@@ -19,6 +19,8 @@ The acceptance proofs:
 """
 
 import json
+import pathlib
+import re
 import threading
 import time
 import urllib.error
@@ -36,6 +38,7 @@ from consensus_tpu.backends.faults import (
 )
 from consensus_tpu.obs.metrics import Registry
 from consensus_tpu.obs.trace import (
+    HOST_SPANS,
     MAX_SPANS_PER_TRACE,
     FlightRecorder,
     IterationLedger,
@@ -44,6 +47,7 @@ from consensus_tpu.obs.trace import (
     TraceStore,
     get_flight_recorder,
     get_trace_store,
+    span,
     trace_current,
     use_trace,
 )
@@ -165,15 +169,53 @@ class TestTraceContext:
         trace.end(row, outcome="retired")
         score = trace.begin("engine_score", parent=root)
         time.sleep(0.005)
+        # the call waits in the engine, then one dispatch takes it up
+        run = trace.begin("engine.dispatch", parent=score)
+        time.sleep(0.005)
+        trace.end(run)
         trace.end(score)
         trace.end(root)
         path = trace.critical_path()
         phases = path["phases"]
         assert abs(sum(phases.values()) - path["total_s"]) < 1e-4
         for name in ("queue_wait", "admission_wait", "prefill", "decode",
-                     "score"):
+                     "score", "engine_wait"):
             assert phases[name] > 0.0, name
         assert phases["failover_overhead"] == 0.0
+
+    def test_engine_wait_is_the_call_less_its_dispatches(self):
+        trace = TraceContext("t-5")
+        root = trace.begin("http_request")
+        call = trace.begin("engine_score_matrix", parent=root)
+        time.sleep(0.02)
+        run = trace.begin("engine.dispatch", parent=call)
+        time.sleep(0.01)
+        trace.end(run)
+        trace.end(call)
+        row = trace.begin("engine_row", parent=root)
+        trace.event(row, "slot_admitted")
+        trace.event(row, "prefill_complete")
+        time.sleep(0.02)  # prefilled, waiting for its cohort's dispatch
+        trace.event(row, "decode_dispatch")
+        time.sleep(0.01)
+        trace.end(row)
+        trace.end(root)
+        spans = {s["name"]: s for s in trace.to_dict()["spans"]}
+        phases = trace.critical_path()["phases"]
+        assert phases["score"] == pytest.approx(
+            spans["engine.dispatch"]["duration_s"], abs=1e-4)
+        # both waits, and neither is booked as device work any more
+        assert phases["engine_wait"] >= 0.038
+        assert phases["decode"] < 0.02
+        # a call that no dispatch took up waited, all of it
+        lost = TraceContext("t-6")
+        root = lost.begin("http_request")
+        call = lost.begin("engine_next_token", parent=root)
+        time.sleep(0.005)
+        lost.end(call)
+        lost.end(root)
+        phases = lost.critical_path()["phases"]
+        assert phases["score"] == 0.0 and phases["engine_wait"] > 0.0
 
 
 class TestUseTrace:
@@ -190,6 +232,67 @@ class TestUseTrace:
     def test_none_trace_is_passthrough(self):
         with use_trace(None, 7):
             assert trace_current() is None
+
+
+class TestSpan:
+    def test_child_of_the_active_trace_and_parent_of_what_runs_inside(self):
+        trace = TraceContext("s-1")
+        root = trace.begin("http_request")
+        with use_trace(trace, root):
+            with span("serve.method", method="best_of_n"):
+                inner_parent = trace_current()[1]
+                with span("method.render"):
+                    pass
+            assert trace_current() == (trace, root)
+        spans = {s["name"]: s for s in trace.to_dict()["spans"]}
+        assert spans["serve.method"]["parent"] == root
+        assert spans["serve.method"]["id"] == inner_parent
+        assert spans["method.render"]["parent"] == inner_parent
+        assert spans["serve.method"]["attrs"] == {"method": "best_of_n"}
+        assert not spans["serve.method"]["in_flight"]
+        assert not spans["method.render"]["in_flight"]
+
+    def test_without_a_trace_it_only_annotates(self):
+        assert trace_current() is None
+        with span("engine.iteration"):
+            assert trace_current() is None
+        assert trace_current() is None
+
+    def test_merged_work_writes_the_same_interval_into_each_trace(self):
+        first, second = TraceContext("s-2"), TraceContext("s-3")
+        a = first.begin("engine_embed")
+        b = second.begin("engine_embed")
+        with span("engine.dispatch", traces=[(first, a), (second, b)],
+                  kind="embed", rows=6):
+            with span("backend.launch", program="_embed_forward"):
+                time.sleep(0.002)
+        assert trace_current() is None
+        for trace, parent in ((first, a), (second, b)):
+            spans = {s["name"]: s for s in trace.to_dict()["spans"]}
+            assert spans["engine.dispatch"]["parent"] == parent
+            assert spans["engine.dispatch"]["attrs"] == {
+                "kind": "embed", "rows": 6}
+            assert (spans["backend.launch"]["parent"]
+                    == spans["engine.dispatch"]["id"])
+            assert spans["backend.launch"]["duration_s"] >= 0.002
+
+    def test_a_span_the_cap_dropped_leaves_its_parent_in_charge(self):
+        trace = TraceContext("s-4")
+        root = trace.begin("http_request")
+        for _ in range(MAX_SPANS_PER_TRACE - 1):
+            trace.begin("filler", parent=root)
+        with use_trace(trace, root), span("serve.method"):
+            assert trace_current() == (trace, root)
+        assert trace.dropped_spans == 1
+
+    def test_ends_its_span_when_the_body_raises(self):
+        trace = TraceContext("s-5")
+        root = trace.begin("http_request")
+        with pytest.raises(ValueError):
+            with use_trace(trace, root), span("serve.evaluate"):
+                raise ValueError("boom")
+        assert trace_current() is None
+        assert not trace.to_dict()["spans"][1]["in_flight"]
 
 
 class TestTraceStore:
@@ -542,6 +645,213 @@ class TestFailoverTrace:
                         if s["name"] == "dispatch"]) >= 2
         finally:
             server.stop(drain=False, timeout=5.0)
+
+
+# ---------------------------------------------------------------------------
+# One set of names: span() call sites, named_scope call sites, the tuples
+# ---------------------------------------------------------------------------
+
+_PACKAGE = pathlib.Path(__file__).resolve().parents[1] / "consensus_tpu"
+# obs.trace.span by its bare name; ``tracer.span(`` is the experiment
+# engine's accumulator (obs/spans.py), which names whole runs.
+_SPAN_CALL = re.compile(r"""(?<![.\w])span\(\s*["']([^"']+)["']""")
+_SCOPE_CALL = re.compile(r"""named_scope\(\s*["']([^"']+)["']""")
+
+
+def _names_used(pattern):
+    used = {}
+    for path in sorted(_PACKAGE.rglob("*.py")):
+        for name in pattern.findall(path.read_text()):
+            used.setdefault(name, []).append(path.name)
+    return used
+
+
+class TestNamesAreListed:
+    def test_every_span_call_site_is_in_host_spans(self):
+        used = _names_used(_SPAN_CALL)
+        assert not set(used) - set(HOST_SPANS), {
+            n: used[n] for n in set(used) - set(HOST_SPANS)}
+        # and the tuple lists nothing that no call site writes
+        assert not set(HOST_SPANS) - set(used)
+        assert len(set(HOST_SPANS)) == len(HOST_SPANS)
+        for name in HOST_SPANS:
+            layer, _, what = name.partition(".")
+            assert layer in ("serve", "method", "engine", "backend"), name
+            assert what and name == name.lower(), name
+
+    def test_every_named_scope_is_in_model_scopes(self):
+        from consensus_tpu.models import MODEL_PHASES, MODEL_SCOPES
+
+        used = _names_used(_SCOPE_CALL)
+        assert not set(used) - set(MODEL_SCOPES), {
+            n: used[n] for n in set(used) - set(MODEL_SCOPES)}
+        assert not set(MODEL_SCOPES) - set(used)
+        assert set(MODEL_PHASES) < set(MODEL_SCOPES)
+        assert not set(MODEL_SCOPES) & set(HOST_SPANS)
+
+    def test_the_benchmark_reads_no_name_the_program_does_not_write(self):
+        """Every ``<layer>.<what>`` a metric file or a reader of the
+        benchmark mentions is a name of ``HOST_SPANS``, and every scope a
+        metric file lists is a name of ``MODEL_SCOPES``."""
+        from consensus_tpu.models import MODEL_SCOPES
+
+        bench = _PACKAGE.parent / "benchmark"
+        dotted = re.compile(r"\b(?:serve|method|engine|backend)\.[a-z_0-9]+\b")
+        # (a counter's file may name a key of /healthz, engine.<key>)
+        files = [path for path in sorted((bench / "metrics").glob("*.json"))
+                 if json.loads(path.read_text())["source"] != "program_counter"]
+        files += sorted((bench / "readers").glob("*.py"))
+        files.append(bench / "lib" / "xplane_spans.py")
+        mentioned = {}
+        for path in files:
+            for name in dotted.findall(path.read_text()):
+                if not name.endswith((".py", ".json")):
+                    mentioned.setdefault(name, path.name)
+        assert mentioned, "the benchmark's readers mention no span"
+        assert not set(mentioned) - set(HOST_SPANS), {
+            n: mentioned[n] for n in set(mentioned) - set(HOST_SPANS)}
+        scopes = {}
+        for path in sorted((bench / "metrics").glob("*.json")):
+            for name in json.loads(path.read_text()).get("scopes", ()):
+                scopes[name] = path.name
+        assert scopes and not set(scopes) - set(MODEL_SCOPES), scopes
+
+
+# ---------------------------------------------------------------------------
+# The span tree of one best_of_n request, fake backend and tiny-gemma2
+# ---------------------------------------------------------------------------
+
+
+def _descends_from(span_row, ancestor_id, by_id):
+    while span_row["parent"]:
+        if span_row["parent"] == ancestor_id:
+            return True
+        span_row = by_id[span_row["parent"]]
+    return False
+
+
+class TestRequestSpanTree:
+    @pytest.mark.parametrize("backend", ["fake", "tpu"])
+    def test_layers_nest_under_the_handler_and_close(self, backend):
+        options = {"model": "tiny-gemma2"} if backend == "tpu" else None
+        server = create_server(
+            backend=backend, backend_options=options, port=0,
+            registry=Registry()).start()
+        try:
+            request_id = f"tree-{backend}"
+            status, body = _post(server.base_url, _payload(
+                seed=41, request_id=request_id, evaluate=backend == "fake",
+                params={"n": 2, "max_tokens": 4}), timeout=300.0)
+            assert status == 200, body
+        finally:
+            server.stop(drain=False, timeout=5.0)
+        trace = get_trace_store().get(request_id)
+        spans = trace.to_dict()["spans"]
+        assert len(spans) < 200 and trace.dropped_spans == 0
+        assert not [s["name"] for s in spans if s["in_flight"]]
+        by_id = {s["id"]: s for s in spans}
+        names = {s["name"] for s in spans}
+        handler = next(s for s in spans if s["name"] == "handler")
+        expected = {"serve.method", "method.render", "method.generate",
+                    "method.score", "method.select", "engine.enqueue",
+                    "engine.dispatch"}
+        if backend == "fake":
+            expected |= {"serve.evaluate"}
+        else:
+            expected |= {"backend.tokenize", "backend.layout", "backend.h2d",
+                         "backend.launch", "backend.d2h",
+                         "backend.detokenize"}
+        assert expected <= names, expected - names
+        for row in spans:
+            if row["name"] in expected:
+                assert _descends_from(row, handler["id"], by_id), row["name"]
+        root = next(s for s in spans if s["name"] == "http_request")
+        for name in ("serve.parse", "serve.respond"):
+            row = next(s for s in spans if s["name"] == name)
+            assert row["parent"] == root["id"]
+        # the engine's spans of this thread-less work are inside the call
+        for row in spans:
+            if row["name"] == "engine.dispatch":
+                assert by_id[row["parent"]]["name"].startswith("engine_")
+                assert set(row["attrs"]) == {"kind", "rows"}
+        for row in spans:
+            if row["name"] in HOST_SPANS:
+                for value in row["attrs"].values():  # never a prompt
+                    assert isinstance(value, (int, float)) or len(value) < 64
+        path = trace.critical_path()
+        assert abs(sum(path["phases"].values()) - path["total_s"]) < 1e-4
+        assert path["phases"]["engine_wait"] > 0.0
+        assert path["phases"]["score"] > 0.0
+        assert path["total_s"] == pytest.approx(
+            root["duration_s"], abs=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# named_scope through the model programs: the lowering names every scope
+# ---------------------------------------------------------------------------
+
+_LAYER = {"embed", "layers", "attn_qkv", "attention", "attn_out", "ffn",
+          "final_norm"}
+_PROGRAM_SCOPES = {
+    "generate_tokens_shared_trunk": _LAYER | {
+        "kv_write", "vocab_projection", "sample", "prefill", "decode_step"},
+    "paged_prefill_chunk": _LAYER | {"kv_write"},
+    "paged_score_chunk": _LAYER | {
+        "kv_write", "vocab_projection", "logsumexp"},
+    "_embed_forward": _LAYER,
+}
+
+
+def _lower_program(name):
+    import jax
+    import jax.numpy as jnp
+
+    from consensus_tpu.backends.tpu import _embed_forward
+    from consensus_tpu.models.config import get_model_config
+    from consensus_tpu.models.generate import generate_tokens_shared_trunk
+    from consensus_tpu.models.stepper import (
+        make_page_state,
+        paged_prefill_chunk,
+        paged_score_chunk,
+    )
+    from consensus_tpu.models.transformer import init_params
+
+    config = get_model_config("tiny-gemma2")
+    params = jax.eval_shape(
+        lambda: init_params(config, jax.random.PRNGKey(0), jnp.float32))
+    rows, width = 8, 32
+    tokens = jnp.zeros((rows, width), jnp.int32)
+    valid = jnp.ones((rows, width), bool)
+    tables = jnp.zeros((rows, 4), jnp.int32)
+    lengths = jnp.full((rows,), width, jnp.int32)
+    if name == "generate_tokens_shared_trunk":
+        return generate_tokens_shared_trunk.lower(
+            params, config, tokens[:1], valid[:1], rows,
+            jnp.zeros((rows, 2), jnp.uint32), max_new_tokens=16,
+            temperature=jnp.ones((rows,)),
+            eos_ids=jnp.asarray([-1], jnp.int32), pad_id=0,
+            init_done=jnp.zeros((rows,), bool))
+    if name == "_embed_forward":
+        return _embed_forward.lower(params, config, tokens, valid)
+    state = jax.eval_shape(lambda: make_page_state(config, 32, 16))
+    if name == "paged_prefill_chunk":
+        return paged_prefill_chunk.lower(
+            params, config, tokens, valid, state, tables, lengths, tokens,
+            tokens)
+    return paged_score_chunk.lower(
+        params, config, tokens, tokens, valid, valid, state, tables, lengths,
+        tokens, tokens)
+
+
+class TestModelScopes:
+    @pytest.mark.parametrize("program", sorted(_PROGRAM_SCOPES))
+    def test_lowering_names_every_scope(self, program):
+        from consensus_tpu.models import MODEL_SCOPES
+
+        text = _lower_program(program).as_text(debug_info=True)
+        named = {scope for scope in MODEL_SCOPES
+                 if re.search(rf'[/"]{scope}[/"]', text)}
+        assert named == _PROGRAM_SCOPES[program]
 
 
 # ---------------------------------------------------------------------------
