@@ -27,6 +27,13 @@ import numpy as np
 #: beam_search.py:56 use -1_000_000 through the API's logit_bias map).
 BAN_BIAS = -1.0e6
 
+#: Rows of ONE prompt from which a generate call's group is a program of its
+#: own: the TPU backend routes such a group to its shared-trunk decode even
+#: inside a larger batch (smaller groups combine into classic chunks, see
+#: ``TPUBackend._generate_impl``), and the decode engine dispatches it as a
+#: cohort of its own (``DecodeEngine._decode_cohort``).
+SHARED_TRUNK_SOLO_ROWS = 16
+
 
 # -- error taxonomy ----------------------------------------------------------
 #

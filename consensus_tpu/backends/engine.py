@@ -9,24 +9,36 @@ device idles between flushes while stragglers finish host work.
 
 This engine replaces the barrier with ITERATION-LEVEL batching (Orca, Yu
 et al., OSDI '22): a persistent loop over a fixed table of ``n_slots``
-request slots.  Each iteration
+slots.  A slot holds ONE PROMPT with the rows that decode from it: the
+rows of one generate call that carry the same prompt tokens are a GROUP
+(best_of_n's N drafts; one row is the common case), so ``n_slots`` slots
+are ``n_slots`` resident prompts.  Each iteration
 
 1. consults cancellation probes — queued work is dropped before any pages
    are spent, resident rows are EVICTED and their pages freed;
-2. admits queued generate rows into free slots under a conservative page
-   reservation (prompt + max_tokens pages must fit the pool, so a resident
-   row can always finish — no mid-decode preemption);
+2. admits queued groups into free slots, each WHOLE or not at all, under a
+   conservative page reservation of what the cohort's program will hold
+   (the prompt's pages once plus every row's max_tokens pages must fit the
+   pool, so a resident group can always finish — no mid-decode
+   preemption);
 3. advances chunked PREFILL: each mid-prefill slot ingests one
-   ``prefill_chunk``-token chunk of its prompt, allocating pages as the
-   chunk crosses page boundaries — long prompts interleave with decode
-   instead of stalling it;
-4. dispatches the DECODE cohort: all prefill-complete slots run as one
-   batch on the inner backend, then retire, freeing their pages — new
-   arrivals admitted meanwhile join the next iteration (requests join and
-   leave at iteration granularity; there is no full-batch flush barrier
-   and no timeout reason);
+   ``prefill_chunk``-token chunk of its prompt, once a group, allocating
+   pages as the chunk crosses page boundaries — long prompts interleave
+   with decode instead of stalling it;
+4. dispatches the DECODE cohort: a group of ``SHARED_TRUNK_SOLO_ROWS`` rows
+   or more runs ALONE (one ``inner.generate`` = one shared-trunk program
+   on the TPU backend; two requests' groups in one blocking call would run
+   one after the other inside it and both would wait for the later), all
+   other prefill-complete slots run as one batch; then they retire,
+   freeing their pages — new arrivals admitted meanwhile join the next
+   iteration (requests join and leave at iteration granularity; there is
+   no full-batch flush barrier and no timeout reason);
 5. batches every queued score / next_token / embed call into one inner
    call per kind.
+
+With ``decode_steps`` set (and a backend with the stream seam) the cohort
+is a paged multi-token STREAM whose pages are real and per row: there every
+row is a group of its own, admitted, reserved and retired as a row.
 
 Correctness: per-request PRNG keys (backends/tpu.py) and (prompt,
 seed)-keyed hashing (backends/fake.py) make every result independent of
@@ -58,6 +70,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from consensus_tpu.backends.base import (
+    SHARED_TRUNK_SOLO_ROWS,
     BackendLostError,
     PartialBatchError,
     RequestCancelled,
@@ -128,7 +141,7 @@ class _Item:
 class _Row:
     __slots__ = (
         "item", "index", "request", "prompt_tokens", "prompt_ids",
-        "trace", "span",
+        "max_tokens", "trace", "span",
     )
 
     def __init__(
@@ -141,25 +154,42 @@ class _Row:
         #: fake one) — page accounting AND the prefix-cache content key.
         self.prompt_ids = prompt_ids
         self.prompt_tokens = max(1, len(prompt_ids))
+        self.max_tokens = int(getattr(request, "max_tokens", 0))
         self.trace = None
         self.span = 0
 
 
 class _Slot:
+    """One resident prompt and the rows of one call that decode from it."""
+
     __slots__ = (
-        "idx", "row", "table", "prefilled", "state", "reserved",
-        "cached_tokens", "shard",
+        "idx", "seq", "rows", "item", "solo", "table", "tail", "prefilled",
+        "state", "needed", "reserved", "cached_tokens", "shard",
     )
 
-    def __init__(self, idx: int, row: _Row, reserved: int, shard: int = 0):
+    def __init__(
+        self, idx: int, seq: int, rows: List[_Row], needed: int,
+        reserved: int, shard: int = 0,
+    ):
         self.idx = idx
-        self.row = row
+        #: Admission order: cohorts form oldest first (slot indices are
+        #: reused, so they say nothing of arrival).
+        self.seq = seq
+        self.rows = rows
+        self.item = rows[0].item
+        #: A program of its own in the backend, so a cohort of its own.
+        self.solo = len(rows) >= SHARED_TRUNK_SOLO_ROWS
+        #: The prompt's pages (prefilled once, whatever the rows) and the
+        #: rows' generated-token pages (allocated when the cohort forms).
         self.table = BlockTable(idx)
+        self.tail: List[int] = []
         self.prefilled = 0
         self.state = _PREFILL
-        #: Worst-case pages this row may ever need (prompt + max_tokens
-        #: minus any cached prefix) — held against the pool so a resident
-        #: row can always decode to completion without preemption.
+        #: Worst-case pages this group may ever hold (``_pages_needed``);
+        #: ``reserved`` is that less any cached prefix — held against the
+        #: pool so a resident group can always decode to completion
+        #: without preemption.
+        self.needed = needed
         self.reserved = reserved
         #: Prompt tokens adopted from the prefix cache (page-aligned) —
         #: their prefill chunks are skipped entirely.
@@ -409,7 +439,10 @@ class DecodeEngine:
 
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
-        self._gen_backlog: List[_Row] = []
+        #: Queued generate groups in arrival order (rows of one call over
+        #: one prompt; ``submit`` forms them).
+        self._gen_backlog: List[List[_Row]] = []
+        self._admitted_seq = 0
         self._other: Dict[str, List[_Item]] = {
             "score": [], "next_token": [], "embed": [], "score_matrix": [],
         }
@@ -501,13 +534,19 @@ class DecodeEngine:
             if self._stopped:
                 raise RuntimeError("decode engine is closed")
             if kind == "generate":
+                # Equal prompt tokens within the call make a group; on the
+                # stream path (pages per row) every row is its own.
+                streams = self._streams()
+                groups: Dict[Any, List[_Row]] = {}
                 for i, req in enumerate(item.requests):
                     row = _Row(item, i, req, self._prompt_token_ids(req))
                     if item.trace is not None:
                         row.trace = item.trace
                         row.span = item.trace.begin(
                             "engine_row", parent=item.span, row=i)
-                    self._gen_backlog.append(row)
+                    key = i if streams else tuple(row.prompt_ids)
+                    groups.setdefault(key, []).append(row)
+                self._gen_backlog.extend(groups.values())
             else:
                 self._other[kind].append(item)
             self._work.notify_all()
@@ -528,9 +567,10 @@ class DecodeEngine:
         return [(item.trace, item.span) for item in seen.values()]
 
     @staticmethod
-    def _trace_row_event(row: _Row, name: str, **attrs: Any) -> None:
-        if row.trace is not None:
-            row.trace.event(row.span, name, **attrs)
+    def _trace_rows_event(rows: List[_Row], name: str, **attrs: Any) -> None:
+        for row in rows:
+            if row.trace is not None:
+                row.trace.event(row.span, name, **attrs)
 
     @staticmethod
     def _trace_row_end(row: _Row, **attrs: Any) -> None:
@@ -607,8 +647,7 @@ class DecodeEngine:
                     self._occ_sum / self._occ_iters if self._occ_iters else 0.0
                 ),
                 "iterations": self.iterations,
-                "queue_depth": len(self._gen_backlog)
-                + sum(len(q) for q in self._other.values()),
+                "queue_depth": self._queue_depth(),
                 # Aggregates across every dp shard's pool (dp=1 == the
                 # single legacy pool, unchanged numbers).
                 "kv_pages": sum(p.num_pages for p in pools),
@@ -699,6 +738,19 @@ class DecodeEngine:
                 with self._work:
                     self._fail_all(exc)
 
+    def _queue_depth(self) -> int:
+        """Queued generate rows plus queued calls of the other kinds."""
+        return sum(len(rows) for rows in self._gen_backlog) + sum(
+            len(q) for q in self._other.values()
+        )
+
+    def _streams(self) -> bool:
+        """Whether a decode cohort opens a paged multi-token stream (pages
+        real and per row) and not one blocking ``inner.generate``."""
+        return self.decode_steps is not None and callable(
+            getattr(self.inner, "generate_stream", None)
+        )
+
     def _has_work(self) -> bool:
         return (
             bool(self._gen_backlog)
@@ -717,13 +769,13 @@ class DecodeEngine:
                     close()
                 except Exception:
                     pass
-        for row in self._gen_backlog:
-            self._fail_item(row.item, exc)
+        for rows in self._gen_backlog:
+            self._fail_item(rows[0].item, exc)
         self._gen_backlog = []
         for slot in list(self._slots):
             if slot is not None:
                 self._evict(slot, count=False)
-                self._fail_item(slot.row.item, exc)
+                self._fail_item(slot.item, exc)
         for queue in self._other.values():
             for item in queue:
                 self._fail_item(item, exc)
@@ -768,9 +820,7 @@ class DecodeEngine:
                 self._occ_sum += occ
                 self._occ_iters += 1
             self.iterations += 1
-            queue_depth = len(self._gen_backlog) + sum(
-                len(q) for q in self._other.values()
-            )
+            queue_depth = self._queue_depth()
             pages_in_use = sum(pool.in_use for pool in self.pools)
             others = {
                 kind: queue[:] for kind, queue in self._other.items() if queue
@@ -821,7 +871,7 @@ class DecodeEngine:
                     "merge": self._iter_merge_s,
                 },
                 tokens=self._iter_tokens,
-                cohort=len(cohort),
+                cohort=sum(len(slot.rows) for slot in cohort),
                 queue_depth=queue_depth,
                 pages_in_use=pages_in_use,
                 spec_proposed=self._iter_spec_proposed,
@@ -868,17 +918,18 @@ class DecodeEngine:
 
     def _process_cancellations(self) -> None:
         cancelled_items = set()
-        keep: List[_Row] = []
-        for row in self._gen_backlog:
-            if row.item.failed or row.item in cancelled_items or row.item.cancelled():
-                cancelled_items.add(row.item)
+        keep: List[List[_Row]] = []
+        for rows in self._gen_backlog:
+            item = rows[0].item
+            if item.failed or item in cancelled_items or item.cancelled():
+                cancelled_items.add(item)
             else:
-                keep.append(row)
+                keep.append(rows)
         self._gen_backlog = keep
         for slot in list(self._slots):
             if slot is None:
                 continue
-            item = slot.row.item
+            item = slot.item
             if item.failed or item in cancelled_items or item.cancelled():
                 cancelled_items.add(item)
                 self._evict(slot)
@@ -915,20 +966,26 @@ class DecodeEngine:
             if s is not None:
                 occupied[s.shard] += 1
         while free and self._gen_backlog:
-            row = self._gen_backlog[0]
-            if row.item.failed:
+            rows = self._gen_backlog[0]
+            if rows[0].item.failed:
                 self._gen_backlog.pop(0)
                 continue
-            needed = self.pool.pages_for_tokens(
-                row.prompt_tokens + int(getattr(row.request, "max_tokens", 0))
-            )
+            needed = self._pages_needed(rows)
             if needed > self.pool.num_pages:
+                if len(rows) > 1:
+                    # More rows than the pool ever holds: its halves queue
+                    # in its place, each admitted whole (a given call
+                    # always splits the same way, so its cohorts' row
+                    # counts repeat).
+                    half = len(rows) // 2
+                    self._gen_backlog[:1] = [rows[:half], rows[half:]]
+                    continue
                 self._gen_backlog.pop(0)
-                self._reject_oversized(row, needed)
+                self._reject_oversized(rows[0], needed)
                 continue
             # Balanced admission: among free slots whose dp shard still has
             # reservation headroom, take the one on the least-loaded shard
-            # (fewest resident rows, then fewest reserved pages, then lowest
+            # (fewest resident groups, then fewest reserved pages, then lowest
             # slot index — which at dp=1 is exactly the legacy FIFO pick).
             best = None
             best_key = None
@@ -940,8 +997,8 @@ class DecodeEngine:
                 if best_key is None or key < best_key:
                     best, best_key = slot_idx, key
             if best is None:
-                # Fits a pool but not right now — hold FIFO order and wait
-                # for resident rows to retire.
+                # Fits a pool but not right now — hold FIFO order and wait,
+                # whole, for resident groups to retire.
                 break
             self._gen_backlog.pop(0)
             free.remove(best)
@@ -951,7 +1008,8 @@ class DecodeEngine:
             cached_pages: List[int] = []
             cached_tokens = 0
             if cache is not None:
-                cached_pages, cached_tokens = cache.lookup(row.prompt_ids)
+                # One lookup a group: its rows read the same prompt pages.
+                cached_pages, cached_tokens = cache.lookup(rows[0].prompt_ids)
                 if cached_tokens:
                     self._m_prefix_hits.inc()
                     self._m_prefix_saved.inc(cached_tokens)
@@ -959,30 +1017,45 @@ class DecodeEngine:
                     self._m_prefix_misses.inc()
             # Shared pages come off the cache, not the free list — only the
             # private remainder counts against the reservation.
+            self._admitted_seq += 1
             slot = _Slot(
-                best, row, reserved=needed - len(cached_pages), shard=shard
+                best, self._admitted_seq, rows, needed,
+                reserved=needed - len(cached_pages), shard=shard,
             )
             if cached_tokens:
                 slot.table.adopt_shared(pool, cached_pages, cached_tokens)
                 slot.prefilled = cached_tokens
                 slot.cached_tokens = cached_tokens
-                if slot.prefilled >= row.prompt_tokens:
+                if slot.prefilled >= rows[0].prompt_tokens:
                     slot.state = _READY
             self._slots[slot.idx] = slot
             self._reserved[shard] += slot.reserved
             occupied[shard] += 1
-            self._m_admitted.inc()
-            self._trace_row_event(
-                row, "slot_admitted", slot=slot.idx, shard=shard,
+            self._m_admitted.inc(len(rows))
+            self._trace_rows_event(
+                rows, "slot_admitted", slot=slot.idx, shard=shard,
                 cached_tokens=cached_tokens)
             if slot.state == _READY:
-                self._trace_row_event(row, "prefill_complete", cached=True)
+                self._trace_rows_event(rows, "prefill_complete", cached=True)
+
+    def _pages_needed(self, rows: List[_Row]) -> int:
+        """Pages the cohort's program holds for a group, at most: the
+        prompt's once and every row's generated tokens."""
+        pages = self.pool.pages_for_tokens
+        prompt = rows[0].prompt_tokens
+        if self._streams():
+            # The paged stream writes a row's tokens on from its own
+            # prompt's last page.
+            return sum(pages(prompt + row.max_tokens) for row in rows)
+        # One blocking generate: a prompt trunk and a tail a row.
+        return pages(prompt) + sum(pages(row.max_tokens) for row in rows)
 
     def _advance_prefill(self) -> None:
         for slot in self._slots:
             if slot is None or slot.state != _PREFILL:
                 continue
-            remaining = slot.row.prompt_tokens - slot.prefilled
+            prompt_tokens = slot.rows[0].prompt_tokens
+            remaining = prompt_tokens - slot.prefilled
             chunk = min(self.prefill_chunk, remaining)
             if chunk > 0:
                 # Reservation guarantees the pool has room.
@@ -990,10 +1063,10 @@ class DecodeEngine:
                 slot.prefilled += chunk
                 self._m_prefill_chunks.inc()
                 self._m_prefill_tokens.inc(chunk)
-                self._trace_row_event(slot.row, "prefill_chunk", tokens=chunk)
-            if slot.prefilled >= slot.row.prompt_tokens:
+                self._trace_rows_event(slot.rows, "prefill_chunk", tokens=chunk)
+            if slot.prefilled >= prompt_tokens:
                 slot.state = _READY
-                self._trace_row_event(slot.row, "prefill_complete")
+                self._trace_rows_event(slot.rows, "prefill_complete")
 
     def _decode_cohort(self) -> List[_Slot]:
         # One multi-token stream in flight at a time: newly-ready slots
@@ -1002,42 +1075,47 @@ class DecodeEngine:
         # that is the double-buffering, not a second stream).
         if self._stream is not None:
             return []
-        ready = [s for s in self._slots if s is not None and s.state == _READY]
-        prefilling = any(
-            s is not None and s.state == _PREFILL for s in self._slots
+        ready = sorted(
+            (s for s in self._slots if s is not None and s.state == _READY),
+            key=lambda s: s.seq,
         )
-        if not ready or (prefilling and len(ready) < self.min_fill):
+        if not ready:
             return []
-        for slot in ready:
+        if ready[0].solo:
+            cohort = ready[:1]
+        else:
+            cohort = [s for s in ready if not s.solo]
+            prefilling = any(
+                s is not None and s.state == _PREFILL for s in self._slots
+            )
+            if prefilling and len(cohort) < self.min_fill:
+                return []
+        for slot in cohort:
             # Generated-token pages, allocated up front (the reservation
             # made at admission covers them); retired below with the slot.
-            slot.table.append_tokens(
-                self.pools[slot.shard],
-                int(getattr(slot.row.request, "max_tokens", 0)),
+            slot.tail = self.pools[slot.shard].alloc(
+                slot.needed - len(slot.table.pages), owner=slot
             )
         self._m_pages.observe(sum(pool.in_use for pool in self.pools))
-        return ready
+        return cohort
 
     # -- dispatch (lock released) -------------------------------------------
 
     def _dispatch_decode(self, cohort: List[_Slot]) -> None:
-        if self.decode_steps is not None and callable(
-            getattr(self.inner, "generate_stream", None)
-        ):
+        if self._streams():
             self._open_stream(cohort)
             return
-        requests = [slot.row.request for slot in cohort]
+        rows = [row for slot in cohort for row in slot.rows]
+        requests = [row.request for row in rows]
         self.dispatch_counts["generate"] += 1
-        for slot in cohort:
-            self._trace_row_event(
-                slot.row, "decode_dispatch", cohort=len(cohort))
+        self._trace_rows_event(rows, "decode_dispatch", cohort=len(rows))
         results: Optional[List[Any]] = None
         row_errors: Dict[int, BaseException] = {}
         batch_error: Optional[BaseException] = None
         t_dev = time.perf_counter()
         try:
             with span("engine.dispatch", traces=self._item_traces(
-                slot.row.item for slot in cohort
+                slot.item for slot in cohort
             ), kind="generate", rows=len(requests)):
                 results = self.inner.generate(requests)
         except PartialBatchError as exc:
@@ -1053,15 +1131,15 @@ class DecodeEngine:
         t_merge = time.perf_counter()
         with span("engine.merge"), self._lock:
             tokens = 0
-            for i, slot in enumerate(cohort):
+            for slot in cohort:
                 self._retire(slot)
-                item = slot.row.item
+            for i, row in enumerate(rows):
                 if batch_error is not None:
-                    self._trace_row_end(slot.row, outcome="error")
-                    self._fail_item(item, batch_error)
+                    self._trace_row_end(row, outcome="error")
+                    self._fail_item(row.item, batch_error)
                 elif i in row_errors:
-                    self._trace_row_end(slot.row, outcome="error")
-                    self._record_row(item, slot.row.index, None, row_errors[i])
+                    self._trace_row_end(row, outcome="error")
+                    self._record_row(row.item, row.index, None, row_errors[i])
                 else:
                     result = results[i]
                     ids = getattr(result, "token_ids", None) or ()
@@ -1070,8 +1148,8 @@ class DecodeEngine:
                     )
                     tokens += row_tokens
                     self._trace_row_end(
-                        slot.row, outcome="retired", tokens=row_tokens)
-                    self._record_row(item, slot.row.index, result, None)
+                        row, outcome="retired", tokens=row_tokens)
+                    self._record_row(row.item, row.index, result, None)
             self._iter_tokens += tokens
             self._m_tokens_iter.observe(tokens)
             self._m_tokens_dispatch.observe(tokens)
@@ -1086,19 +1164,21 @@ class DecodeEngine:
         """Start a K-step decode stream for this cohort: the inner backend
         prefills the cohort and launches the FIRST K-step window; the call
         returns as soon as the window is enqueued (jax async dispatch), so
-        the next iteration's host phases run while the device decodes."""
-        requests = [slot.row.request for slot in cohort]
+        the next iteration's host phases run while the device decodes.
+        (A stream's slots hold one row each: ``submit`` groups nothing on
+        this path, so stream row ``i`` is ``cohort[i]``.)"""
+        rows = [slot.rows[0] for slot in cohort]
+        requests = [row.request for row in rows]
         self.dispatch_counts["generate"] += 1
-        for slot in cohort:
-            self._trace_row_event(
-                slot.row, "decode_dispatch", cohort=len(cohort),
-                decode_steps=self.decode_steps)
+        self._trace_rows_event(
+            rows, "decode_dispatch", cohort=len(rows),
+            decode_steps=self.decode_steps)
         t_disp = time.perf_counter()
         try:
             # The stream's prefill and first window; later windows are one
             # a token or a few, and stay out of the requests' span trees.
             with span("engine.dispatch", traces=self._item_traces(
-                slot.row.item for slot in cohort
+                slot.item for slot in cohort
             ), kind="generate_stream", rows=len(requests)):
                 if self.speculative:
                     stream = self.inner.generate_stream(
@@ -1118,8 +1198,8 @@ class DecodeEngine:
             with self._lock:
                 for slot in cohort:
                     self._retire(slot)
-                    self._trace_row_end(slot.row, outcome="error")
-                    self._fail_item(slot.row.item, exc)
+                    self._trace_row_end(slot.rows[0], outcome="error")
+                    self._fail_item(slot.item, exc)
                 self._work.notify_all()
             self._iter_merge_s += time.perf_counter() - t_merge
             return
@@ -1178,9 +1258,9 @@ class DecodeEngine:
                 n_ids = len(ids) if ids else self._count_text_tokens(
                     getattr(result, "text", "") or ""
                 )
-                self._trace_row_end(
-                    slot.row, outcome="retired", tokens=n_ids)
-                self._record_row(slot.row.item, slot.row.index, result, None)
+                row = slot.rows[0]
+                self._trace_row_end(row, outcome="retired", tokens=n_ids)
+                self._record_row(row.item, row.index, result, None)
             self._work.notify_all()
         self._iter_merge_s += time.perf_counter() - t_merge
 
@@ -1219,8 +1299,8 @@ class DecodeEngine:
                 if slot is None or self._slots[slot.idx] is not slot:
                     continue
                 self._retire(slot)
-                self._trace_row_end(slot.row, outcome="error")
-                self._fail_item(slot.row.item, error)
+                self._trace_row_end(slot.rows[0], outcome="error")
+                self._fail_item(slot.item, error)
             self._work.notify_all()
         self._iter_merge_s += time.perf_counter() - t_merge
 
@@ -1349,30 +1429,35 @@ class DecodeEngine:
     def _retire(self, slot: _Slot) -> None:
         pool = self.pools[slot.shard]
         cache = self.prefix_caches[slot.shard]
-        if cache is not None and slot.prefilled >= slot.row.prompt_tokens:
+        prompt_tokens = slot.rows[0].prompt_tokens
+        if cache is not None and slot.prefilled >= prompt_tokens:
             # Donate the fully-prefilled, page-aligned prompt prefix before
             # releasing: the cache takes its own reference, so the pages
             # survive this slot's free below.  (Evicted mid-prefill slots
             # hold partial KV — never cacheable.)
             ps = pool.page_size
-            n_pages = slot.row.prompt_tokens // ps
+            n_pages = prompt_tokens // ps
             if n_pages > 0:
                 before = cache.evictions
                 if cache.insert(
-                    slot.row.prompt_ids[: n_pages * ps],
+                    slot.rows[0].prompt_ids[: n_pages * ps],
                     slot.table.pages[:n_pages],
                 ):
                     self._m_prefix_inserted.inc(n_pages)
                 self._m_prefix_evictions.inc(cache.evictions - before)
         slot.table.release(pool)
+        if slot.tail:
+            pool.free(slot.tail)
+            slot.tail = []
         self._reserved[slot.shard] -= slot.reserved
         self._slots[slot.idx] = None
 
     def _evict(self, slot: _Slot, count: bool = True) -> None:
         self._retire(slot)
-        self._trace_row_end(slot.row, outcome="evicted")
+        for row in slot.rows:
+            self._trace_row_end(row, outcome="evicted")
         if count:
-            self._m_evicted.inc()
+            self._m_evicted.inc(len(slot.rows))
 
     def _record_row(
         self, item: _Item, index: int, result, error: Optional[BaseException]

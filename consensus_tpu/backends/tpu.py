@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from consensus_tpu.backends.base import (
+    SHARED_TRUNK_SOLO_ROWS as _SHARED_TRUNK_SOLO_ROWS,
     GenerationRequest,
     GenerationResult,
     NextTokenRequest,
@@ -79,8 +80,8 @@ _SHARED_TRUNK_MIN_ROWS = 4
 #: A small identical-prompt group inside a LARGER batch routes classic
 #: instead: combined classic chunks amortize the per-step weight read over
 #: every row in the chunk, which beats the shared path's 1-row prefill
-#: once the group is this small (see _generate_impl docstring).
-_SHARED_TRUNK_SOLO_ROWS = 16
+#: once the group is under ``_SHARED_TRUNK_SOLO_ROWS`` (backends/base.py:
+#: the engine forms its cohorts by the same number; see _generate_impl).
 
 #: Search-session KV caches above this (plus resident weights) risk HBM
 #: exhaustion — fall back to the cacheless full-prefix session instead.

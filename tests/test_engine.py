@@ -641,6 +641,217 @@ class TestEngineScheduling:
 
 
 # ---------------------------------------------------------------------------
+# Cohorts form by prompt: a slot holds one prompt with its rows
+# ---------------------------------------------------------------------------
+
+
+class _CohortRecorder(FakeBackend):
+    """Fake backend that keeps every ``generate`` call's prompts."""
+
+    def __init__(self):
+        super().__init__()
+        self.cohorts = []
+
+    def generate(self, requests):
+        self.cohorts.append([r.user_prompt for r in requests])
+        return super().generate(requests)
+
+
+def _group(prompt, rows, max_tokens=12, seed=0):
+    return [
+        GenerationRequest(
+            user_prompt=prompt, max_tokens=max_tokens, seed=seed + i)
+        for i in range(rows)
+    ]
+
+
+def _run_calls(engine, calls, limit=40):
+    """Submit ``calls`` one after the other (so the backlog holds them in
+    that order), step the engine until all are answered; returns each
+    call's outbox."""
+    boxes, threads, queued = [], [], 0
+    for requests in calls:
+        thread, out = _submit_async(engine, requests)
+        queued += len(requests)
+        assert _wait_until(
+            lambda: engine.stats()["queue_depth"] == queued)
+        threads.append(thread)
+        boxes.append(out)
+    for _ in range(limit):
+        if all(boxes):
+            break
+        engine.run_iteration()
+    for thread in threads:
+        thread.join(timeout=5.0)
+    assert all("result" in out for out in boxes), boxes
+    return boxes
+
+
+PROMPT_A = "one two three four five"  # 5 pseudo-tokens: 2 pages of 4
+PROMPT_B = "six seven eight nine ten"
+
+#: name -> (calls, engine options, the inner generate calls expected: one
+#: (rows, distinct prompts) pair a cohort, in order).  12 tokens are 3 pages
+#: of 4, so a group of N rows reserves 2 + 3N pages.
+COHORT_CASES = {
+    # One statement of best_of_n: one program, not four cohorts of eight.
+    "one_call_of_32_is_one_cohort": (
+        [_group(PROMPT_A, 32)], {}, [(32, 1)]),
+    # Each large group alone, in arrival order: merged, the backend would
+    # run them one after the other and both callers would wait for both.
+    "two_calls_of_32_are_two_cohorts_in_arrival_order": (
+        [_group(PROMPT_A, 32), _group(PROMPT_B, 32, seed=100)], {},
+        [(32, 1), (32, 1)]),
+    # 98 + 98 pages do not fit 128: the second group waits whole and is
+    # admitted whole once the first has retired.
+    "a_group_that_does_not_fit_waits_whole": (
+        [_group(PROMPT_A, 32), _group(PROMPT_B, 32, seed=100)],
+        {"num_pages": 128}, [(32, 1), (32, 1)]),
+    # 98 pages never fit 64: the halves (50 each) go in its place.
+    "a_group_the_pool_never_holds_goes_in_halves": (
+        [_group(PROMPT_A, 32)], {"num_pages": 64}, [(16, 1), (16, 1)]),
+    # Distinct prompts share a cohort as before, a row a slot.
+    "eight_distinct_prompts_are_one_cohort": (
+        [[r for i in range(8) for r in _group(f"prompt number {i}", 1, seed=i)]],
+        {}, [(8, 8)]),
+    # Small groups (habermas candidates) go on sharing one cohort.
+    "small_groups_share_a_cohort": (
+        [_group(f"draft for statement {i}", 4, seed=10 * i) for i in range(3)],
+        {}, [(12, 3)]),
+    # A call's large group runs alone, its other rows in the cohort after.
+    "a_call_of_a_large_and_a_small_group": (
+        [_group(PROMPT_A, 16) + _group(PROMPT_B, 2, seed=50)], {},
+        [(16, 1), (2, 1)]),
+    # More distinct prompts than slots: the slot count still caps them.
+    "slots_cap_the_resident_prompts": (
+        [[r for i in range(6) for r in _group(f"prompt number {i}", 1, seed=i)]],
+        {"slots": 4, "min_fill": 1}, [(4, 4), (2, 2)]),
+}
+
+
+class TestCohortsFormByPrompt:
+    @pytest.mark.parametrize("case", sorted(COHORT_CASES))
+    def test_cohorts(self, case):
+        calls, options, expected = COHORT_CASES[case]
+        inner = _CohortRecorder()
+        options = {"slots": 8, "page_size": 4, "num_pages": 512, **options}
+        engine = DecodeEngine(inner, auto_start=False, **options)
+        try:
+            boxes = _run_calls(engine, calls)
+            stats = engine.stats()
+        finally:
+            engine.close()
+        assert [(len(c), len(set(c))) for c in inner.cohorts] == expected
+        # arrival order: the cohorts' prompts follow the calls' prompts
+        order = dict.fromkeys(p for cohort in inner.cohorts for p in cohort)
+        assert list(order) == list(dict.fromkeys(
+            r.user_prompt for requests in calls for r in requests))
+        # every row answered as a solo backend answers it, in its place
+        for requests, out in zip(calls, boxes):
+            assert [r.text for r in out["result"]] == [
+                r.text for r in FakeBackend().generate(requests)]
+        assert stats["kv_pages_reserved"] == 0 and stats["slots_occupied"] == 0
+        assert engine.pool.in_use == 0
+
+    @pytest.mark.parametrize("decode_steps, resident_rows, reserved", [
+        # one blocking generate: the prompt's 2 pages once, 3 pages a row
+        (None, 32, 2 + 32 * 3),
+        # the paged stream: a row a slot, ceil((5 + 12) / 4) = 5 pages each
+        (4, 8, 8 * 5),
+    ])
+    def test_reservation_is_what_the_cohort_holds(
+        self, decode_steps, resident_rows, reserved
+    ):
+        reg = Registry()
+        engine = DecodeEngine(
+            FakeBackend(), slots=8, page_size=4, num_pages=512,
+            auto_start=False, decode_steps=decode_steps, registry=reg,
+        )
+        try:
+            thread, out = _submit_async(engine, _group(PROMPT_A, 32))
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 32)
+            with engine._lock:
+                engine._admit()
+            stats = engine.stats()
+            assert stats["kv_pages_reserved"] == reserved
+            assert stats["queue_depth"] == 32 - resident_rows
+            assert stats["slots_occupied"] == (1 if decode_steps is None else 8)
+            assert _counter_total(reg, "engine_admitted_total") == resident_rows
+            for _ in range(80):
+                if out:
+                    break
+                engine.run_iteration()
+            thread.join(timeout=5.0)
+            assert len(out["result"]) == 32
+            stats = engine.stats()
+            assert stats["kv_pages_reserved"] == 0
+            assert engine.pool.in_use == 0
+            # the pool never held more than was reserved
+            assert stats["kv_pages_high_water"] <= reserved
+        finally:
+            engine.close()
+
+    def test_a_waiting_group_stays_whole_in_the_backlog(self):
+        engine = DecodeEngine(
+            FakeBackend(), slots=8, page_size=4, num_pages=128,
+            auto_start=False,
+        )
+        try:
+            first = _submit_async(engine, _group(PROMPT_A, 32))
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 32)
+            second = _submit_async(engine, _group(PROMPT_B, 32, seed=100))
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 64)
+            with engine._lock:
+                engine._admit()
+            stats = engine.stats()
+            # free slots and 30 free pages, and not one row of the second
+            assert stats["slots_occupied"] == 1
+            assert stats["kv_pages_reserved"] == 98
+            assert stats["queue_depth"] == 32
+            engine.run_iteration()  # the first runs and retires
+            assert _wait_until(lambda: "result" in first[1])
+            assert not second[1]
+            with engine._lock:
+                engine._admit()
+            stats = engine.stats()
+            assert stats["slots_occupied"] == 1 and stats["queue_depth"] == 0
+            assert stats["kv_pages_reserved"] == 98
+            for _ in range(4):
+                engine.run_iteration()
+            for thread, _ in (first, second):
+                thread.join(timeout=5.0)
+            assert len(second[1]["result"]) == 32
+        finally:
+            engine.close()
+
+    def test_cancelled_group_is_evicted_whole(self):
+        reg = Registry()
+        engine = DecodeEngine(
+            FakeBackend(), slots=8, page_size=4, num_pages=512,
+            prefill_chunk=2, auto_start=False, registry=reg,
+        )
+        flag = {"cancelled": False}
+        try:
+            thread, out = _submit_async(
+                engine, _group(PROMPT_A, 32), probe=lambda: flag["cancelled"])
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 32)
+            engine.run_iteration()  # admitted, 2 of 5 prompt tokens prefilled
+            assert engine.stats()["slots_occupied"] == 1
+            assert engine.pool.in_use == 1  # one chunk a group, not a row
+            flag["cancelled"] = True
+            engine.run_iteration()
+            thread.join(timeout=5.0)
+            assert isinstance(out.get("error"), RequestCancelled)
+            stats = engine.stats()
+            assert stats["slots_occupied"] == 0
+            assert stats["kv_pages_reserved"] == 0 and engine.pool.in_use == 0
+            assert _counter_total(reg, "engine_evicted_total") == 32
+            assert _counter_total(reg, "engine_prefill_chunks_total") == 1
+        finally:
+            engine.close()
+
+
+# ---------------------------------------------------------------------------
 # Obs pins: no timeout flushes, no spurious wakeups, recompile-flat
 # ---------------------------------------------------------------------------
 

@@ -173,3 +173,39 @@ class TestRoutingThreshold:
         ]
         calls = self._routes(shared, requests, monkeypatch)
         assert calls["shared"] == 1 and calls["classic"] >= 1
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9])
+def test_engine_hands_the_whole_group_to_one_program(
+    shared, monkeypatch, temperature
+):
+    """The 32 rows of one call go through the engine as one cohort: one
+    ``generate_shared`` launch of 32 rows, whose rows are, row for row, what
+    the same seeds give in four direct calls of 8 (the engine's old cohorts;
+    per-row keys make a row independent of who shares its batch)."""
+    from consensus_tpu.backends.batching import BatchingBackend
+
+    requests = requests_same_prompt(32, temperature=temperature)
+    direct = [
+        result
+        for i in range(0, 32, 8)
+        for result in shared.generate(requests[i : i + 8])
+    ]
+    launches = []
+    record = shared.instruments.record_launch
+    monkeypatch.setattr(
+        shared.instruments, "record_launch",
+        lambda kind, shape: launches.append((kind, shape[0]))
+        or record(kind, shape),
+    )
+    engined = BatchingBackend(shared, engine=True)
+    try:
+        served = engined.generate(requests)
+        stats = engined.engine.stats()
+    finally:
+        engined.close()
+    assert [r.token_ids for r in served] == [r.token_ids for r in direct]
+    assert [r.text for r in served] == [r.text for r in direct]
+    assert launches == [("generate_shared", 32)]
+    assert engined.batch_counts["generate"] == 1
+    assert stats["decode_windows"] == 1 and stats["kv_pages_reserved"] == 0
