@@ -22,7 +22,11 @@ Numbers compared, each with a limit of its own in the cell's file:
                     request; the limit is 0).
 ``truncated``       prompts the backend cut to fit its context (limit 0).
 ``weights``         weight leaves whose bits differ from the reference's own
-                    draw from the same seed (limit 0).
+                    draw from the same seed, and leaves that only the served
+                    tree or only the reference has (limit 0).
+
+The forward is the cell's reference file (``benchmark/references/``); the
+tokenizer and the prompts' rendering are common to all of them.
 """
 
 from __future__ import annotations
@@ -208,24 +212,32 @@ def _gather_one(sent, matrices, generations, numbers, rng, n_extra):
     return jobs
 
 
-def compare(cfg: Any, weights: Any, jobs: List[Any], numbers: Numbers,
-            control: bool = False) -> None:
-    """Run every job's rows through the reference and fold the gaps into
-    ``numbers``.  With ``control`` the same forward in float8 takes the
-    served side's place, and the numbers are the control's."""
+def differing_leaves(served: Dict[str, int], own: Dict[str, int]) -> Tuple[int, int]:
+    """Of two trees' ``weights_checksum``: (leaves that differ, leaves
+    compared).  A leaf that one tree has and the other lacks differs."""
+    paths = set(served) | set(own)
+    return sum(1 for p in paths if served.get(p) != own.get(p)), len(paths)
+
+
+def compare(reference: Any, cfg: Any, weights: Any, jobs: List[Any],
+            numbers: Numbers, control: bool = False) -> None:
+    """Run every job's rows through ``reference`` (the cell's reference
+    file) and fold the gaps into ``numbers``.  With ``control`` the same
+    forward in float8 takes the served side's place, and the numbers are the
+    control's."""
     flat = [row for _, rows, _, _ in jobs for row in rows]
     kinds = [kind for kind, rows, _, _ in jobs for _ in rows]
     served = [None if values is None else values[r]
               for _, rows, values, _ in jobs for r in range(len(rows))]
     if not flat:
         return
-    scored = ref.score_rows(cfg, weights, flat)
+    scored = reference.score_rows(cfg, weights, flat)
     if control:
-        low = ref.score_rows(cfg, weights, flat, precision="fp8")
+        low = reference.score_rows(cfg, weights, flat, precision="fp8")
         # Greedy under the control: the reference's logit of the token the
         # lower precision puts first, at each position of the same rows.
         greedy = [i for i, kind in enumerate(kinds) if kind == "greedy"]
-        first = dict(zip(greedy, ref.score_rows(cfg, weights, [
+        first = dict(zip(greedy, reference.score_rows(cfg, weights, [
             (flat[i][0], flat[i][1], [int(t) for t in low[i].best_id])
             for i in greedy])))
     for index, (kind, got) in enumerate(zip(kinds, scored)):
@@ -263,7 +275,7 @@ def verdict(numbers: Numbers, limits: Dict[str, float]) -> Tuple[bool, Dict[str,
     return ok, block
 
 
-def control_numbers(cfg: Any, weights: Any, jobs: List[Any],
+def control_numbers(reference: Any, cfg: Any, weights: Any, jobs: List[Any],
                     numbers: Numbers) -> Numbers:
     """The numbers of the control: the float8 forward's gaps, beside the
     exact numbers of the run it was put into."""
@@ -271,5 +283,5 @@ def control_numbers(cfg: Any, weights: Any, jobs: List[Any],
     for name in numbers.values:
         if not name.endswith("_gap"):
             control.add(name, numbers.values[name], numbers.compared[name])
-    compare(cfg, weights, jobs, control, control=True)
+    compare(reference, cfg, weights, jobs, control, control=True)
     return control

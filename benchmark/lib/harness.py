@@ -2,7 +2,11 @@
 
 Everything that belongs to one configuration, one traffic mix, one cell or
 one per-layer metric is a file of its own under the benchmark's directory,
-found by name; this module holds what is common to all of them.
+found by name: ``configs/``, ``traffic/``, ``workloads/``, ``metrics/`` with
+their ``readers/``, and what is an architecture's, ``references/`` (the
+plain forward and the weights' draws) and ``work/`` (the least FLOPs and
+bytes), which a configuration file names.  This module holds what is common
+to all of them.
 """
 
 from __future__ import annotations
@@ -13,8 +17,9 @@ import importlib.util
 import json
 import pathlib
 import statistics
+import sys
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 from benchmark.lib import reference as ref
 from benchmark.lib import traffic as traffic_lib
@@ -35,6 +40,9 @@ class Cell:
     config: Dict[str, Any]
     traffic: Dict[str, Any]
     bench_dir: pathlib.Path
+    #: The configuration's reference and work files, as modules.
+    reference: Any = None
+    work: Any = None
 
     @property
     def model(self) -> Dict[str, Any]:
@@ -51,13 +59,66 @@ def find_file(bench_dirs: List[pathlib.Path], kind: str, name: str,
         f"no {kind}/{name}{suffix} under {[str(d) for d in bench_dirs]}")
 
 
+def load_module(bench_dirs: List[pathlib.Path], kind: str, name: str,
+                gives: Sequence[str]) -> Any:
+    """``<kind>/<name>.py`` as a module of its own; one that lacks a function
+    of ``gives`` is refused here, not where the function is first missed."""
+    path = find_file(bench_dirs, kind, name, ".py")
+    module_name = f"benchmark_{kind}_{name}"
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[module_name] = module  # dataclasses look their module up
+    spec.loader.exec_module(module)
+    missing = [g for g in gives if not callable(getattr(module, g, None))]
+    if missing:
+        raise ValueError(f"{path} gives no {', '.join(missing)}")
+    return module
+
+
+REFERENCE_GIVES = ("ref_config", "make_weights", "score_rows")
+WORK_GIVES = ("param_count", "weight_bytes", "span_flops", "step_bytes")
+
+#: Where a work file's terms are held to its whole: (cached positions, new
+#: positions, of them with the head, rows that decode).
+_WORK_PROBES = ((0, 1, 1, 1), (0, 3, 0, 1), (9, 1, 1, 1), (0, 2000, 1, 8),
+                (2000, 50, 50, 32), (700, 100, 100, 160))
+
+
+def load_work(bench_dirs: List[pathlib.Path], name: str,
+              model: Dict[str, Any]) -> Any:
+    """The work file ``work/<name>.py``, refused unless its terms sum to its
+    whole on this ``model`` block: FLOPs of a span, bytes of a decode step,
+    bytes of the weights."""
+    work = load_module(bench_dirs, "work", name, WORK_GIVES)
+    terms = getattr(work, "TERMS", ())
+    if not terms:
+        raise ValueError(f"work/{name}.py names no TERMS")
+
+    def held(what: str, count: Any) -> None:
+        whole, parts = count(None), sum(count(term) for term in terms)
+        if abs(parts - whole) > 1e-9 * abs(whole):
+            raise ValueError(f"work/{name}.py: the terms' {what} sum to "
+                             f"{parts!r}, the whole is {whole!r}")
+
+    held("weight_bytes", lambda term: work.weight_bytes(model, term=term))
+    for start, count, with_head, rows in _WORK_PROBES:
+        held(f"span_flops{(start, count, with_head)}", lambda term:
+             work.span_flops(model, start, count, with_head, term=term))
+        held(f"step_bytes{(start, rows)}", lambda term:
+             work.step_bytes(model, start, rows, term=term))
+    return work
+
+
 def load_cell(bench_dirs: List[pathlib.Path], name: str) -> Cell:
     workload = load_json(find_file(bench_dirs, "workloads", name))
+    config = load_json(find_file(bench_dirs, "configs", workload["config"]))
     return Cell(
-        name=name, workload=workload,
-        config=load_json(find_file(bench_dirs, "configs", workload["config"])),
+        name=name, workload=workload, config=config,
         traffic=load_json(find_file(bench_dirs, "traffic", workload["traffic"])),
-        bench_dir=bench_dirs[0])
+        bench_dir=bench_dirs[0],
+        reference=load_module(bench_dirs, "references",
+                              config.get("reference", "dense"), REFERENCE_GIVES),
+        work=load_work(bench_dirs, config.get("work", "dense"), config["model"]))
 
 
 def load_metrics(bench_dirs: List[pathlib.Path]) -> Dict[str, Dict[str, Any]]:
@@ -68,24 +129,26 @@ def load_metrics(bench_dirs: List[pathlib.Path]) -> Dict[str, Dict[str, Any]]:
             metric = load_json(path)
             if metric["name"] in out:
                 continue
-            reader = find_file(bench_dirs, "readers", metric["reader"], ".py")
-            spec = importlib.util.spec_from_file_location(
-                f"benchmark_reader_{metric['reader']}", reader)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            metric["read"] = module.read
+            metric["read"] = load_module(
+                bench_dirs, "readers", metric["reader"], ("read",)).read
             out[metric["name"]] = metric
     return out
 
 
+def _hashable(value: Any) -> Any:
+    if isinstance(value, list):
+        return tuple(_hashable(v) for v in value)
+    return value
+
+
 def model_config(cell: Cell) -> Any:
-    """The program's ``ModelConfig`` for this cell's configuration file."""
+    """The program's ``ModelConfig`` for this cell's configuration file: the
+    ``model`` block whole, so a key the program lacks is a ``TypeError``
+    here and not a default, with every list a tuple (the config is a static
+    argument of ``jit`` and has to hash)."""
     from consensus_tpu.models.config import ModelConfig
 
-    fields = dict(cell.model)
-    fields["local_layer_pattern"] = tuple(fields["local_layer_pattern"])
-    if fields.get("rope_scaling") is not None:
-        fields["rope_scaling"] = tuple(fields["rope_scaling"])
+    fields = {key: _hashable(value) for key, value in cell.model.items()}
     return ModelConfig(name=cell.config["name"], **fields)
 
 

@@ -6,7 +6,7 @@ for it raises, it does not default to another chip's numbers.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Any, Dict, Tuple
 
 PEAKS: Dict[str, Dict[str, object]] = {
     "TPU v5 lite": {
@@ -26,3 +26,13 @@ def peaks(device_kind: str) -> Dict[str, object]:
         raise ValueError(
             f"no published peaks for device kind {device_kind!r}; known: "
             f"{sorted(PEAKS)}") from None
+
+
+def least_seconds(flops: float, bytes_: float,
+                  peak: Dict[str, Any]) -> Tuple[float, str]:
+    """(seconds, which bound sets them) on a chip with these peaks."""
+    by_compute = flops / peak["bf16_flops_per_s"]
+    by_bandwidth = bytes_ / peak["hbm_bytes_per_s"]
+    if by_compute >= by_bandwidth:
+        return by_compute, "compute"
+    return by_bandwidth, "bandwidth"

@@ -1,13 +1,13 @@
 """Useful work of a stretch of the run, from the records of the calls into
 the backend layer and nothing the implementation did: tokens by kind, the
-FLOPs they need, and the bytes the decode launches must read."""
+FLOPs they need, and the bytes the decode launches must read, as the cell's
+work file (``benchmark/work/<name>.py``) counts them."""
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from benchmark.lib import reference as ref
-from benchmark.lib import work
 
 
 class Lengths:
@@ -59,11 +59,14 @@ def _statements(calls: List[Dict[str, Any]]):
         yield text, [(call, made) for _, call, made in group]
 
 
-def tally(model: Dict[str, Any], calls: List[Dict[str, Any]], lo: float,
-          hi: float, lengths: "Lengths | None" = None) -> Dict[str, Dict[str, float]]:
+def tally(work: Any, model: Dict[str, Any], calls: List[Dict[str, Any]],
+          lo: float, hi: float, lengths: "Lengths | None" = None,
+          term: Optional[str] = None) -> Dict[str, Dict[str, float]]:
     """By kind of call (``generate``, ``score_matrix``, ``embed``): launches,
     useful tokens, FLOPs and (for generation) the bytes the decode steps must
     read, each call counted by the share of its span inside [lo, hi].
+    ``work`` is the cell's work file; with ``term`` the FLOPs and bytes are
+    that term's alone (one of ``work.TERMS``).
 
     The work is what a statement needs, not what the program made of it: a
     statement's prompt is prefilled once and every decode step reads the
@@ -85,12 +88,13 @@ def tally(model: Dict[str, Any], calls: List[Dict[str, Any]], lo: float,
         p = n(text, True)
         counts = [made for _, made in rows]
         tokens = p + sum(counts)
-        flops = work.span_flops(model, 0, p, 1) + sum(
-            work.span_flops(model, p, t, t) for t in counts)
-        bytes_ = float(work.weight_bytes(model))
+        flops = work.span_flops(model, 0, p, 1, term=term) + sum(
+            work.span_flops(model, p, t, t, term=term) for t in counts)
+        bytes_ = float(work.weight_bytes(model, term=term))
         for step in range(1, max(counts) + 1):
             cached = p + sum(min(t, step - 1) for t in counts)
-            bytes_ += work.step_bytes(model, cached)
+            decoding = sum(1 for t in counts if t >= step)
+            bytes_ += work.step_bytes(model, cached, decoding, term=term)
         for call, _ in rows:
             part = overlap(call["start"], call["end"], lo, hi) / len(rows)
             if part:
@@ -109,17 +113,18 @@ def tally(model: Dict[str, Any], calls: List[Dict[str, Any]], lo: float,
                             for a in request.agents]
                 for text in set(prefixes):
                     tokens += n(text, True)
-                    flops += work.span_flops(model, 0, n(text, True))
+                    flops += work.span_flops(model, 0, n(text, True), term=term)
                 for candidate in request.candidates:
                     c = n(candidate)
                     for text in prefixes:
                         tokens += c
-                        flops += work.span_flops(model, n(text, True), c, c)
+                        flops += work.span_flops(model, n(text, True), c, c,
+                                                 term=term)
             add(kind, share, tokens, flops)
         elif kind == "embed":
             prompts = [n(text, True) for text in call["requests"]]
             add(kind, share, sum(prompts),
-                sum(work.span_flops(model, 0, p) for p in prompts))
+                sum(work.span_flops(model, 0, p, term=term) for p in prompts))
     return out
 
 

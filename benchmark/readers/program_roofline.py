@@ -4,7 +4,7 @@ metric names (FLOPs over the peak against bytes over the bandwidth,
 whichever is larger), over the device seconds of the programs it names.
 Beside the share stand the bound that set the least seconds, and both."""
 
-from benchmark.lib import work
+from benchmark.lib.peaks import least_seconds
 
 
 def read(context, metric):
@@ -13,12 +13,11 @@ def read(context, metric):
         return None
     device_s = sum(seconds for name, seconds in trace["by_program_s"].items()
                    if any(part in name for part in metric["programs"]))
-    kinds = context["tally"](context["cell"].model, context["calls"],
-                             traced[0], traced[1])
+    kinds = context["tally"](context["calls"], traced[0], traced[1])
     flops = sum(kinds[k]["flops"] for k in metric["calls"] if k in kinds)
     bytes_ = sum(kinds[k]["bytes"] for k in metric["calls"] if k in kinds)
     if not device_s or not flops:
         return None
-    least, bound = work.least_seconds(flops, bytes_, peak)
+    least, bound = least_seconds(flops, bytes_, peak)
     return {"value": 100.0 * least / device_s, "bound": bound,
             "least_s": least, "device_s": device_s}

@@ -5,8 +5,8 @@ def read(context, metric):
     peak = context["peak"]
     if peak is None or not context["span_s"]:
         return None
-    kinds = context["tally"](context["cell"].model, context["calls"],
-                             context["first_send"], context["last_done"])
+    kinds = context["tally"](context["calls"], context["first_send"],
+                             context["last_done"])
     flops = sum(entry["flops"] for entry in kinds.values())
     if not flops:
         return None

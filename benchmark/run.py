@@ -7,7 +7,7 @@ server as ``python -m consensus_tpu.serve --backend tpu`` does, warms up what
 the cell's traffic uses (set-up; ``harness.warm_up`` says how), drives ``POST
 /v1/consensus`` over the loopback for ``--seconds`` seconds, frees the
 program's state, and holds a sample of what was served against the plain
-float32 reference.  The last line
+float32 reference that the cell's configuration names.  The last line
 of standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics``, ``device``, in a traced run ``breakdown``, and last ``compared``.
 
@@ -111,6 +111,9 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
 
     bench_dirs = [pathlib.Path(d).resolve() for d in args.bench_dir] + [HERE]
     cell = harness.load_cell(bench_dirs, args.workload)
+    # Before anything runs: a key of the model block that the cell's
+    # reference does not compute stops here, with the key in the message.
+    cfg = cell.reference.ref_config(cell.model)
     device = require_device(args.platform, int(cell.workload["chips"]))
     peak = peaks(device["kind"]) if device["platform"] == "tpu" else None
     metrics = harness.load_metrics(bench_dirs) if args.trace else {}
@@ -207,19 +210,18 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
 
         numbers = check.Numbers()
         numbers.add("truncated", truncated)
-        cfg = ref.ref_config(cell.model)
-        weights = ref.make_weights(cfg, args.seed)
-        sums = ref.weights_checksum(weights)
-        numbers.add("weights", sum(1 for k in sums if sums[k] != served_sums.get(k)),
-                    len(sums))
+        weights = cell.reference.make_weights(cfg, args.seed)
+        numbers.add("weights", *check.differing_leaves(
+            served_sums, ref.weights_checksum(weights)))
         jobs, sample = check.gather(
             cell, check.choose_sample(cell, sent, calls, args.seed), calls,
             numbers, args.seed)
         check_start = time.perf_counter()
-        check.compare(cfg, weights, jobs, numbers)
+        check.compare(cell.reference, cfg, weights, jobs, numbers)
         control = None
         if args.control:
-            control = check.control_numbers(cfg, weights, jobs, numbers)
+            control = check.control_numbers(cell.reference, cfg, weights, jobs,
+                                            numbers)
         del weights
         phases["window_s"] = e2e["window_span_s"]
         phases["check_s"] = time.perf_counter() - check_start
@@ -238,8 +240,6 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     if not args.trace:
         result["metrics"] = {
             "statements_per_s": {"value": e2e["statements_per_s"], "unit": "1/s"},
-            "time_to_statement_p50_s": {
-                "value": e2e["time_to_statement_p50_s"], "unit": "s"},
             "setup_s": {"value": setup_s, "unit": "s"},
         }
         if "time_to_statement_p90_s" in e2e:
@@ -275,7 +275,8 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
             "health_after": health_after, "compiled": compiled, "calls": calls,
             "trace": reduced, "traced": traced, "memory_peak_bytes": memory_peak,
             # One cache of token counts for every reader's tally.
-            "tally": functools.partial(useful.tally, lengths=lengths),
+            "tally": functools.partial(useful.tally, cell.work, cell.model,
+                                       lengths=lengths),
         }
         result["metrics"] = {}
         listed = {}
@@ -334,7 +335,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="cpu is for the rehearsal only")
     parser.add_argument("--bench-dir", action="append", default=[],
                         help="a further directory of configs/, traffic/, "
-                             "workloads/, metrics/ and readers/, searched first")
+                             "workloads/, metrics/, readers/, references/ and "
+                             "work/, searched first")
     parser.add_argument("--control", action="store_true",
                         help="also read the float8 control's numbers (for "
                              "setting limits; never part of a measured run)")

@@ -1,14 +1,18 @@
 """The harness end to end on the CPU at a tiny configuration: the shape of
 the last line, a configuration, a mix, a cell and a metric picked up as new
-files with no edit to a file that is there, the float8 control coming out
-not correct, and ``correct`` coming out false when the timed path is broken
-underneath.  Slow (minutes): run by hand, ``pytest benchmark/tests``.
+files with no edit to a file that is there, an architecture the dense
+reference refuses brought the same way with a reference and a work count of
+its own, the float8 control coming out not correct, and ``correct`` coming
+out false when the timed path is broken underneath.  Slow (minutes): run by
+hand, ``pytest benchmark/tests``.
 """
 
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
+import shutil
 import subprocess
 import sys
 
@@ -17,16 +21,21 @@ import pytest
 TESTS = pathlib.Path(__file__).resolve().parent
 ROOT = TESTS.parents[1]
 FIXTURE = TESTS / "fixture"
+POST_NORMS = TESTS / "fixture_post_norms"
 
 
-def run_cell(*args, bench_dirs=(FIXTURE,)):
+def launch(*args, bench_dirs=(FIXTURE,)):
     command = [sys.executable, str(ROOT / "benchmark" / "run.py"),
                "--platform", "cpu"]
     for d in bench_dirs:
         command += ["--bench-dir", str(d)]
-    done = subprocess.run(command + list(args), capture_output=True, text=True,
-                          cwd=ROOT, env={**__import__("os").environ,
-                                         "JAX_PLATFORMS": "cpu"}, timeout=1500)
+    return subprocess.run(command + list(args), capture_output=True, text=True,
+                          cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                          timeout=1500)
+
+
+def run_cell(*args, bench_dirs=(FIXTURE,)):
+    done = launch(*args, bench_dirs=bench_dirs)
     assert done.returncode == 0, done.stderr[-3000:]
     return json.loads(done.stdout.strip().splitlines()[-1]), done.stderr
 
@@ -36,7 +45,7 @@ def test_refuses_without_a_tpu():
         [sys.executable, str(ROOT / "benchmark" / "run.py"), "--workload",
          "smollm2-1.7b.bon_sweep", "--seed", "1", "--seconds", "1", "--trace", "0"],
         capture_output=True, text=True, cwd=ROOT,
-        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"}, timeout=300)
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=300)
     assert done.returncode == 2
     assert done.stdout.strip() == ""
 
@@ -96,11 +105,71 @@ def test_new_files_are_picked_up_and_control_fails(tmp_path):
     assert control["selection"]["value"] == 0
 
 
+def _tracked_files_changed():
+    done = subprocess.run(["git", "status", "--porcelain", "--untracked-files=no"],
+                          capture_output=True, text=True, cwd=ROOT)
+    return done.stdout if done.returncode == 0 else None  # None: no git here
+
+
+def test_a_new_architecture_comes_as_files(tmp_path):
+    """A later PR's architecture: a configuration the program runs and the
+    dense reference refuses, with a reference and a work count of its own, a
+    metric on a term of that count, its cell and its mix, all files of a
+    directory of their own and no file of the repository touched."""
+    before = _tracked_files_changed()
+    shutil.copytree(POST_NORMS, tmp_path, dirs_exist_ok=True)
+    for kind in ("traffic", "workloads"):
+        (tmp_path / kind).mkdir()
+    mix = json.loads((FIXTURE / "traffic" / "bon_small.json").read_text())
+    mix["name"] = "bon_three"
+    mix["request"]["params"]["n"] = 3
+    (tmp_path / "traffic" / "bon_three.json").write_text(json.dumps(mix))
+    cell = json.loads(
+        (FIXTURE / "workloads" / "tiny-dense.bon_small.json").read_text())
+    cell.update(name="tiny-post-norms.bon_three", config="tiny-post-norms",
+                traffic="bon_three")
+    (tmp_path / "workloads" / "tiny-post-norms.bon_three.json").write_text(
+        json.dumps(cell))
+    run = ("--workload", "tiny-post-norms.bon_three", "--seed", "2600000123",
+           "--seconds", "2", "--trace", "1", "--control")
+
+    line, _ = run_cell(*run, bench_dirs=(tmp_path, FIXTURE))
+    assert line["correct"] is True and line["failed"] == 0
+    compared = line["compared"]
+    for name in ("matrix_gap", "greedy_gap", "generated", "selection",
+                 "truncated", "weights"):
+        assert compared[name]["value"] <= compared[name]["limit"]
+    # Both trees hold the post-norms: 11 leaves a layer stack and 3 beside.
+    assert compared["weights"]["compared"] == 14
+    assert compared["greedy_gap"]["compared"] == 3 * 6
+    assert line["control_correct"] is False
+    assert (line["control"]["matrix_gap"]["value"]
+            > line["control"]["matrix_gap"]["limit"])
+    # The metric on the new term is found and read; like the dense rooflines
+    # it has no peak to be a share of on a CPU, and is left out, not 0.
+    for name in ("post_norm_roofline", "attention_roofline", "window_mfu_pct"):
+        assert name not in line["metrics"]
+    assert "engine_wait_ms" in line["metrics"]
+    assert line["metrics"]["time_to_statement_p50_s"]["value"] > 0
+    assert _tracked_files_changed() == before
+
+    # The same configuration held to the dense reference: refused in set-up,
+    # the key named, no result line.
+    path = tmp_path / "configs" / "tiny-post-norms.json"
+    config = json.loads(path.read_text())
+    del config["reference"]
+    path.write_text(json.dumps(config))
+    done = launch(*run, bench_dirs=(tmp_path, FIXTURE))
+    assert done.returncode not in (0, 2)
+    assert done.stdout.strip() == ""
+    assert "use_post_norms" in done.stderr.strip().splitlines()[-1]
+    assert "window of" not in done.stderr  # nothing was measured
+
+
 def test_untraced_line_has_the_end_to_end_metrics():
     line, stderr = run_cell("--workload", "tiny-dense.bon_small", "--seed", "5",
                             "--seconds", "2", "--trace", "0")
-    assert set(line["metrics"]) == {"statements_per_s", "time_to_statement_p50_s",
-                                    "setup_s"}
+    assert set(line["metrics"]) == {"statements_per_s", "setup_s"}
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["correct"] is True
     assert "breakdown" not in line and "control" not in line

@@ -4,6 +4,7 @@ the metric is left out of the line) where its spans or the trace are
 missing.  The harness end to end on the CPU prints the three metrics that
 come from the span trees."""
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -13,6 +14,7 @@ import pytest
 
 from benchmark.lib import useful, xplane_spans
 from benchmark.lib.peaks import peaks
+from benchmark.work import dense
 
 TESTS = pathlib.Path(__file__).resolve().parent
 BENCH = TESTS.parent
@@ -115,9 +117,10 @@ def test_span_tree_reader_reads_the_store():
 
 
 def context(**over):
-    base = {"cell": types.SimpleNamespace(name="no-such-cell", model=MODEL),
+    base = {"cell": types.SimpleNamespace(name="no-such-cell", model=MODEL,
+                                          work=dense),
             "peak": peaks("TPU v5 lite"), "traced": None, "calls": [],
-            "tally": useful.tally}
+            "tally": functools.partial(useful.tally, dense, MODEL)}
     return {**base, **over}
 
 

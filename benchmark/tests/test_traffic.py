@@ -10,7 +10,8 @@ import types
 
 import pytest
 
-from benchmark.lib import harness, traffic, useful, work
+from benchmark.lib import harness, traffic, useful
+from benchmark.work import dense as work
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 MIX = json.loads((BENCH / "traffic" / "bon_sweep.json").read_text())
@@ -110,8 +111,8 @@ def test_a_statement_s_work_does_not_follow_the_number_of_calls():
     one = [_generate_call(0.0, 4.0, range(base, base + 32))]
     four = [_generate_call(k, k + 1.0, range(base + 8 * k, base + 8 * k + 8))
             for k in range(4)]
-    a = useful.tally(MODEL, one, 0.0, 4.0)["generate"]
-    b = useful.tally(MODEL, four, 0.0, 4.0)["generate"]
+    a = useful.tally(work, MODEL, one, 0.0, 4.0)["generate"]
+    b = useful.tally(work, MODEL, four, 0.0, 4.0)["generate"]
     for key in ("tokens", "flops", "bytes"):
         assert a[key] == pytest.approx(b[key])
     assert (a["launches"], b["launches"]) == (1.0, 4.0)
@@ -125,7 +126,7 @@ def test_a_statement_s_work_does_not_follow_the_number_of_calls():
     # Another statement on the same prompt is another statement's work, and
     # half of the stretch holds half of it.
     two = one + [_generate_call(0.0, 4.0, range(5 * base, 5 * base + 32))]
-    assert useful.tally(MODEL, two, 0.0, 4.0)["generate"]["bytes"] == \
+    assert useful.tally(work, MODEL, two, 0.0, 4.0)["generate"]["bytes"] == \
         pytest.approx(2 * a["bytes"])
-    assert useful.tally(MODEL, one, 0.0, 2.0)["generate"]["bytes"] == \
+    assert useful.tally(work, MODEL, one, 0.0, 2.0)["generate"]["bytes"] == \
         pytest.approx(a["bytes"] / 2)

@@ -1,10 +1,11 @@
-"""The FLOP and byte functions against numbers worked by hand."""
+"""The dense work file's FLOP and byte functions against numbers worked by
+hand."""
 
 import json
 import pathlib
 
-from benchmark.lib import work
-from benchmark.lib.peaks import PEAKS, peaks
+from benchmark.lib.peaks import PEAKS, least_seconds, peaks
+from benchmark.work import dense as work
 
 import pytest
 
@@ -52,11 +53,14 @@ def test_span_flops_by_hand():
 
 def test_step_bytes_and_bound():
     m = model("mistral-7b-v0.3-h16")
-    assert work.step_bytes(m, 1000) == 7_516_463_104 + 1000 * 65536
-    seconds, bound = work.least_seconds(1e9, work.step_bytes(m, 0), peaks("TPU v5 lite"))
+    # Positions that rows share are read once: the rows change nothing.
+    for rows in (1, 32, 160):
+        assert work.step_bytes(m, 1000, rows) == 7_516_463_104 + 1000 * 65536
+    seconds, bound = least_seconds(1e9, work.step_bytes(m, 0, 1),
+                                   peaks("TPU v5 lite"))
     assert bound == "bandwidth"
     assert seconds == pytest.approx(7_516_463_104 / 819e9)
-    assert work.least_seconds(1e15, 1.0, peaks("TPU v5 lite"))[1] == "compute"
+    assert least_seconds(1e15, 1.0, peaks("TPU v5 lite"))[1] == "compute"
 
 
 def test_unknown_device_raises():

@@ -1,4 +1,4 @@
-"""The terms of the work counts, each alone, against ``work`` itself and
+"""The terms of the dense work file, each alone, against its whole and
 numbers worked by hand; and the tally by term over plain records."""
 
 import json
@@ -7,7 +7,8 @@ import types
 
 import pytest
 
-from benchmark.lib import scope_work, useful, work
+from benchmark.lib import useful
+from benchmark.work import dense as work
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
 MODELS = {name: json.loads((CONFIGS / f"{name}.json").read_text())["model"]
@@ -19,37 +20,40 @@ def test_the_three_terms_sum_to_span_flops(name):
     m = MODELS[name]
     for start, count, with_head in ((0, 2000, 1), (2000, 50, 50),
                                     (700, 100, 100), (9, 1, 1), (0, 3, 0)):
-        terms = (scope_work.attention_flops(m, start, count)
-                 + scope_work.head_flops(m, with_head)
-                 + scope_work.matrix_flops(m, count))
+        terms = sum(work.span_flops(m, start, count, with_head, term=term)
+                    for term in work.TERMS)
         assert terms == pytest.approx(
             work.span_flops(m, start, count, with_head), rel=1e-12)
         # each alone, by hand
         context = count * start + count * (count + 1) // 2
-        assert scope_work.attention_flops(m, start, count) == \
+        assert work.span_flops(m, start, count, with_head, term="attention") == \
             4 * context * m["n_heads"] * m["head_dim"] * m["n_layers"]
-        assert scope_work.head_flops(m, with_head) == \
+        assert work.span_flops(m, start, count, with_head, term="head") == \
             2 * with_head * m["vocab_size"] * m["d_model"]
-        assert scope_work.matrix_flops(m, count) == \
+        assert work.span_flops(m, start, count, with_head, term="matrix") == \
             2 * count * m["n_layers"] * work.layer_matmul_params(m)
 
 
 @pytest.mark.parametrize("name", sorted(MODELS))
 def test_the_cache_term_and_the_weights_sum_to_step_bytes(name):
     m = MODELS[name]
-    for cached in (0, 1, 3000):
-        assert scope_work.cache_bytes(m, cached) == \
-            cached * work.kv_bytes_per_token(m)
-        assert scope_work.cache_bytes(m, cached) + work.weight_bytes(m) == \
-            work.step_bytes(m, cached)
-    assert scope_work.head_bytes(m) == m["vocab_size"] * m["d_model"] * 2
-    assert scope_work.head_bytes(m) < work.weight_bytes(m)
+    for cached, rows in ((0, 1), (1, 8), (3000, 32)):
+        cache = work.step_bytes(m, cached, rows, term="attention")
+        assert cache == cached * work.kv_bytes_per_token(m)
+        assert cache + work.weight_bytes(m) == work.step_bytes(m, cached, rows)
+        assert sum(work.step_bytes(m, cached, rows, term=term)
+                   for term in work.TERMS) == work.step_bytes(m, cached, rows)
+    assert work.head_bytes(m) == m["vocab_size"] * m["d_model"] * 2
+    assert work.weight_bytes(m, term="head") == work.head_bytes(m)
+    assert work.weight_bytes(m, term="attention") == 0
+    assert sum(work.weight_bytes(m, term=term) for term in work.TERMS) == \
+        work.weight_bytes(m)
 
 
 def test_smollm2_head_by_hand():
     m = MODELS["smollm2-1.7b"]
-    assert scope_work.head_flops(m, 1) == 2 * 49152 * 2048
-    assert scope_work.head_bytes(m) == 201_326_592  # the tied table, 201 MB
+    assert work.span_flops(m, 0, 0, 1, term="head") == 2 * 49152 * 2048
+    assert work.head_bytes(m) == 201_326_592  # the tied table, 201 MB
 
 
 def _records():
@@ -74,31 +78,34 @@ def _records():
 def test_tally_by_term_sums_to_the_tally(name):
     m = MODELS[name]
     calls = _records()
-    whole = useful.tally(m, calls, 0.0, 4.0)
-    terms = scope_work.tally_terms(useful.tally, m, calls, 0.0, 4.0)
-    assert set(terms) == set(scope_work.TERMS)
+    whole = useful.tally(work, m, calls, 0.0, 4.0)
+    terms = {term: useful.tally(work, m, calls, 0.0, 4.0, term=term)
+             for term in work.TERMS}
     for kind, entry in whole.items():
         for key in ("flops", "bytes"):
-            assert sum(terms[t][kind][key] for t in scope_work.TERMS) == \
+            assert sum(terms[t][kind][key] for t in work.TERMS) == \
                 pytest.approx(entry[key], rel=1e-12)
+        # What is no work is the same under every term.
+        for key in ("launches", "tokens"):
+            assert {terms[t][kind][key] for t in work.TERMS} == {entry[key]}
     # By hand, the generation call: 8 rows of 20 tokens after a prompt of
     # 101 (100 bytes and the first token).  The head: the prompt's last
     # position and every sampled one; its table at the prefill and at each
     # of the 20 steps.  The cache: what 20 steps read.
     head = terms["head"]["generate"]
-    assert head["flops"] == scope_work.head_flops(m, 1 + 8 * 20)
-    assert head["bytes"] == pytest.approx(21 * scope_work.head_bytes(m))
+    assert head["flops"] == work.span_flops(m, 0, 0, 1 + 8 * 20, term="head")
+    assert head["bytes"] == pytest.approx(21 * work.head_bytes(m))
     cache = sum((101 + 8 * (step - 1)) * work.kv_bytes_per_token(m)
                 for step in range(1, 21))
     assert terms["attention"]["generate"]["bytes"] == pytest.approx(cache)
     assert terms["attention"]["generate"]["flops"] == pytest.approx(
-        scope_work.attention_flops(m, 0, 101)
-        + 8 * scope_work.attention_flops(m, 101, 20))
+        work.span_flops(m, 0, 101, term="attention")
+        + 8 * work.span_flops(m, 101, 20, term="attention"))
     # Scoring reads no bytes in the tally, and embeds have no head.
     assert terms["head"]["embed"]["flops"] == 0.0
     assert terms["attention"]["score_matrix"]["bytes"] == 0.0
     assert terms["head"]["score_matrix"]["flops"] == \
-        scope_work.head_flops(m, 2 * (30 + 40))
+        work.span_flops(m, 0, 0, 2 * (30 + 40), term="head")
     # Half of the embed call lies in the stretch.
     assert terms["matrix"]["embed"]["flops"] == pytest.approx(
-        0.5 * scope_work.matrix_flops(m, 51 + 71))
+        0.5 * work.span_flops(m, 0, 51 + 71, term="matrix"))
