@@ -245,6 +245,22 @@ class DecodeEngine:
         #: Requires ``decode_steps`` (the draft window IS the decode
         #: window); backends without the stream seam fall back exactly
         #: like plain multi-token decode.
+        #: A configuration with recurrent layers (``has_recurrent_state``
+        #: of the inner backend): a row holds a state beside its pages.  The
+        #: stream path's slots keep pages alone, so it is refused here, by
+        #: name, and not at the first cohort.
+        self.recurrent = bool(getattr(inner, "has_recurrent_state", False))
+        if self.recurrent and (decode_steps is not None or speculative):
+            from consensus_tpu.models.config import (
+                STREAM_NEEDS_STATE,
+                RecurrentStateUnsupported,
+            )
+
+            raise RecurrentStateUnsupported(
+                "the engine's stream path (decode_steps: paged_decode_steps, "
+                "paged_verify_steps, paged_gather_step)",
+                STREAM_NEEDS_STATE,
+            )
         self.speculative = bool(speculative)
         if self.speculative and self.decode_steps is None:
             # The draft window IS the decode window; speculative alone
@@ -281,6 +297,13 @@ class DecodeEngine:
         self.pools: List[PagePool] = [
             PagePool(int(num_pages), page_size) for _ in range(self.mesh_dp)
         ]
+        #: Pages of the pool that one resident row's recurrent state stands
+        #: for (0 without recurrent layers): ``_pages_needed`` reserves them
+        #: beside the row's tokens' pages.
+        state_pages = getattr(inner, "recurrent_state_pages", None)
+        self._state_pages = (
+            int(state_pages(page_size)) if callable(state_pages) else 0
+        )
         self.pool = self.pools[0]  # dp=1 alias; shard-0 pool under a mesh
         #: Cross-request prefix KV reuse (ROADMAP item 3): completed
         #: prompts donate their page-aligned prefix pages to a
@@ -306,8 +329,15 @@ class DecodeEngine:
                 if prefix_cache_pages is not None
                 else max(1, self.pool.num_pages // 4)
             )
+            # With recurrent layers a run of pages is no prefix: the cache
+            # declines every run, and counts them where the backend's other
+            # counters are.
+            declined = getattr(
+                getattr(inner, "instruments", None),
+                "record_prefix_run_declined", None)
             self.prefix_caches = [
-                PrefixCache(pool, budget, identity=identity)
+                PrefixCache(pool, budget, identity=identity,
+                            needs_state=self.recurrent, on_declined=declined)
                 for pool in self.pools
             ]
         self.prefix_cache = self.prefix_caches[0]
@@ -632,6 +662,7 @@ class DecodeEngine:
                     for key in (
                         "entries", "pages", "max_pages", "hits", "misses",
                         "evictions", "inserted_pages", "tokens_saved",
+                        "declined_runs",
                     )
                 }
                 total = agg["hits"] + agg["misses"]
@@ -1040,7 +1071,8 @@ class DecodeEngine:
 
     def _pages_needed(self, rows: List[_Row]) -> int:
         """Pages the cohort's program holds for a group, at most: the
-        prompt's once and every row's generated tokens."""
+        prompt's once and every row's generated tokens, and with recurrent
+        layers every row's state, in pages of the same pool."""
         pages = self.pool.pages_for_tokens
         prompt = rows[0].prompt_tokens
         if self._streams():
@@ -1048,7 +1080,8 @@ class DecodeEngine:
             # prompt's last page.
             return sum(pages(prompt + row.max_tokens) for row in rows)
         # One blocking generate: a prompt trunk and a tail a row.
-        return pages(prompt) + sum(pages(row.max_tokens) for row in rows)
+        return (pages(prompt) + sum(pages(row.max_tokens) for row in rows)
+                + len(rows) * self._state_pages)
 
     def _advance_prefill(self) -> None:
         for slot in self._slots:

@@ -51,7 +51,13 @@ from consensus_tpu.backends.base import (
     ScoreResult,
     TokenCandidate,
 )
-from consensus_tpu.models.config import ModelConfig, get_model_config
+from consensus_tpu.models.config import (
+    SEARCH_NEEDS_STATE,
+    STREAM_NEEDS_STATE,
+    ModelConfig,
+    RecurrentStateUnsupported,
+    get_model_config,
+)
 from consensus_tpu.obs.backends import BackendInstruments
 from consensus_tpu.obs.trace import span
 from consensus_tpu.models.generate import generate_tokens, next_token_topk
@@ -467,7 +473,39 @@ class TPUBackend:
             self.model_name,
             "int8" if self.kv_quant else "dense",
             ("tp", self._shard_count),
+            # Pages alone, or pages that mean nothing without the recurrent
+            # state at their end: the two kinds of model never share a run.
+            ("state", "recurrent" if self.config.has_ssm else "pages"),
         )
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """The configuration has recurrent layers: a row holds a state
+        beside its pages, and a run of pages is no prefix of it."""
+        return self.config.has_ssm
+
+    def _recurrent_row_bytes(self) -> int:
+        """Bytes of one row's recurrent state over all layers (0 dense)."""
+        itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
+        return self.config.ssm_state_bytes(itemsize) // self._shard_count
+
+    def _kv_page_bytes(self, page_size: int) -> int:
+        c = self.config
+        kv_itemsize = (
+            1.25
+            if self.kv_quant
+            else jnp.dtype(self.params["embed"].dtype).itemsize
+        )
+        bytes_per_token = int(
+            2 * c.n_layers * c.n_kv_heads * c.head_dim * kv_itemsize
+        ) // self._shard_count or 1
+        return bytes_per_token * page_size
+
+    def recurrent_state_pages(self, page_size: int = 16) -> int:
+        """One row's recurrent state in pages of the engine's pool, rounded
+        up: what the engine reserves a row beside its tokens' pages (0 for a
+        configuration without recurrent layers)."""
+        return -(-self._recurrent_row_bytes() // self._kv_page_bytes(page_size))
 
     def suggest_kv_page_pool(self, page_size: int = 16) -> int:
         """Size the decode engine's KV page pool from the session HBM
@@ -479,18 +517,12 @@ class TPUBackend:
         page count INCLUDES the prefix cache's share: the engine's LRU
         budget (a quarter of the pool by default) bounds how many of these
         pages cached prefixes may pin, so cache + resident slots can never
-        outgrow the reservation made here."""
-        c = self.config
-        kv_itemsize = (
-            1.25
-            if self.kv_quant
-            else jnp.dtype(self.params["embed"].dtype).itemsize
+        outgrow the reservation made here.  A configuration with recurrent
+        layers gets the same pool: the engine counts each resident row's
+        state against it in pages (``recurrent_state_pages``)."""
+        return max(
+            64, (self._session_budget.cap // 2) // self._kv_page_bytes(page_size)
         )
-        bytes_per_token = int(
-            2 * c.n_layers * c.n_kv_heads * c.head_dim * kv_itemsize
-        ) // self._shard_count or 1
-        page_bytes = bytes_per_token * page_size
-        return max(64, (self._session_budget.cap // 2) // page_bytes)
 
     def _sliced(self, requests, fn, limit: Optional[int] = None):
         """Run ``fn`` over ``limit``-sized slices (default max_batch_rows)
@@ -689,6 +721,11 @@ class TPUBackend:
         window instead of 1 per scan step, byte-identical token streams
         (exact sequential PRNG replay).
         """
+        if self.config.has_ssm:
+            raise RecurrentStateUnsupported(
+                "generate_stream (the engine's decode_steps)",
+                STREAM_NEEDS_STATE,
+            )
         return _PagedGenerateStream(
             self, list(requests), decode_steps, speculative=speculative
         )
@@ -771,7 +808,11 @@ class TPUBackend:
         unit = (
             2 * c.n_layers * c.n_kv_heads * c.head_dim * itemsize
         ) // self._shard_count
-        per_row = (prompt_width + 2 * max_new) * unit
+        # A recurrent layer's state rides the loop's carry beside the tail:
+        # two copies a row, whatever the prompt's length (0 bytes dense).
+        per_row = (
+            (prompt_width + 2 * max_new) * unit + 2 * self._recurrent_row_bytes()
+        )
         # Live search sessions hold real HBM reservations from the same
         # non-weight slice — generate batches must fit BESIDE them.
         budget = (
@@ -943,6 +984,9 @@ class TPUBackend:
                 "generate_shared",
                 (target, width, max_new, int(segmented), int(bias_table is not None)),
             )
+            if self.config.has_ssm:  # one trunk's state, forked to every row
+                self.instruments.record_state_fork(
+                    "generate", target, target * self._recurrent_row_bytes())
 
             pad = self.tokenizer.pad_id
             tokens = np.full((1, width), pad, np.int32)
@@ -1505,8 +1549,34 @@ class TPUBackend:
                 * self.config.head_dim * dtype.itemsize * 2
             )
 
+            # With recurrent layers the pool holds, beside the pages, one
+            # snapshot a unique context (at its page boundary) and every
+            # row's fork of its context's, going into the row's layers and
+            # coming out of them.
+            state_bytes = self._recurrent_row_bytes()
+            snapshot_rows = (
+                _bucket(len(prefix_ids), minimum=8) if state_bytes else 0
+            )
+
             def pool_bytes(n_rows: int) -> int:
-                return (shared_total + n_rows * max_private + 1) * page_bytes
+                return (
+                    (shared_total + n_rows * max_private + 1) * page_bytes
+                    + (snapshot_rows + 2 * n_rows) * state_bytes
+                )
+
+            width = _bucket(max_q, minimum=ps)
+
+            def fits_beside_weights(n_rows: int) -> bool:
+                """With recurrent layers alone: the chunk's own temporaries
+                (``_score_chunk_transient_bytes``) and its pool fit what the
+                weights leave.  Such a model is large beside its cache, and
+                the 3 GiB the budget leaves for temporaries is not what a
+                wide chunk of it takes."""
+                if not state_bytes:
+                    return True
+                return pool_bytes(n_rows) + self._score_chunk_transient_bytes(
+                    n_rows, width, max_blocks * ps
+                ) <= _HBM_BYTES - self._params_bytes
 
             total_rows = len(rows)
             chunk_rows = min(
@@ -1514,12 +1584,14 @@ class TPUBackend:
                 _bucket(max(self.max_batch_rows, 64), minimum=8),
             )
             budget = self._session_budget.cap
-            while chunk_rows > 1 and pool_bytes(chunk_rows) > budget:
+            while chunk_rows > 1 and (
+                pool_bytes(chunk_rows) > budget
+                or not fits_beside_weights(chunk_rows)
+            ):
                 chunk_rows //= 2
             if pool_bytes(chunk_rows) > budget:
                 return None  # even one row over-commits; per-call path chunks finer
             chunk_rows = max(chunk_rows, self._dp)
-            width = _bucket(max_q, minimum=ps)
             num_pages = shared_total + chunk_rows * max_private
             sink = num_pages
 
@@ -1528,7 +1600,8 @@ class TPUBackend:
         try:
             with span("backend.launch", program="make_page_state"):
                 state = make_page_state(
-                    self.config, num_pages, ps, dtype=dtype, mesh=mesh
+                    self.config, num_pages, ps, dtype=dtype, mesh=mesh,
+                    ssm_rows=snapshot_rows,
                 )
             state = self._prefill_shared_pages(state, prefix_ids, shared, sink, mesh)
             chunk_stats = []
@@ -1571,6 +1644,31 @@ class TPUBackend:
             path="fused",
         )
 
+    def _score_chunk_transient_bytes(
+        self, n_rows: int, width: int, keys: int
+    ) -> int:
+        """What one layer of a score chunk of ``n_rows`` x ``width`` over
+        ``keys`` gathered key positions holds at once, as the program is
+        written: the attention logits in float32 and their weights, the
+        feed-forward's gate, up and product, and the mixer's input product,
+        convolution, decay weights of a chunk, and the states at the chunks'
+        boundaries going in and coming out.  (For 64 x 256 x 1600 at
+        Falcon-H1's widths the TPU compiler's own count is 6.5 GB; this
+        gives 7.8.)"""
+        c = self.config
+        itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
+        cells = n_rows * width
+        attention = cells * c.n_heads * keys * (4 + itemsize)
+        ffn = cells * c.ffn_hidden * 3 * itemsize
+        chunks = -(-width // c.ssm_chunk)
+        layer_state = 4 * c.ssm_heads * c.ssm_head_dim * c.ssm_state
+        mixer = (
+            cells * (c.ssm_in_dim * itemsize + c.ssm_conv_dim * 8)
+            + cells * min(width, c.ssm_chunk) * c.ssm_heads * 8
+            + 2 * n_rows * chunks * layer_state
+        )
+        return attention + ffn + mixer
+
     def _prefill_shared_pages(self, state, prefix_ids, shared, sink, mesh):
         """Ingest every unique agent context's full pages (one row per
         unique prefix, chunked along the sequence).  Rows padding the pow2
@@ -1581,6 +1679,12 @@ class TPUBackend:
         pre = [p for p in prefix_ids if shared[p][1] > 0]
         if not pre:
             return state
+        if self.config.has_ssm:
+            # A row a context, in ``prefix_ids``' order: the rows of the
+            # state table are the contexts' snapshots, which the score
+            # chunk's ``ssm_rows`` index.  A context shorter than a page has
+            # no column here and keeps zeros.
+            pre = list(prefix_ids)
         n_rows = _bucket(len(pre), minimum=8)
         max_n0 = max(shared[p][2] for p in pre)
         chunk = min(256, _bucket(max_n0, minimum=ps))
@@ -1647,9 +1751,12 @@ class TPUBackend:
             lengths = np.zeros((n_rows,), np.int32)
             write_pages = np.full((n_rows, width), sink, np.int32)
             write_offsets = np.zeros((n_rows, width), np.int32)
+            snapshot_of = {p: i for i, p in enumerate(prefix_ids)}
+            ssm_rows = np.zeros((n_rows,), np.int32)
             for r, (prefix, cont, q_len, n_private) in enumerate(chunk):
                 ids = prefix_ids[prefix]
                 first, npg, n0 = shared[prefix]
+                ssm_rows[r] = snapshot_of[prefix]
                 stream = ids + cont
                 block = stream[n0 : n0 + q_len]
                 tokens[r, : q_len] = block
@@ -1676,11 +1783,16 @@ class TPUBackend:
             chunk_valid[n_real:] = chunk_valid[0]
             lengths[n_real:] = lengths[0]
             tables[n_real:] = tables[0]
+            ssm_rows[n_real:] = ssm_rows[0]
             self.instruments.record_padding(
                 "score_matrix", n_rows, width,
                 sum(q for (_, _, q, _) in chunk),
             )
             self.instruments.record_launch("score_matrix", (n_rows, width))
+            if self.config.has_ssm:  # each row starts from its context's state
+                self.instruments.record_state_fork(
+                    "score_matrix", n_real,
+                    (state.ssm.h.shape[1] + n_rows) * self._recurrent_row_bytes())
         # lengths is rank-1: jit's in-program constraint shards it.
         placed = self._place_batch(
             tokens, targets, score_mask, chunk_valid, tables,
@@ -1691,6 +1803,7 @@ class TPUBackend:
                 self.params, self.config, placed[0], placed[1], placed[2],
                 placed[3], state, placed[4], jnp.asarray(lengths), placed[5],
                 placed[6], mesh=mesh,
+                ssm_rows=jnp.asarray(ssm_rows) if self.config.has_ssm else None,
             )
 
     # -- next-token distribution ----------------------------------------------
@@ -1791,7 +1904,17 @@ class TPUBackend:
         fit alongside the weights (the session sizes its cache from the
         ACTUAL tokenized prefix width, so the check happens in its
         constructor, not on a pessimistic pre-tokenize bound) — the factory
-        then builds the full-prefix fallback over the CALLING backend."""
+        then builds the full-prefix fallback over the CALLING backend.
+
+        A configuration with recurrent layers is refused by name, and not
+        handed to the fallback: a search reorders and rolls back rows, and
+        neither session keeps a state to gather or to restore."""
+        if self.config.has_ssm:
+            raise RecurrentStateUnsupported(
+                "a token-search session (open_fused_token_search, "
+                "search_prefill, search_step, the rollouts)",
+                SEARCH_NEEDS_STATE,
+            )
         return TPUTokenSearchSession(self, spec)
 
     # -- embeddings ------------------------------------------------------------

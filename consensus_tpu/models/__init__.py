@@ -19,7 +19,14 @@ MODEL_PHASES = ("prefill", "decode_step")
 #: ``layers`` is the scan over the layers, and names what no scope of the
 #: layer body covers: the loop's own slicing of a layer's weights and cache
 #: out of the stacked arrays, and the stacking of what the layer returns.
+#: A block with a recurrent mixer (``transformer.ssm_mixer``) adds ``ssm_in``
+#: (the input product and its multipliers), ``ssm_conv`` (the causal
+#: convolution and its window), ``ssm_scan`` (step sizes, decays, the chunked
+#: or one-step recurrence, the skip term) and ``ssm_out`` (gate, grouped
+#: norm, output product); ``state_fork`` is a program copying one recurrent
+#: state to many rows (``transformer.fork_ssm``).
 MODEL_SCOPES = (
     "embed", "layers", "attn_qkv", "kv_write", "attention", "attn_out", "ffn",
     "final_norm", "vocab_projection", "logsumexp", "sample",
+    "ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "state_fork",
 ) + MODEL_PHASES

@@ -12,6 +12,25 @@ import dataclasses
 from typing import Optional, Tuple
 
 
+class RecurrentStateUnsupported(ValueError):
+    """A program or method that cannot carry a recurrent layer's state was
+    asked to run a configuration that has one.  ``what`` names it.  A
+    ``ValueError``: asking again cannot succeed, so the scheduler does not
+    retry, and the service answers a client error with this message."""
+
+    def __init__(self, what: str, why: str):
+        super().__init__(
+            f"{what} does not run a configuration with recurrent layers: {why}")
+        self.what = what
+
+
+#: Why the two families of programs that cannot carry the state refuse.
+SEARCH_NEEDS_STATE = ("beams reorder and rollouts roll back rows, which needs "
+                      "a gather and a checkpoint of the recurrent state")
+STREAM_NEEDS_STATE = ("the stream path's slots hold pages by position and no "
+                      "recurrent state by row")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny"
@@ -60,6 +79,77 @@ class ModelConfig:
     # vocabulary.  Teacher-forced scoring ignores it and keeps the
     # full-vocabulary logsumexp.
     sample_vocab: Optional[int] = None
+    # -- a Mamba-2 mixer beside attention in every block (Falcon-H1) --------
+    # ``ssm_heads`` 0 means no mixer: a dense block, as before.  With heads,
+    # every layer's normed input feeds the mixer and attention side by side
+    # and both are added to the residual (transformer.ssm_mixer).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    # Columns of the recurrent state a head keeps per channel (d_state).
+    ssm_state: int = 0
+    # B and C are shared by the heads of a group.
+    ssm_groups: int = 1
+    # Width of the causal depthwise convolution over x, B and C.
+    ssm_conv: int = 4
+    # Positions a chunk of the chunked scan holds.
+    ssm_chunk: int = 128
+    # Inner width (heads x head size); the gate z has it too.
+    ssm_inner: int = 0
+    # True: norm, then gate.  False (Falcon-H1): gate, then norm.
+    ssm_norm_before_gate: bool = False
+    # muP multipliers, each a multiplication where it stands; None = none.
+    embedding_multiplier: Optional[float] = None
+    attention_in_multiplier: Optional[float] = None
+    attention_out_multiplier: Optional[float] = None
+    key_multiplier: Optional[float] = None
+    ssm_in_multiplier: Optional[float] = None
+    # Over the slices [z | x | B | C | dt] of the mixer's input product.
+    ssm_slice_multipliers: Optional[Tuple[float, ...]] = None
+    ssm_out_multiplier: Optional[float] = None
+    # (on the gate product, inside the activation; on the down product).
+    mlp_multipliers: Optional[Tuple[float, float]] = None
+    lm_head_multiplier: Optional[float] = None
+
+    def __post_init__(self):
+        if self.ssm_heads and self.ssm_inner != self.ssm_heads * self.ssm_head_dim:
+            raise ValueError(
+                f"ssm_inner={self.ssm_inner} is not ssm_heads x ssm_head_dim "
+                f"({self.ssm_heads} x {self.ssm_head_dim})")
+        if self.ssm_heads and (self.ssm_heads % self.ssm_groups
+                               or self.ssm_inner % self.ssm_groups):
+            raise ValueError("ssm_groups must divide ssm_heads and ssm_inner")
+
+    @property
+    def has_ssm(self) -> bool:
+        """Recurrent layers: a state by row rides beside the KV cache."""
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Columns the convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """Columns of the mixer's input product: [z | x | B | C | dt]."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
+
+    @property
+    def ssm_slice_widths(self) -> Tuple[int, ...]:
+        """Widths of the slices [z | x | B | C | dt] of the input product,
+        the order ``ssm_slice_multipliers`` is in."""
+        gn = self.ssm_groups * self.ssm_state
+        return (self.ssm_inner, self.ssm_inner, gn, gn, self.ssm_heads)
+
+    def ssm_state_bytes(self, itemsize: int) -> int:
+        """Bytes of one row's recurrent state over all layers: the float32
+        matrix of every head and the convolution's window in the
+        activations' type.  0 for a dense configuration."""
+        if not self.has_ssm:
+            return 0
+        h = 4 * self.ssm_heads * self.ssm_head_dim * self.ssm_state
+        conv = itemsize * (self.ssm_conv - 1) * self.ssm_conv_dim
+        return self.n_layers * (h + conv)
 
     @property
     def q_scale(self) -> float:
@@ -175,6 +265,36 @@ MODEL_CONFIGS = {
         n_kv_heads=2,
         head_dim=16,
         ffn_hidden=128,
+    ),
+    # Falcon-H1's block at a test's size: a Mamba-2 mixer beside grouped-
+    # query attention, every multiplier at a value other than 1, an untied
+    # head.  The published sizes live in benchmark/configs/.
+    "tiny-falcon-h1": _llama3(
+        "tiny-falcon-h1",
+        vocab_size=512,
+        d_model=64,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=16,
+        ffn_hidden=128,
+        rope_theta=1e11,
+        ssm_heads=4,
+        ssm_head_dim=16,
+        ssm_state=16,
+        ssm_groups=2,
+        ssm_conv=4,
+        ssm_chunk=8,
+        ssm_inner=64,
+        embedding_multiplier=5.5,
+        attention_in_multiplier=0.9,
+        attention_out_multiplier=0.04,
+        key_multiplier=0.011,
+        ssm_in_multiplier=0.25,
+        ssm_slice_multipliers=(0.35, 0.25, 0.18, 0.5, 0.36),
+        ssm_out_multiplier=0.09,
+        mlp_multipliers=(0.18, 0.011),
+        lm_head_multiplier=0.0078,
     ),
 }
 

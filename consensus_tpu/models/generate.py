@@ -24,6 +24,7 @@ from consensus_tpu.models.config import ModelConfig
 from consensus_tpu.models.sampling import ban_undecodable, sample_tokens
 from consensus_tpu.models.transformer import (
     KVCache,
+    fork_ssm,
     forward,
     forward_trunk_tail,
     make_cache,
@@ -279,6 +280,7 @@ def _decode_segment(
     quantize_tail: bool = False,
     presence: Optional[jax.Array] = None,  # (B, V) bool seen-token mask
     rep_penalty: Optional[jax.Array] = None,  # (B,) float32
+    ssm=None,  # (L, B, ...) recurrent state a later segment starts from
 ):
     """One ``seg_len``-step slice of a decode, B = n_slots * n_roles rows.
 
@@ -297,9 +299,17 @@ def _decode_segment(
     Serves both decode layouts: shared-trunk (n_slots=B, n_roles=1 — every
     row broadcast-attends trunk row 0) and classic per-row trunks
     (n_slots=1, n_roles=B).
+
+    A configuration with recurrent layers carries each row's state through
+    the loop beside the tail.  The first segment takes it from the trunk:
+    row for row in the classic layout, one row forked to all B in the shared
+    one (there is no broadcasting a state that every row then changes).  A
+    later segment is handed ``ssm``, what the one before returned last.
     """
     c = config
     batch = n_slots * n_roles
+    if c.has_ssm and ssm is None:
+        ssm = trunk.ssm if n_roles == batch else fork_ssm(trunk.ssm, batch)
     if eos_ids is None:
         eos_ids = jnp.zeros((0,), jnp.int32)
     if bias_table is not None:
@@ -351,6 +361,7 @@ def _decode_segment(
         (i, next_logits, tail_k, tail_v, done, key, cur_pos, tokens_buf,
          emitted_buf) = carry[:9]
         pres = carry[9] if use_rp else None
+        state = carry[-1] if c.has_ssm else None
         if key.ndim == 2:  # per-row keys: rows draw independently
             pairs = jax.vmap(jax.random.split)(key)
             key, sub = pairs[:, 0], pairs[:, 1]
@@ -371,11 +382,12 @@ def _decode_segment(
         new_done = done | token_is_eos
 
         pos = cur_pos + 1
-        hidden, tail_k, tail_v = forward_trunk_tail(
+        hidden, tail_k, tail_v, state = forward_trunk_tail(
             params, config, token, pos, trunk, tail_k, tail_v,
             tail_positions, i, n_slots, n_roles,
             frozen_k=frozen_k, frozen_v=frozen_v,
             frozen_positions=tuple(frozen_positions),
+            ssm=state,
         )
         logits = project_logits(params, config, hidden)
         tokens_buf = jax.lax.dynamic_update_slice(tokens_buf, token[None], (i, 0))
@@ -386,19 +398,20 @@ def _decode_segment(
             i + 1, logits, tail_k, tail_v, new_done, key, pos,
             tokens_buf, emitted_buf,
         )
-        return out + ((pres,) if use_rp else ())
+        return out + ((pres,) if use_rp else ()) + (
+            (state,) if c.has_ssm else ())
 
     init = (
         jnp.asarray(0, jnp.int32), next_logits, tail_k, tail_v,
         done, keys, cur_pos, tokens_buf, emitted_buf,
-    ) + ((presence,) if use_rp else ())
+    ) + ((presence,) if use_rp else ()) + ((ssm,) if c.has_ssm else ())
     with jax.named_scope("decode_step"):
         final = jax.lax.while_loop(cond, body, init)
     (_, next_logits, tail_k, tail_v, done, keys, _, tokens_buf, emitted_buf) = final[:9]
     presence = final[9] if use_rp else None
     return (
         tokens_buf, emitted_buf, next_logits, tail_k, tail_v, done, keys,
-        presence,
+        presence, final[-1] if c.has_ssm else None,
     )
 
 
@@ -464,12 +477,13 @@ def _segmented_loop(
 
     frozen_k: list = []
     frozen_v: list = []
+    ssm = None  # the first segment takes the rows' state from the trunk
     tokens = np.full((orig_batch, max_new_tokens), pad_id, np.int32)
     emitted = np.zeros((orig_batch, max_new_tokens), bool)
     n_segs = max_new_tokens // seg_len
     for seg in range(n_segs):
         (tokens_buf, emitted_buf, next_logits, tail_k, tail_v, done, keys,
-         presence) = (
+         presence, ssm) = (
             _decode_segment(
                 params, config, trunk, tuple(frozen_k), tuple(frozen_v),
                 base_pos, jnp.asarray(seg * seg_len, jnp.int32),
@@ -482,7 +496,7 @@ def _segmented_loop(
                 logit_bias=logit_bias,
                 bias_table=bias_table, bias_index=bias_index, pad_id=pad_id,
                 quantize_tail=kv_quant,
-                presence=presence, rep_penalty=rep_penalty,
+                presence=presence, rep_penalty=rep_penalty, ssm=ssm,
             )
         )
         col = seg * seg_len
@@ -533,6 +547,8 @@ def _segmented_loop(
                     presence = take(presence, idx, axis=0)
                 if rep_penalty is not None:
                     rep_penalty = take(rep_penalty, idx, axis=0)
+                if ssm is not None:
+                    ssm = jax.tree.map(lambda a: take(a, idx, axis=1), ssm)
                 if not shared_layout:
                     # Classic layout: the trunk is per-row too.
                     trunk = jax.tree.map(
@@ -715,6 +731,7 @@ def generate_tokens_segmented(
             v=_quantize_kv(trunk.v),
             key_positions=trunk.key_positions,
             key_valid=trunk.key_valid,
+            ssm=trunk.ssm,
         )
     # Bucket-padding dummy rows (no valid prompt tokens) start done —
     # matches generate_tokens' init_done.

@@ -43,11 +43,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from consensus_tpu.models.config import ModelConfig
+from consensus_tpu.models.config import (
+    SEARCH_NEEDS_STATE,
+    STREAM_NEEDS_STATE,
+    ModelConfig,
+)
 from consensus_tpu.models.generate import left_pad_positions
 from consensus_tpu.models.quant import matmul
 from consensus_tpu.models.transformer import (
     KVCache,
+    RecurrentStateUnsupported,
+    SSMState,
+    _times,
     attn_out_block,
     embed_tokens,
     ffn_block,
@@ -55,9 +62,12 @@ from consensus_tpu.models.transformer import (
     forward,
     forward_shared_trunk,
     forward_trunk_tail,
+    fork_ssm,
     make_cache,
+    make_ssm_state,
     project_logits,
     rms_norm,
+    ssm_mixer,
     apply_rope,
     _softcap,
 )
@@ -68,6 +78,14 @@ from consensus_tpu.ops.welfare import (
     WELFARE_RULES,
     sanitize_utilities,
 )
+
+
+def refuse_recurrent(config: ModelConfig, what: str, why: str) -> None:
+    """Programs that reorder, roll back or page rows by position alone have
+    nowhere to keep a recurrent layer's state: they say so by name, when
+    they are traced, for a configuration that has one."""
+    if config.has_ssm:
+        raise RecurrentStateUnsupported(what, why)
 
 
 class SearchState(NamedTuple):
@@ -199,6 +217,7 @@ def search_prefill(
     """Prefill the (ref + agents) prefixes ONCE into the shared trunk,
     allocate empty per-(slot x role) tails, and return the root proposals
     (every slot starts identical)."""
+    refuse_recurrent(config, "search_prefill", SEARCH_NEEDS_STATE)
     w0 = prefix_tokens.shape[1]
     c = config
     positions = left_pad_positions(prefix_valid)
@@ -254,6 +273,7 @@ def search_step(
     """Advance every beam slot from its parent by one token; propose + score.
     Only the per-row TAILS are gathered on beam reorders — the shared trunk
     is untouched."""
+    refuse_recurrent(config, "search_step", SEARCH_NEEDS_STATE)
     parents, tokens = advance[0], advance[1]
     step_index, write_col = step_meta[0], step_meta[1]
     rows = jnp.arange(n_beams * n_roles)
@@ -268,7 +288,7 @@ def search_step(
     tail_positions = jax.lax.dynamic_update_slice(
         tail_positions, cur_pos[:, None], (0, write_col)
     )
-    hidden, tail_k, tail_v = forward_trunk_tail(
+    hidden, tail_k, tail_v, _ = forward_trunk_tail(
         params, config, row_tokens, cur_pos,
         state.trunk, tail_k, tail_v, tail_positions, write_col,
         n_beams, n_roles,
@@ -309,6 +329,7 @@ def suffix_propose(
     the packed (P, k, 2 + A) candidate array; the session state is
     untouched, so a lookahead tree costs one call per LEVEL and zero cache
     duplication."""
+    refuse_recurrent(config, "suffix_propose", SEARCH_NEEDS_STATE)
     n_paths = suffix_tokens.shape[0]
     cache, _ = _scratch_cache(state, t_filled, extra=0)
     hidden = forward_shared_trunk(
@@ -347,6 +368,7 @@ def rollout_scored(
     state is copied into a widened scratch, so it stays untouched.  Replaces
     the reference's rollout + per-agent full-statement scoring
     (mcts.py:470-651) — the call that its own NameError bug aborts."""
+    refuse_recurrent(config, "rollout_scored", SEARCH_NEEDS_STATE)
     scratch, write_index = _scratch_cache(
         state, t_filled, extra=suffix_len + depth
     )
@@ -443,6 +465,7 @@ def rollout_scored_many(
     steps ever attend).  The einsum attention path is forced because the
     scratch trunk has interior invalid columns (see forward_trunk_tail).
     """
+    refuse_recurrent(config, "rollout_scored_many", SEARCH_NEEDS_STATE)
     c = config
     n_paths = suffix_tokens.shape[0]
     rows = n_paths * n_roles
@@ -507,7 +530,7 @@ def rollout_scored_many(
             kp_tail, pos[:, None], (0, write_col)
         )
         row_tokens = jnp.repeat(token, n_roles)  # path-major (rows,)
-        hidden2, k_tail, v_tail = forward_trunk_tail(
+        hidden2, k_tail, v_tail, _ = forward_trunk_tail(
             params, config, row_tokens, pos,
             scratch, k_tail, v_tail, kp_tail, write_col,
             n_paths, n_roles,
@@ -580,6 +603,7 @@ def rollout_verify_many(
     already pin (exact ids, allclose totals) — re-pinned for this program
     on tiny models in tests/test_speculative.py.  The session state is
     untouched."""
+    refuse_recurrent(config, "rollout_verify_many", SEARCH_NEEDS_STATE)
     n_paths = suffix_tokens.shape[0]
     suffix_tokens = _constrain(suffix_tokens, mesh, "data", None)
     draft_tokens = _constrain(draft_tokens, mesh, "data", None)
@@ -652,10 +676,17 @@ def rollout_verify_many(
 class PagedSlotState(NamedTuple):
     """Device page pool: K/V for every resident slot, owned by block tables
     host-side.  Shape (L, num_pages + 1, page_size, KV, hd); the final page
-    is the write sink."""
+    is the write sink.
+
+    ``ssm``: the other kind of state, for a configuration with recurrent
+    layers: one state a row (``transformer.SSMState``, (L, rows, ...)),
+    whatever the row's length.  The chunked prefill carries each row's state
+    from chunk to chunk here; the score chunk reads the same table as its
+    rows' snapshots (``ssm_rows``) and hands it back untouched."""
 
     k_pages: jax.Array
     v_pages: jax.Array
+    ssm: Optional[SSMState] = None
 
 
 def _constrain(x: jax.Array, mesh: Optional[Mesh], *axes) -> jax.Array:
@@ -689,6 +720,7 @@ def _constrain_state(
     return PagedSlotState(
         _constrain(state.k_pages, mesh, None, None, None, "model", None),
         _constrain(state.v_pages, mesh, None, None, None, "model", None),
+        state.ssm,
     )
 
 
@@ -698,10 +730,17 @@ def make_page_state(
     page_size: int,
     dtype=jnp.float32,
     mesh: Optional[Mesh] = None,
+    ssm_rows: int = 0,
 ) -> PagedSlotState:
+    """``ssm_rows``: rows of zero recurrent state to hold beside the pages
+    (one a context the pool's prefill will run); a configuration without
+    recurrent layers holds none whatever is asked."""
     c = config
     shape = (c.n_layers, num_pages + 1, page_size, c.n_kv_heads, c.head_dim)
-    state = PagedSlotState(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    state = PagedSlotState(
+        jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+        make_ssm_state(c, ssm_rows, dtype) if ssm_rows else None,
+    )
     if mesh is not None:
         kv_axis = "model" if c.n_kv_heads % mesh.shape["model"] == 0 else None
         sharding = NamedSharding(
@@ -710,6 +749,7 @@ def make_page_state(
         state = PagedSlotState(
             jax.device_put(state.k_pages, sharding),
             jax.device_put(state.v_pages, sharding),
+            state.ssm,
         )
     return state
 
@@ -724,24 +764,36 @@ def _paged_forward(
     lengths: jax.Array,  # (B,) int32 — INCLUDING this call's tokens
     write_pages: jax.Array,  # (B, S) int32 — sink for invalid columns
     write_offsets: jax.Array,  # (B, S) int32
+    valid: Optional[jax.Array] = None,  # (B, S) bool — real columns
+    ssm: Optional[SSMState] = None,  # (L, B, ...) the rows' recurrent state
 ):
     """Shared body of chunked prefill and the decode step: write this
     call's K/V into the pages the cursors name, then attend every query
-    through its slot's block table.  Returns (hidden (B, S, D), state)."""
+    through its slot's block table.  Returns (hidden (B, S, D), state); the
+    state's ``ssm`` is the rows' recurrent state after their ``valid``
+    columns (a configuration with recurrent layers has to be handed both)."""
     b, s = tokens.shape
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+    if c.has_ssm and (ssm is None or valid is None):
+        raise RecurrentStateUnsupported(
+            "this paged program", "its rows carry pages and no recurrent state")
     x = embed_tokens(params, c, tokens)
     local_flags = jnp.asarray(c.local_flags)
 
     def layer_step(x, scanned):
-        lp, kp_l, vp_l, is_local = scanned
+        lp, kp_l, vp_l, is_local, ssm_l = scanned
         with jax.named_scope("attn_qkv"):
             attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            q = matmul(attn_in, lp["wq"]).reshape(b, s, h, hd)
-            k = matmul(attn_in, lp["wk"]).reshape(b, s, kv, hd)
-            v = matmul(attn_in, lp["wv"]).reshape(b, s, kv, hd)
+            qkv_in = _times(attn_in, c.attention_in_multiplier)
+            q = matmul(qkv_in, lp["wq"]).reshape(b, s, h, hd)
+            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+                b, s, kv, hd)
+            v = matmul(qkv_in, lp["wv"]).reshape(b, s, kv, hd)
             q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
             k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
+        mixed = None
+        if c.has_ssm:
+            mixed, ssm_l = ssm_mixer(c, lp, attn_in, ssm_l, valid)
 
         # Scatter the fresh K/V into their pages.  Cursor pairs are unique
         # across rows (slots own disjoint pages) except the sink, which is
@@ -766,15 +818,16 @@ def _paged_forward(
                     lambda _: attend(None),
                     None,
                 )
-        x = attn_out_block(c, lp, x, attn.reshape(b, s, h * hd))
-        return ffn_block(c, lp, x), (kp_l, vp_l)
+        x = attn_out_block(c, lp, x, attn.reshape(b, s, h * hd), mixed)
+        return ffn_block(c, lp, x), (kp_l, vp_l, ssm_l)
 
     with jax.named_scope("layers"):
-        x, (new_k, new_v) = jax.lax.scan(
+        x, (new_k, new_v, new_ssm) = jax.lax.scan(
             layer_step, x,
-            (params["layers"], state.k_pages, state.v_pages, local_flags),
+            (params["layers"], state.k_pages, state.v_pages, local_flags,
+             ssm if c.has_ssm else None),
         )
-    return final_norm(params, c, x), PagedSlotState(new_k, new_v)
+    return final_norm(params, c, x), PagedSlotState(new_k, new_v, new_ssm)
 
 
 @functools.partial(
@@ -801,6 +854,11 @@ def paged_prefill_chunk(
     program.  Returns the final-norm hidden of each slot's LAST valid chunk
     position (B, D) — callers project logits only when the prompt is
     complete — and the updated page state.
+
+    With recurrent layers ``state.ssm`` holds one state a row of this call:
+    each row's goes in at its stream position ``lengths - valid_count`` and
+    comes out after its valid columns, so a prompt's chunks hand it on, and a
+    row with no valid column keeps what it has.
     """
     b, chunk = tokens.shape
     tokens = _constrain(tokens, mesh, "data", None)
@@ -816,6 +874,7 @@ def paged_prefill_chunk(
     hidden, state = _paged_forward(
         params, config, tokens, positions, state,
         block_tables, lengths, write_pages, write_offsets,
+        valid=chunk_valid, ssm=state.ssm,
     )
     state = _constrain_state(state, mesh)
     last = jnp.maximum(n_valid - 1, 0)
@@ -845,6 +904,7 @@ def paged_decode_step(
     f32, updated page state); under a mesh the logits come out sharded
     (slots over ``data``, vocab over ``model`` — the embedding's row shards
     produce vocab-sharded logits and argmax reductions ride ICI)."""
+    refuse_recurrent(config, "paged_decode_step", STREAM_NEEDS_STATE)
     tokens = _constrain(tokens, mesh, "data")
     block_tables = _constrain(block_tables, mesh, "data", None)
     lengths = _constrain(lengths, mesh, "data")
@@ -922,6 +982,7 @@ def paged_decode_steps(
     ``tokens``/``emitted``/``done`` (small int/bool arrays) and the KV state
     never crosses the device boundary.
     """
+    refuse_recurrent(config, "paged_decode_steps", STREAM_NEEDS_STATE)
     batch = logits.shape[0]
     page_size = state.k_pages.shape[2]
     sink = state.k_pages.shape[1] - 1
@@ -1078,6 +1139,7 @@ def paged_verify_steps(
     bonus token), and the trailing tuple re-enters the next window's
     dispatch with ``has_pending=True``.
     """
+    refuse_recurrent(config, "paged_verify_steps", STREAM_NEEDS_STATE)
     batch = draft_tokens.shape[0]
     assert draft_tokens.shape[1] == num_steps, (
         "draft_tokens must carry num_steps columns"
@@ -1234,6 +1296,7 @@ def paged_score_chunk(
     write_pages: jax.Array,  # (B, S) int32 — private pages / sink
     write_offsets: jax.Array,  # (B, S) int32
     mesh: Optional[Mesh] = None,  # static: rows over data, heads over model
+    ssm_rows: Optional[jax.Array] = None,  # (B,) int32 — row of state.ssm
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array], PagedSlotState]:
     """Teacher-forced scoring of one (candidates x agents) row chunk over
     shared context pages, reduced ON DEVICE.
@@ -1254,6 +1317,12 @@ def paged_score_chunk(
     per-row reductions ``(sum_lp, last_lp, sum_exp_lp, count)`` — enough
     for every consumer statistic (mean / sum / last / moments) — and the
     updated page state.  No per-token vector survives to be fetched.
+
+    With recurrent layers the shared pages are half of a context: the other
+    half is the state at the page boundary where the row's query block
+    starts.  ``state.ssm`` holds those snapshots, one a context as the
+    prefill left them, and ``ssm_rows`` names each row's; the row's copy
+    runs on through its block and is dropped, the snapshots stay.
     """
     tokens = _constrain(tokens, mesh, "data", None)
     targets = _constrain(targets, mesh, "data", None)
@@ -1268,11 +1337,14 @@ def paged_score_chunk(
     n_valid = jnp.sum(chunk_valid.astype(jnp.int32), axis=1)  # (B,)
     start = lengths - n_valid
     positions = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    snapshots = state.ssm
     hidden, state = _paged_forward(
         params, config, tokens, positions, state,
         block_tables, lengths, write_pages, write_offsets,
+        valid=chunk_valid,
+        ssm=fork_ssm(snapshots, ssm_rows) if config.has_ssm else None,
     )
-    state = _constrain_state(state, mesh)
+    state = _constrain_state(state._replace(ssm=snapshots), mesh)
     mask = score_mask & chunk_valid
 
     def score_col(carry, xs):
@@ -1371,6 +1443,7 @@ def paged_gather_step(
     that position to float tolerance — pinned against the dense forward
     in tests/test_engine.py.  Returns (logits (B, V) f32, state) — only
     the sink page changed."""
+    refuse_recurrent(config, "paged_gather_step", STREAM_NEEDS_STATE)
     num_pages = state.k_pages.shape[1] - 1
     b = tokens.shape[0]
     tokens = _constrain(tokens, mesh, "data")

@@ -23,12 +23,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from consensus_tpu.models.config import ModelConfig
+from consensus_tpu.models.config import ModelConfig, RecurrentStateUnsupported
 from consensus_tpu.models.quant import (
     gather_target_logits,
     head_matmul,
@@ -38,6 +38,7 @@ from consensus_tpu.models.quant import (
 )
 
 Params = Dict[str, Any]
+
 
 MASK_FILL = -1e9  # finite fill: pad query rows softmax to uniform, not NaN
 
@@ -54,26 +55,40 @@ def init_params(
     c = config
     keys = jax.random.split(key, 8)
 
-    def dense(k, *shape, scale=None):
+    def dense(k, *shape, scale=None, over=None):
+        # ``over``: the multipliers that stand between this matrix and its
+        # branch's output.  The draw is divided by them, so that the branch
+        # comes out of unit order (a checkpoint's weights are as large).
         scale = scale if scale is not None else shape[-2] ** -0.5
+        for m in over or ():
+            if m is not None:
+                scale = scale / m
         return (jax.random.normal(k, shape) * scale).astype(dtype)
 
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+    a_in = c.attention_in_multiplier
+    mlp = c.mlp_multipliers or (None, None)
     layers = {
         "attn_norm": jnp.zeros((c.n_layers, c.d_model), dtype)
         if c.rmsnorm_style == "gemma"
         else jnp.ones((c.n_layers, c.d_model), dtype),
-        "wq": dense(keys[0], c.n_layers, c.d_model, h * hd),
-        "wk": dense(keys[1], c.n_layers, c.d_model, kv * hd),
-        "wv": dense(keys[2], c.n_layers, c.d_model, kv * hd),
-        "wo": dense(keys[3], c.n_layers, h * hd, c.d_model),
+        "wq": dense(keys[0], c.n_layers, c.d_model, h * hd, over=(a_in,)),
+        "wk": dense(keys[1], c.n_layers, c.d_model, kv * hd,
+                    over=(a_in, c.key_multiplier)),
+        "wv": dense(keys[2], c.n_layers, c.d_model, kv * hd, over=(a_in,)),
+        "wo": dense(keys[3], c.n_layers, h * hd, c.d_model,
+                    over=(c.attention_out_multiplier,)),
         "ffn_norm": jnp.zeros((c.n_layers, c.d_model), dtype)
         if c.rmsnorm_style == "gemma"
         else jnp.ones((c.n_layers, c.d_model), dtype),
-        "w_gate": dense(keys[4], c.n_layers, c.d_model, c.ffn_hidden),
+        "w_gate": dense(keys[4], c.n_layers, c.d_model, c.ffn_hidden,
+                        over=(mlp[0],)),
         "w_up": dense(keys[5], c.n_layers, c.d_model, c.ffn_hidden),
-        "w_down": dense(keys[6], c.n_layers, c.ffn_hidden, c.d_model),
+        "w_down": dense(keys[6], c.n_layers, c.ffn_hidden, c.d_model,
+                        over=(mlp[1],)),
     }
+    if c.has_ssm:
+        layers.update(_init_ssm_params(c, key, dtype))
     if c.use_post_norms:
         # Distinct buffers per leaf — aliased leaves break donation
         # (e.g. the quantization jit donates the whole pytree).
@@ -87,10 +102,13 @@ def init_params(
         layers["post_attn_norm"] = norm_init()
         layers["post_ffn_norm"] = norm_init()
 
+    embed_scale = (
+        0.02 if c.embedding_multiplier is None else 1.0 / c.embedding_multiplier
+    )
     params: Params = {
-        "embed": (jax.random.normal(keys[7], (c.vocab_size, c.d_model)) * 0.02).astype(
-            dtype
-        ),
+        "embed": (
+            jax.random.normal(keys[7], (c.vocab_size, c.d_model)) * embed_scale
+        ).astype(dtype),
         "layers": layers,
         "final_norm": jnp.zeros((c.d_model,), dtype)
         if c.rmsnorm_style == "gemma"
@@ -98,9 +116,60 @@ def init_params(
     }
     if not c.tie_lm_head:
         params["lm_head"] = dense(
-            jax.random.fold_in(keys[7], 1), c.vocab_size, c.d_model, scale=c.d_model**-0.5
+            jax.random.fold_in(keys[7], 1), c.vocab_size, c.d_model,
+            scale=c.d_model**-0.5, over=(c.lm_head_multiplier,),
         )
     return params
+
+
+#: ``fold_in`` data of the mixer's leaves, beside the eight keys that
+#: ``init_params`` splits (a split's keys are no fold of its parent).
+_SSM_KEY_BASE = 100
+
+
+def _init_ssm_params(c: ModelConfig, key: jax.Array, dtype) -> Params:
+    """The mixer's leaves, stacked over layers, with Mamba-2's own ranges so
+    that state carries: ``A = -exp(a_log)`` in [-16, -1], a step size whose
+    bias is the inverse softplus of a log-uniform draw in [0.001, 0.1],
+    ``D = 1``.  The three per-head vectors stay float32 whatever ``dtype``."""
+    n, d = c.n_layers, c.d_model
+    k_in, k_conv, k_bias, k_a, k_dt, k_out = (
+        jax.random.fold_in(key, _SSM_KEY_BASE + i) for i in range(6)
+    )
+    # One scale a column: each slice of [z | x | B | C | dt] is divided by
+    # its own multiplier (and all by the input's), so each is of unit order.
+    slices = c.ssm_slice_multipliers or (1.0,) * 5
+    col_scale = jnp.concatenate([
+        jnp.full((w,), d**-0.5 / (m * (c.ssm_in_multiplier or 1.0)), jnp.float32)
+        for w, m in zip(c.ssm_slice_widths, slices)
+    ])
+    step = jnp.exp(
+        jax.random.uniform(k_dt, (n, c.ssm_heads))
+        * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001)
+    )
+    return {
+        "ssm_in": (
+            jax.random.normal(k_in, (n, d, c.ssm_in_dim)) * col_scale
+        ).astype(dtype),
+        "ssm_conv_w": (
+            jax.random.normal(k_conv, (n, c.ssm_conv, c.ssm_conv_dim))
+            * c.ssm_conv**-0.5
+        ).astype(dtype),
+        "ssm_conv_b": (
+            jax.random.normal(k_bias, (n, c.ssm_conv_dim)) * 0.1
+        ).astype(dtype),
+        "ssm_a_log": jnp.log(
+            jax.random.uniform(k_a, (n, c.ssm_heads), minval=1.0, maxval=16.0)
+        ),
+        # softplus(x) = step  <=>  x = step + log(1 - exp(-step))
+        "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "ssm_d": jnp.ones((n, c.ssm_heads), jnp.float32),
+        "ssm_norm": jnp.ones((n, c.ssm_inner), dtype),
+        "ssm_out": (
+            jax.random.normal(k_out, (n, c.ssm_inner, d))
+            * (c.ssm_inner**-0.5 / (c.ssm_out_multiplier or 1.0))
+        ).astype(dtype),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -179,20 +248,35 @@ def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _times(x: jax.Array, multiplier: Optional[float]) -> jax.Array:
+    """``x`` times a muP multiplier, as a multiplication in the program where
+    the configuration has one; ``x`` itself where it has none."""
+    if multiplier is None:
+        return x
+    return x * jnp.asarray(multiplier, x.dtype)
+
+
 def embed_tokens(params: Params, c: ModelConfig, tokens: jax.Array) -> jax.Array:
     with jax.named_scope("embed"):
         x = take_rows(params["embed"], tokens)
         if c.scale_embeddings:
             x = x * jnp.asarray(c.d_model**0.5, x.dtype)
-        return x
+        return _times(x, c.embedding_multiplier)
 
 
-def attn_out_block(c: ModelConfig, lp, x: jax.Array, attn: jax.Array) -> jax.Array:
-    """Output projection of the heads' values (..., H*hd), and the residual."""
+def attn_out_block(
+    c: ModelConfig, lp, x: jax.Array, attn: jax.Array,
+    mixed: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Output projection of the heads' values (..., H*hd), and the residual;
+    ``mixed`` is the recurrent mixer's branch of the same block, added with
+    it."""
     with jax.named_scope("attn_out"):
-        attn = matmul(attn, lp["wo"])
+        attn = _times(matmul(attn, lp["wo"]), c.attention_out_multiplier)
         if c.use_post_norms:
             attn = rms_norm(attn, lp["post_attn_norm"], c.rms_eps, c.rmsnorm_style)
+        if mixed is not None:
+            return x + attn + mixed
         return x + attn
 
 
@@ -200,12 +284,14 @@ def ffn_block(c: ModelConfig, lp, x: jax.Array) -> jax.Array:
     """Norm, gated feed-forward, and the residual."""
     with jax.named_scope("ffn"):
         ffn_in = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style)
-        gate = matmul(ffn_in, lp["w_gate"])
+        gate_m, down_m = c.mlp_multipliers or (None, None)
+        gate = _times(matmul(ffn_in, lp["w_gate"]), gate_m)
         if c.activation == "geglu":
             gate = jax.nn.gelu(gate, approximate=True)
         else:
             gate = jax.nn.silu(gate)
-        ffn = matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"])
+        ffn = _times(
+            matmul(gate * matmul(ffn_in, lp["w_up"]), lp["w_down"]), down_m)
         if c.use_post_norms:
             ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
         return x + ffn
@@ -216,9 +302,224 @@ def final_norm(params: Params, c: ModelConfig, x: jax.Array) -> jax.Array:
         return rms_norm(x, params["final_norm"], c.rms_eps, c.rmsnorm_style)
 
 
+
 # ---------------------------------------------------------------------------
-# KV cache
+# The recurrent mixer (Mamba-2) of a block that has one beside attention
 # ---------------------------------------------------------------------------
+
+
+def _ssm_slice_vector(c: ModelConfig) -> Optional[jax.Array]:
+    """``ssm_slice_multipliers`` spread over [z | x | B | C | dt]."""
+    if c.ssm_slice_multipliers is None:
+        return None
+    return jnp.concatenate([
+        jnp.full((w,), m, jnp.float32)
+        for w, m in zip(c.ssm_slice_widths, c.ssm_slice_multipliers)
+    ])
+
+
+def _ssm_conv(c: ModelConfig, lp, xbc, window, valid):
+    """Causal depthwise convolution over each row's one run of valid
+    positions, which ``window`` (B, K-1, C) precedes: the last K-1 inputs
+    before this span (zeros at a sequence's start).  Returns (silu of the
+    convolution (B, S, C), the window after the run's last position).  A row
+    with no valid position keeps its window."""
+    b, s, _ = xbc.shape
+    k = c.ssm_conv
+    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    first = jnp.argmax(valid, axis=1).astype(jnp.int32)  # 0 where none
+    xbc = jnp.where(valid[:, :, None], xbc, jnp.zeros((), xbc.dtype))
+    cat = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))  # index t + k - 1 = t
+    # The window lands right before the run: in the left pad, or in the
+    # k - 1 columns put in front.
+    at = jnp.arange(s + k - 1)[None, :]
+    for j in range(k - 1):
+        cat = jnp.where(
+            (at == (first + j)[:, None])[:, :, None],
+            window[:, j : j + 1, :].astype(cat.dtype), cat,
+        )
+    w = lp["ssm_conv_w"].astype(jnp.float32)
+    out = lp["ssm_conv_b"].astype(jnp.float32)[None, None, :]
+    for j in range(k):
+        out = out + cat[:, j : j + s, :].astype(jnp.float32) * w[j][None, None, :]
+    tail = (first + n_valid)[:, None] + jnp.arange(k - 1)[None, :]
+    new_window = jnp.take_along_axis(cat, tail[:, :, None], axis=1)
+    return jax.nn.silu(out).astype(xbc.dtype), new_window.astype(window.dtype)
+
+
+def _ssm_scan_step(x, dt, a, bm, cm, h):
+    """One position of the recurrence: ``x`` (B, H, P), ``dt`` (B, H)
+    float32, ``a`` (H,), ``bm``/``cm`` (B, H, N) by head, ``h`` (B, H, P, N)
+    float32.  The state is read and written once, elementwise."""
+    decay = jnp.exp(dt * a[None, :])[:, :, None, None]
+    dbx = (dt[:, :, None] * x.astype(jnp.float32))[..., None] * bm.astype(
+        jnp.float32)[:, :, None, :]
+    h = decay * h + dbx
+    y = jnp.sum(h * cm.astype(jnp.float32)[:, :, None, :], axis=-1)
+    return y, h
+
+
+def _ssm_scan_chunked(c: ModelConfig, x, dt, a, bm, cm, h0):
+    """The recurrence over a span in chunks of ``ssm_chunk`` (Mamba-2's
+    state-space duality): within a chunk a masked product of the decays,
+    between chunks the state, carried in float32.  ``x`` (B, S, H, P),
+    ``dt`` (B, S, H) float32 and zero where nothing may change the state,
+    ``bm``/``cm`` (B, S, G, N), ``h0`` (B, H, P, N).  Returns (y (B, S, H,
+    P) float32, the state after the span)."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2], bm.shape[3]
+    r = h // g
+    q = min(c.ssm_chunk, s)
+    pad = -s % q
+    if pad:  # dt = 0: the padding neither moves the state nor is read
+        x, dt, bm, cm = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, bm, cm)
+        )
+    nc = (s + pad) // q
+    f32 = jnp.float32
+    x = x.reshape(b, nc, q, g, r, p)
+    dt = dt.reshape(b, nc, q, g, r)
+    bm = bm.reshape(b, nc, q, g, n)
+    cm = cm.reshape(b, nc, q, g, n)
+    da = dt * a.reshape(g, r)[None, None, None]
+    cum = jnp.cumsum(da, axis=2)  # (B, nc, Q, G, R), <= 0 and falling
+    # Within the chunk: y_i += sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) dt_j x_j
+    cb = jnp.einsum("bcign,bcjgn->bcgij", cm, bm, preferred_element_type=f32)
+    seg = cum[:, :, :, None] - cum[:, :, None, :]  # (B, nc, i, j, G, R)
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None, None]
+    weights = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
+    weights = weights * dt[:, :, None] * jnp.moveaxis(cb, 2, 4)[..., None]
+    y = jnp.einsum("bcijgr,bcjgrp->bcigrp", weights, x.astype(f32),
+                   preferred_element_type=f32)
+    # What each chunk adds to the state, decayed to the chunk's end.
+    to_end = jnp.exp(cum[:, :, -1:] - cum) * dt  # (B, nc, Q, G, R)
+    added = jnp.einsum("bcjgrp,bcjgn->bcgrpn", to_end[..., None] * x.astype(f32),
+                       bm.astype(f32), preferred_element_type=f32)
+    chunk_decay = jnp.exp(cum[:, :, -1])  # (B, nc, G, R)
+
+    def carry_state(state, per_chunk):
+        decay, add = per_chunk
+        return decay[..., None, None] * state + add, state
+
+    h_last, h_before = jax.lax.scan(
+        carry_state, h0.reshape(b, g, r, p, n),
+        (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(added, 1, 0)),
+    )
+    h_before = jnp.moveaxis(h_before, 0, 1)  # (B, nc, G, R, P, N)
+    # What the state before the chunk gives each position of it.
+    y = y + jnp.einsum("bcign,bcgrpn->bcigrp", cm.astype(f32), h_before,
+                       preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    y = y.reshape(b, nc * q, h, p)[:, :s]
+    return y, h_last.reshape(b, h, p, n)
+
+
+def ssm_mixer(
+    c: ModelConfig, lp, u: jax.Array, state: Optional["SSMState"],
+    valid: jax.Array,
+):
+    """The Mamba-2 branch of a block, on the block's normed input ``u`` (B,
+    S, D): returns (its contribution to the residual (B, S, D), the state
+    after the span).  ``state`` is one layer's ``SSMState`` (conv window (B,
+    K-1, C), h (B, H, P, N) float32), or None at a sequence's start.  ``valid`` (B, S)
+    is one run of real positions a row; the others leave the state and the
+    window as they found them, and what is returned at them is not read.  A
+    span of one position takes the recurrence's one-step form, a longer one
+    the chunked form."""
+    b, s, _ = u.shape
+    heads, p, n, g = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
+    inner, gn = c.ssm_inner, c.ssm_groups * c.ssm_state
+    if state is None:
+        window = jnp.zeros((b, c.ssm_conv - 1, c.ssm_conv_dim), u.dtype)
+        h = jnp.zeros((b, heads, p, n), jnp.float32)
+    else:
+        window, h = state
+    with jax.named_scope("ssm_in"):
+        proj = matmul(_times(u, c.ssm_in_multiplier), lp["ssm_in"])
+        slices = _ssm_slice_vector(c)
+        if slices is not None:
+            proj = proj * slices.astype(proj.dtype)
+        z = proj[..., :inner]
+        xbc = proj[..., inner : inner + c.ssm_conv_dim]
+        dt = proj[..., inner + c.ssm_conv_dim :]
+    with jax.named_scope("ssm_conv"):
+        xbc, window = _ssm_conv(c, lp, xbc, window, valid)
+    with jax.named_scope("ssm_scan"):
+        x = xbc[..., :inner].reshape(b, s, heads, p)
+        bm = xbc[..., inner : inner + gn].reshape(b, s, g, n)
+        cm = xbc[..., inner + gn :].reshape(b, s, g, n)
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32) + lp["ssm_dt_bias"].astype(jnp.float32))
+        dt = jnp.where(valid[:, :, None], dt, 0.0)
+        a = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
+        if s == 1:
+            by_head = lambda t: jnp.repeat(t[:, 0], heads // g, axis=1)
+            y, h = _ssm_scan_step(x[:, 0], dt[:, 0], a, by_head(bm), by_head(cm), h)
+            y = y[:, None]
+        else:
+            y, h = _ssm_scan_chunked(c, x, dt, a, bm, cm, h)
+        y = y + lp["ssm_d"].astype(jnp.float32)[None, None, :, None] * x.astype(
+            jnp.float32)
+    with jax.named_scope("ssm_out"):
+        y = y.reshape(b, s, inner)
+        gate = jax.nn.silu(z.astype(jnp.float32))
+        if not c.ssm_norm_before_gate:
+            y = y * gate
+        # RMSNorm over each group's columns.
+        yg = y.reshape(b, s, g, inner // g)
+        yg = yg * jax.lax.rsqrt(
+            jnp.mean(yg * yg, axis=-1, keepdims=True) + c.rms_eps)
+        y = yg.reshape(b, s, inner) * lp["ssm_norm"].astype(jnp.float32)
+        if c.ssm_norm_before_gate:
+            y = y * gate
+        out = _times(matmul(y.astype(u.dtype), lp["ssm_out"]), c.ssm_out_multiplier)
+    return out, SSMState(window, h)
+
+
+# ---------------------------------------------------------------------------
+# KV cache, and the recurrent state that rides beside it
+# ---------------------------------------------------------------------------
+
+
+class SSMState(NamedTuple):
+    """The recurrent state of a configuration with a mixer, by row, the
+    layer axis leading: the other kind of state beside keys and values by
+    position.  Its size does not grow with the prefix; a shared prefix is a
+    copy of it at the prefix's end (``fork_ssm``), not a run of pages."""
+
+    conv: jax.Array  # (L, B, K-1, conv_dim): the last K-1 inputs of the conv
+    h: jax.Array  # (L, B, heads, head size, state) float32
+
+
+def make_ssm_state(
+    config: ModelConfig, batch: int, dtype: jnp.dtype = jnp.float32
+) -> Optional[SSMState]:
+    """A zero state for ``batch`` rows (a sequence's start); None where the
+    configuration has no recurrent layer."""
+    c = config
+    if not c.has_ssm:
+        return None
+    return SSMState(
+        conv=jnp.zeros((c.n_layers, batch, c.ssm_conv - 1, c.ssm_conv_dim), dtype),
+        h=jnp.zeros(
+            (c.n_layers, batch, c.ssm_heads, c.ssm_head_dim, c.ssm_state),
+            jnp.float32,
+        ),
+    )
+
+
+def fork_ssm(state: SSMState, rows: jax.Array | int) -> SSMState:
+    """Copy a state to many rows: ``rows`` is the index of the source row
+    for each new row, or a count (every new row starts from row 0).  This
+    is what a shared prefix costs a recurrent layer."""
+    with jax.named_scope("state_fork"):
+        if isinstance(rows, int):
+            return jax.tree.map(
+                lambda a: jnp.broadcast_to(
+                    a[:, :1], a.shape[:1] + (rows,) + a.shape[2:]),
+                state,
+            )
+        return jax.tree.map(lambda a: jnp.take(a, rows, axis=1), state)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -228,9 +529,14 @@ class KVCache:
     v: jax.Array  # (L, B, T, KV, hd)
     key_positions: jax.Array  # (B, T) int32
     key_valid: jax.Array  # (B, T) bool
+    #: The rows' recurrent state after the last position written; None for
+    #: a configuration without recurrent layers.
+    ssm: Optional[SSMState] = None
 
     def tree_flatten(self):
-        return (self.k, self.v, self.key_positions, self.key_valid), None
+        return (
+            self.k, self.v, self.key_positions, self.key_valid, self.ssm
+        ), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -247,6 +553,7 @@ def make_cache(
         v=jnp.zeros(shape, dtype),
         key_positions=jnp.zeros((batch, max_len), jnp.int32),
         key_valid=jnp.zeros((batch, max_len), jnp.bool_),
+        ssm=make_ssm_state(c, batch, dtype),
     )
 
 
@@ -314,16 +621,21 @@ def forward(
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     batch, span = tokens.shape
 
-    def layer_step(x, scanned):
+    def layer_step(x, scanned, ssm_l=None):
         lp, k_cache_l, v_cache_l, is_local = scanned
 
         with jax.named_scope("attn_qkv"):
             attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            q = matmul(attn_in, lp["wq"]).reshape(batch, span, h, hd)
-            k = matmul(attn_in, lp["wk"]).reshape(batch, span, kv, hd)
-            v = matmul(attn_in, lp["wv"]).reshape(batch, span, kv, hd)
+            qkv_in = _times(attn_in, c.attention_in_multiplier)
+            q = matmul(qkv_in, lp["wq"]).reshape(batch, span, h, hd)
+            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+                batch, span, kv, hd)
+            v = matmul(qkv_in, lp["wv"]).reshape(batch, span, kv, hd)
             q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
             k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
+        mixed = None
+        if c.has_ssm:
+            mixed, ssm_l = ssm_mixer(c, lp, attn_in, ssm_l, valid)
 
         if k_cache_l is None:
             keys, values = k, v
@@ -389,11 +701,11 @@ def forward(
                 logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
                 weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
                 attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
-        x = attn_out_block(c, lp, x, attn.reshape(batch, span, h * hd))
+        x = attn_out_block(c, lp, x, attn.reshape(batch, span, h * hd), mixed)
         x = ffn_block(c, lp, x)
 
         return x, (keys if k_cache_l is not None else None,
-                   values if k_cache_l is not None else None)
+                   values if k_cache_l is not None else None, ssm_l)
 
     layer_params = params["layers"]
     if cache is None:
@@ -409,15 +721,16 @@ def forward(
         new_cache = None
     else:
         def scan_fn(carry, xs):
-            lp, kc, vc, flag = xs
-            new_x, (nk, nv) = layer_step(carry, (lp, kc, vc, flag))
-            return new_x, (nk, nv)
+            lp, kc, vc, flag, ssm_l = xs  # ssm_l: None without a mixer
+            return layer_step(carry, (lp, kc, vc, flag), ssm_l)
 
         with jax.named_scope("layers"):
-            x, (new_k, new_v) = jax.lax.scan(
-                scan_fn, x, (layer_params, cache.k, cache.v, local_flags)
+            x, (new_k, new_v, new_ssm) = jax.lax.scan(
+                scan_fn, x,
+                (layer_params, cache.k, cache.v, local_flags, cache.ssm),
             )
-        new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions, key_valid=k_valid)
+        new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions,
+                            key_valid=k_valid, ssm=new_ssm)
 
     x = final_norm(params, c, x)
     if return_hidden:
@@ -456,6 +769,7 @@ def forward_trunk_tail(
     frozen_v=(),
     frozen_positions=(),  # sequence of (Rows, F_i) int32, one per block
     use_decode_kernel: bool = True,
+    ssm: Optional[SSMState] = None,  # (L, Rows, ...) recurrent state by row
 ):
     """One-token decode step where every search slot shares ONE trunk cache.
 
@@ -493,10 +807,17 @@ def forward_trunk_tail(
     columns — stepper.rollout_scored_many) violates; the einsum path masks
     by ``trunk.key_valid`` and handles any validity pattern.
 
-    Returns (final-norm hidden (Rows, D), new tail_k, new tail_v) with the
-    tail structure preserved.
+    ``ssm``: a configuration with recurrent layers keeps each row's state
+    here (forked from the trunk's where the rows share one); this step
+    advances it by the one position.
+
+    Returns (final-norm hidden (Rows, D), new tail_k, new tail_v, new ssm)
+    with the tail structure preserved; ``ssm`` is None where there is none.
     """
     c = config
+    if c.has_ssm and ssm is None:
+        raise RecurrentStateUnsupported(
+            "forward_trunk_tail", "rows without their recurrent state")
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     reps = h // kv
     rows = tokens.shape[0]
@@ -547,15 +868,23 @@ def forward_trunk_tail(
     local_flags = jnp.asarray(c.local_flags)
 
     def layer_step(x, scanned):
-        lp, k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail, is_local = scanned
+        (lp, k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail, is_local,
+         ssm_l) = scanned
 
         with jax.named_scope("attn_qkv"):
             attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            q = matmul(attn_in, lp["wq"]).reshape(rows, 1, h, hd)
-            k = matmul(attn_in, lp["wk"]).reshape(rows, 1, kv, hd)
-            v = matmul(attn_in, lp["wv"]).reshape(rows, 1, kv, hd)
+            qkv_in = _times(attn_in, c.attention_in_multiplier)
+            q = matmul(qkv_in, lp["wq"]).reshape(rows, 1, h, hd)
+            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+                rows, 1, kv, hd)
+            v = matmul(qkv_in, lp["wv"]).reshape(rows, 1, kv, hd)
             q = apply_rope(q, positions[:, None], c.rope_theta, c.rope_scaling)
             k = apply_rope(k, positions[:, None], c.rope_theta, c.rope_scaling)
+        mixed = None
+        if c.has_ssm:
+            mixed, ssm_l = ssm_mixer(
+                c, lp, attn_in[:, None], ssm_l, jnp.ones((rows, 1), bool))
+            mixed = mixed[:, 0]
 
         with jax.named_scope("kv_write"):
             if tail_quantized:
@@ -705,20 +1034,21 @@ def forward_trunk_tail(
                         block, width, weights[..., offset : offset + width]
                     )
                     offset += width
-        x = attn_out_block(c, lp, x, attn.reshape(rows, h * hd))
-        return ffn_block(c, lp, x), (new_k_tail, new_v_tail)
+        x = attn_out_block(c, lp, x, attn.reshape(rows, h * hd), mixed)
+        return ffn_block(c, lp, x), (new_k_tail, new_v_tail, ssm_l)
 
     # One scanned pytree serves every variant: lax.scan slices each leaf
     # along the layer axis, including nested (int8, scale) pairs and the
     # per-block frozen tuples.
     scanned = (
         params["layers"], trunk.k, trunk.v, frozen_k, frozen_v,
-        tail_k, tail_v, local_flags,
+        tail_k, tail_v, local_flags, ssm,
     )
     with jax.named_scope("layers"):
-        x, (new_tail_k, new_tail_v) = jax.lax.scan(layer_step, x, scanned)
+        x, (new_tail_k, new_tail_v, new_ssm) = jax.lax.scan(
+            layer_step, x, scanned)
     x = final_norm(params, c, x)
-    return x, new_tail_k, new_tail_v
+    return x, new_tail_k, new_tail_v, new_ssm
 
 
 def forward_shared_trunk(
@@ -783,15 +1113,27 @@ def forward_shared_trunk(
         )
     local_flags = jnp.asarray(c.local_flags)
 
+    ssm_rows = None
+    if c.has_ssm:
+        if return_suffix_kv:
+            raise RecurrentStateUnsupported(
+                "forward_shared_trunk", "suffix keys without the suffix's state")
+        # Every (path, role) row starts from its role's state at the trunk's
+        # end; what the suffix makes of it is not kept.
+        ssm_rows = fork_ssm(cache.ssm, jnp.tile(jnp.arange(n_roles), n_paths))
+
     def layer_step(x, scanned):
-        lp, k_trunk, v_trunk, is_local = scanned  # k/v_trunk: (R, T, kv, hd)
+        # k/v_trunk: (R, T, kv, hd)
+        lp, k_trunk, v_trunk, is_local, ssm_l = scanned
 
         with jax.named_scope("attn_qkv"):
             attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
             flat = attn_in.reshape(n_paths * n_roles, span, -1)
-            q = matmul(flat, lp["wq"]).reshape(n_paths * n_roles, span, h, hd)
-            ks = matmul(flat, lp["wk"]).reshape(n_paths * n_roles, span, kv, hd)
-            vs = matmul(flat, lp["wv"]).reshape(n_paths * n_roles, span, kv, hd)
+            qkv_in = _times(flat, c.attention_in_multiplier)
+            q = matmul(qkv_in, lp["wq"]).reshape(n_paths * n_roles, span, h, hd)
+            ks = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+                n_paths * n_roles, span, kv, hd)
+            vs = matmul(qkv_in, lp["wv"]).reshape(n_paths * n_roles, span, kv, hd)
             rope_pos = jnp.tile(positions, (n_paths, 1))  # (P*R, L)
             q = apply_rope(q, rope_pos, c.rope_theta, c.rope_scaling)
             ks = apply_rope(ks, rope_pos, c.rope_theta, c.rope_scaling)
@@ -822,13 +1164,20 @@ def forward_shared_trunk(
             ) + jnp.einsum(
                 "prgmst,prtgd->prsgmd", weights[..., t_len:], vs
             )
+        mixed = None
+        if c.has_ssm:
+            mixed, _ = ssm_mixer(
+                c, lp, flat, ssm_l,
+                jnp.ones((n_paths * n_roles, span), bool))
+            mixed = mixed.reshape(n_paths, n_roles, span, -1)
         x = attn_out_block(
-            c, lp, x, attn.reshape(n_paths, n_roles, span, h * hd))
+            c, lp, x, attn.reshape(n_paths, n_roles, span, h * hd), mixed)
         return ffn_block(c, lp, x), ((ks, vs) if return_suffix_kv else None)
 
     with jax.named_scope("layers"):
         x, suffix_kv = jax.lax.scan(
-            layer_step, x, (params["layers"], cache.k, cache.v, local_flags)
+            layer_step, x,
+            (params["layers"], cache.k, cache.v, local_flags, ssm_rows),
         )
     x = final_norm(params, c, x)
     if return_all_positions:
@@ -851,7 +1200,8 @@ def project_logits(params: Params, config: ModelConfig, hidden: jax.Array) -> ja
     position) BEFORE projecting so a (B, S, 256k) tensor never materializes."""
     with jax.named_scope("vocab_projection"):
         head = params["embed"] if config.tie_lm_head else params["lm_head"]
-        return _softcap(head_matmul(hidden, head), config.final_softcap)
+        logits = _times(head_matmul(hidden, head), config.lm_head_multiplier)
+        return _softcap(logits, config.final_softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -936,7 +1286,7 @@ def _streamed_target_logprobs(
             )
             if row_scales is not None:
                 tile = tile * row_scales[:, 0][None, None, :]
-            tile = _softcap(tile, c.final_softcap)
+            tile = _softcap(_times(tile, c.lm_head_multiplier), c.final_softcap)
         with jax.named_scope("logsumexp"):
             row_ids = start + jnp.arange(rows.shape[0])
             fresh = (row_ids >= i * vocab_chunk) & (row_ids < vocab)
@@ -956,7 +1306,8 @@ def _streamed_target_logprobs(
     with jax.named_scope("logsumexp"):
         lse = run_max + jnp.log(run_sum)
         target_logits = _softcap(
-            gather_target_logits(x, head, targets), c.final_softcap
+            _times(gather_target_logits(x, head, targets), c.lm_head_multiplier),
+            c.final_softcap,
         )
         return target_logits - lse
 

@@ -79,6 +79,27 @@ class BackendInstruments:
             labels=("backend",),
             buckets=DEFAULT_TIME_BUCKETS,
         )
+        self._state_forks = reg.counter(
+            "backend_state_fork_rows_total",
+            "Rows that started from a copy of another row's recurrent state "
+            "(one trunk forked to its decode rows, a context's snapshot to "
+            "its score rows), by kind of call.",
+            labels=("backend", "kind"),
+        )
+        self._recurrent_bytes = reg.gauge(
+            "backend_recurrent_state_bytes",
+            "Bytes of recurrent state the latest program launch held by row, "
+            "beside its key-value pages (0 for a configuration without "
+            "recurrent layers).",
+            labels=("backend",),
+        )
+        self._prefix_declined = reg.counter(
+            "backend_prefix_runs_declined_total",
+            "Prefix-cache page runs neither donated nor adopted because the "
+            "configuration has recurrent layers and a run of pages carries "
+            "no state at its end, by operation (lookup or insert).",
+            labels=("backend", "op"),
+        )
         self._seen_lock = threading.Lock()
         self._seen_shapes: Set[Tuple[str, Tuple[int, ...]]] = set()
 
@@ -114,6 +135,17 @@ class BackendInstruments:
         else:
             self._cache_hits.labels(self.backend, kind).inc()
         return first
+
+    # -- recurrent state -----------------------------------------------------
+
+    def record_state_fork(self, kind: str, rows: int, nbytes: int) -> None:
+        """One launch of call kind ``kind`` that forks ``rows`` rows' state
+        and holds ``nbytes`` of recurrent state while it runs."""
+        self._state_forks.labels(self.backend, kind).inc(rows)
+        self._recurrent_bytes.labels(self.backend).set(nbytes)
+
+    def record_prefix_run_declined(self, op: str, runs: int = 1) -> None:
+        self._prefix_declined.labels(self.backend, op).inc(runs)
 
     # -- transfers -----------------------------------------------------------
 

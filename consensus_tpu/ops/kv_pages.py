@@ -39,7 +39,7 @@ import dataclasses
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -278,7 +278,17 @@ class PrefixCache:
         pool: PagePool,
         max_pages: int,
         identity: Tuple = (),
+        needs_state: bool = False,
+        on_declined: Optional[Callable[[str], None]] = None,
     ):
+        #: The model has recurrent layers: a prefix of it is the pages AND
+        #: the recurrent state at their end, and this cache holds pages
+        #: alone.  It then neither donates nor accepts a run: ``lookup``
+        #: misses, ``insert`` refuses, and each is counted
+        #: (``declined_runs``, and ``on_declined("lookup" | "insert")``).
+        self.needs_state = bool(needs_state)
+        self._on_declined = on_declined
+        self.declined_runs = 0
         self.pool = pool
         self.max_pages = max(0, int(max_pages))
         #: Model-tier/quant identity the content keys are seeded with —
@@ -296,6 +306,15 @@ class PrefixCache:
         self.inserted_pages = 0
         self.tokens_saved = 0
 
+    def _declines(self, op: str) -> bool:
+        if not self.needs_state:
+            return False
+        with self._lock:
+            self.declined_runs += 1
+        if self._on_declined is not None:
+            self._on_declined(op)
+        return True
+
     def _chain_keys(self, tokens: Sequence) -> List[bytes]:
         """Digest per page-aligned prefix length: index i covers (i+1) pages."""
         ps = self.pool.page_size
@@ -310,6 +329,8 @@ class PrefixCache:
         """Longest cached page-aligned prefix of ``tokens`` → (pages,
         n_tokens), with one reference taken per page for the caller (free
         them through ``BlockTable.release`` / ``pool.free``)."""
+        if self._declines("lookup"):
+            return [], 0
         keys = self._chain_keys(tokens)
         with self._lock:
             for i in range(len(keys) - 1, -1, -1):
@@ -334,6 +355,8 @@ class PrefixCache:
         if n_pages == 0 or len(tokens) != n_pages * ps:
             return False
         if self.max_pages and n_pages > self.max_pages:
+            return False
+        if self._declines("insert"):
             return False
         keys = self._chain_keys(tokens)
         key = keys[n_pages - 1]
@@ -400,4 +423,5 @@ class PrefixCache:
                 "evictions": self.evictions,
                 "inserted_pages": self.inserted_pages,
                 "tokens_saved": self.tokens_saved,
+                "declined_runs": self.declined_runs,
             }

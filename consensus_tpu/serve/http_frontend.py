@@ -59,6 +59,7 @@ from consensus_tpu.serve.scheduler import (
     RequestTimeout,
     SchedulerRejected,
 )
+from consensus_tpu.models.config import RecurrentStateUnsupported
 from consensus_tpu.serve.service import RequestValidationError, parse_request
 
 logger = logging.getLogger(__name__)
@@ -268,6 +269,18 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
                 return
             except SchedulerRejected as exc:
                 status = self._send_rejection(exc, request_id=request_id)
+                return
+            except RecurrentStateUnsupported as exc:
+                # The served configuration has recurrent layers and this
+                # method needs a program that cannot carry their state:
+                # the client's to change, so a client error that names both.
+                status = 400
+                self._send_json(400, {"error": {
+                    "type": "method_unsupported_for_model",
+                    "method": request.method,
+                    "message": f"method {request.method!r}: {exc}",
+                    "request_id": request_id,
+                }})
                 return
             except Exception as exc:
                 status = 500

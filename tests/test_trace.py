@@ -689,6 +689,31 @@ class TestNamesAreListed:
         assert set(MODEL_PHASES) < set(MODEL_SCOPES)
         assert not set(MODEL_SCOPES) & set(HOST_SPANS)
 
+    def test_the_recurrent_mixers_names_are_listed(self):
+        """The scopes a block with a Mamba-2 mixer adds and the copy of its
+        state, each written by a ``named_scope`` call site; and the three
+        series of ``obs/backends.py`` that count what they cost."""
+        from consensus_tpu.models import MODEL_SCOPES
+        from consensus_tpu.obs.backends import BackendInstruments
+        from consensus_tpu.obs.metrics import Registry
+
+        mixer = {"ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "state_fork"}
+        assert mixer <= set(MODEL_SCOPES)
+        assert mixer <= set(_names_used(_SCOPE_CALL))
+        registry = Registry()
+        instruments = BackendInstruments("tpu", registry=registry)
+        instruments.record_state_fork("generate", 32, 32 * 100)
+        instruments.record_state_fork("score_matrix", 160, 168 * 100)
+        instruments.record_prefix_run_declined("lookup")
+        families = registry.snapshot()["families"]
+        forks = {s["labels"]["kind"]: s["value"]
+                 for s in families["backend_state_fork_rows_total"]["series"]}
+        assert forks == {"generate": 32, "score_matrix": 160}
+        assert families["backend_recurrent_state_bytes"]["series"][0][
+            "value"] == 168 * 100
+        assert families["backend_prefix_runs_declined_total"]["series"][0][
+            "labels"]["op"] == "lookup"
+
     def test_the_benchmark_reads_no_name_the_program_does_not_write(self):
         """Every ``<layer>.<what>`` a metric file or a reader of the
         benchmark mentions is a name of ``HOST_SPANS``, and every scope a
