@@ -1647,14 +1647,20 @@ class TPUBackend:
     def _score_chunk_transient_bytes(
         self, n_rows: int, width: int, keys: int
     ) -> int:
-        """What one layer of a score chunk of ``n_rows`` x ``width`` over
-        ``keys`` gathered key positions holds at once, as the program is
-        written: the attention logits in float32 and their weights, the
+        """What a score chunk of ``n_rows`` x ``width`` over ``keys``
+        gathered key positions holds at once, as the program is written.  One
+        layer: the attention logits in float32 and their weights, the
         feed-forward's gate, up and product, and the mixer's input product,
         convolution, decay weights of a chunk, and the states at the chunks'
-        boundaries going in and coming out.  (For 64 x 256 x 1600 at
-        Falcon-H1's widths the TPU compiler's own count is 6.5 GB; this
-        gives 7.8.)"""
+        boundaries going in and coming out.  And the head, which streams the
+        vocabulary over all the chunk's positions: one float32 tile of
+        ``stepper.score_vocab_tile`` columns, that tile's rows of the table
+        and the table's rows at the targets.  (For 64 x 256 x 1600 at
+        Falcon-H1's widths the TPU compiler's own count of the layer is
+        6.5 GB where this gives 7.8; at 32 x 256 the head adds 0.26 GB
+        here.)"""
+        from consensus_tpu.models.stepper import score_vocab_tile
+
         c = self.config
         itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
         cells = n_rows * width
@@ -1667,7 +1673,9 @@ class TPUBackend:
             + cells * min(width, c.ssm_chunk) * c.ssm_heads * 8
             + 2 * n_rows * chunks * layer_state
         )
-        return attention + ffn + mixer
+        tile = min(score_vocab_tile(cells), c.vocab_size)
+        head = cells * tile * 4 + (cells + tile) * c.d_model * itemsize
+        return attention + ffn + mixer + head
 
     def _prefill_shared_pages(self, state, prefix_ids, shared, sink, mesh):
         """Ingest every unique agent context's full pages (one row per

@@ -54,6 +54,7 @@ from consensus_tpu.models.transformer import (
     KVCache,
     RecurrentStateUnsupported,
     SSMState,
+    _streamed_target_logprobs,
     _times,
     attn_out_block,
     embed_tokens,
@@ -1280,6 +1281,27 @@ def paged_verify_steps(
     )
 
 
+#: The most one float32 tile of a score chunk's head may take, and the fewest
+#: and the most vocabulary columns a tile has.  A tiny test vocabulary is one
+#: tile; past 4,096 columns a tile was no faster on a v5e (32 x 256 positions
+#: x 5,120: 131.6 ms a chunk at 4,096, 143.9 at 8,192; 64 x 256 x 2,048: 24.3
+#: and 27.7; chip run, PR 28) and holds more.
+_SCORE_TILE_BYTES = 256 * 1024**2
+_SCORE_TILE_COLUMNS = (1024, 4096)
+
+
+def score_vocab_tile(positions: int) -> int:
+    """Vocabulary columns a tile of :func:`paged_score_chunk`'s head has for
+    a chunk of ``positions`` = rows x columns: the largest power of two that
+    keeps the (positions, tile) float32 logits within ``_SCORE_TILE_BYTES``,
+    held to ``_SCORE_TILE_COLUMNS`` (4,096 up to 64 x 256 positions, 1,024
+    from 64 x 1,024).  From the shapes alone: the backend's budget counts the
+    same tile."""
+    fewest, most = _SCORE_TILE_COLUMNS
+    columns = min(max(_SCORE_TILE_BYTES // (4 * positions), fewest), most)
+    return 1 << (columns.bit_length() - 1)
+
+
 @functools.partial(
     jax.jit, static_argnames=("config", "mesh"), donate_argnums=(6,)
 )
@@ -1311,12 +1333,17 @@ def paged_score_chunk(
     prefill bytes without copying them — the PagedAttention sharing trick
     applied to scoring.
 
-    The logprob of stream token p+1 is gathered at query position p via a
-    ``lax.scan`` over the S axis — per-position (B, V) logits instead of a
-    (B, S, V) f32 transient, which matters at a 256k vocab.  Returns the
-    per-row reductions ``(sum_lp, last_lp, sum_exp_lp, count)`` — enough
-    for every consumer statistic (mean / sum / last / moments) — and the
-    updated page state.  No per-token vector survives to be fetched.
+    The logprob of stream token p+1 is taken at query position p by
+    :func:`transformer._streamed_target_logprobs`: a ``lax.scan`` over
+    vocabulary tiles, every row and column of the chunk against one tile at
+    a time (:func:`score_vocab_tile` columns of the head), a running
+    maximum and a rescaled sum for each position — the head's table is read
+    once a chunk and no (B, S, V) array exists.  Returns the per-row
+    reductions ``(sum_lp, last_lp, sum_exp_lp, count)`` over the masked
+    columns (``last_lp`` is the value at the row's last one, 0.0 where it
+    has none) — enough for every consumer statistic (mean / sum / last /
+    moments) — and the updated page state.  No per-token vector survives to
+    be fetched.
 
     With recurrent layers the shared pages are half of a context: the other
     half is the state at the page boundary where the row's query block
@@ -1347,36 +1374,17 @@ def paged_score_chunk(
     state = _constrain_state(state._replace(ssm=snapshots), mesh)
     mask = score_mask & chunk_valid
 
-    def score_col(carry, xs):
-        h_col, t_col, m_col = xs  # (B, D), (B,), (B,)
-        logits = project_logits(params, config, h_col)  # (B, V) f32
-        logits = _constrain(logits, mesh, "data", "model")
-        with jax.named_scope("logsumexp"):
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            t_lp = jnp.take_along_axis(lp, t_col[:, None], axis=1)[:, 0]
-            sum_lp, last_lp, sum_exp, counts = carry
-            return (
-                sum_lp + jnp.where(m_col, t_lp, 0.0),
-                jnp.where(m_col, t_lp, last_lp),
-                sum_exp + jnp.where(m_col, jnp.exp(t_lp), 0.0),
-                counts + m_col.astype(jnp.int32),
-            ), None
-
-    init = (
-        jnp.zeros((b,), jnp.float32),
-        jnp.zeros((b,), jnp.float32),
-        jnp.zeros((b,), jnp.float32),
-        jnp.zeros((b,), jnp.int32),
-    )
-    (sum_lp, last_lp, sum_exp, counts), _ = jax.lax.scan(
-        score_col,
-        init,
-        (
-            jnp.moveaxis(hidden, 0, 1),  # (S, B, D)
-            jnp.moveaxis(targets, 0, 1),
-            jnp.moveaxis(mask, 0, 1),
-        ),
-    )
+    lp = _streamed_target_logprobs(
+        params, config, hidden, targets, score_vocab_tile(b * s),
+        constrain_tile=lambda tile: _constrain(tile, mesh, "data", None, "model"),
+    )  # (B, S) f32
+    with jax.named_scope("logsumexp"):
+        sum_lp = jnp.sum(jnp.where(mask, lp, 0.0), axis=1)
+        sum_exp = jnp.sum(jnp.where(mask, jnp.exp(lp), 0.0), axis=1)
+        counts = jnp.sum(mask.astype(jnp.int32), axis=1)
+        cols = jnp.arange(s, dtype=jnp.int32)[None, :]
+        last_col = jnp.max(jnp.where(mask, cols, -1), axis=1, keepdims=True)
+        last_lp = jnp.sum(jnp.where(cols == last_col, lp, 0.0), axis=1)
     return (sum_lp, last_lp, sum_exp, counts), state
 
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1263,10 +1263,14 @@ def _streamed_target_logprobs(
     x: jax.Array,  # (B, S, D) final-norm hidden states
     targets: jax.Array,  # (B, S) int32 — token whose logprob each slot yields
     vocab_chunk: int,
+    constrain_tile: Optional[Callable[[jax.Array], jax.Array]] = None,
 ) -> jax.Array:
     """log p(targets[b, s] | hidden x[b, s]) with a streaming logsumexp over
     vocab tiles — the memory-bounded core shared by the full-sequence and
-    shared-context scorers (never materializes (B, S, V))."""
+    shared-context scorers and the fused score chunk (never materializes
+    (B, S, V); the head's table is read once whatever B and S are).
+    ``constrain_tile`` is the caller's sharding constraint on a
+    (B, S, vocab_chunk) tile, for a program lowered under a mesh."""
     c = config
     head = params["embed"] if c.tie_lm_head else params["lm_head"]
     vocab = head.shape[0]
@@ -1287,6 +1291,8 @@ def _streamed_target_logprobs(
             if row_scales is not None:
                 tile = tile * row_scales[:, 0][None, None, :]
             tile = _softcap(_times(tile, c.lm_head_multiplier), c.final_softcap)
+            if constrain_tile is not None:
+                tile = constrain_tile(tile)
         with jax.named_scope("logsumexp"):
             row_ids = start + jnp.arange(rows.shape[0])
             fresh = (row_ids >= i * vocab_chunk) & (row_ids < vocab)

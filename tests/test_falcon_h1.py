@@ -471,25 +471,33 @@ def test_generation_rows_count_the_state_twice_beside_the_tail(backend, monkeypa
 
 def test_a_score_chunks_temporaries_at_the_published_widths(backend, monkeypatch):
     """What keeps a 64-row score chunk of the published model off a 16 GB
-    chip: 7.8 GB of temporaries and 2.3 GB of pool beside 8.79 GB of weights;
-    32 rows take half of both and fit."""
+    chip: 7.8 GB of a layer's temporaries, 0.5 GB of the head's and 2.3 GB of
+    pool beside 8.79 GB of weights; 32 rows take half of a layer's and of the
+    pool, 0.26 GB for the head (the same 4,096 columns a tile), and fit."""
     from consensus_tpu.backends import tpu
 
     full = dataclasses.replace(
         CONFIG, d_model=5120, n_layers=4, n_heads=20, n_kv_heads=4, head_dim=128,
         ffn_hidden=21504, ssm_heads=32, ssm_head_dim=128, ssm_state=256,
-        ssm_inner=4096, ssm_chunk=128)
+        ssm_inner=4096, ssm_chunk=128, vocab_size=261120)
     monkeypatch.setattr(backend, "config", full)
     monkeypatch.setattr(
         backend, "params", {"embed": jnp.zeros((1, 1), jnp.bfloat16)})
+
+    def head(rows, tile):  # one float32 tile, its rows, the targets' rows
+        return rows * 256 * tile * 4 + (rows * 256 + tile) * 5120 * 2
+
     wide = backend._score_chunk_transient_bytes(64, 256, 1600)
-    assert round(wide / 1e9, 1) == 7.8
-    assert backend._score_chunk_transient_bytes(32, 256, 1600) * 2 == wide
+    half = backend._score_chunk_transient_bytes(32, 256, 1600)
+    assert round((wide - head(64, 4096)) / 1e9, 1) == 7.8
+    assert (half - head(32, 4096)) * 2 == wide - head(64, 4096)
+    assert round(head(64, 4096) / 1e9, 2) == 0.48
+    assert round(head(32, 4096) / 1e9, 2) == 0.26
     left = tpu._HBM_BYTES - 8_788_709_632
     row = backend._recurrent_row_bytes()
     assert row == 4 * (4 * 32 * 128 * 256 + 2 * 3 * 5120)
     assert wide + (8 + 2 * 64) * row > left
-    assert wide // 2 + (8 + 2 * 32) * row < left
+    assert half + (8 + 2 * 32) * row < left
 
 
 def test_the_engine_reserves_a_rows_state_in_pages(backend):
@@ -573,14 +581,18 @@ def test_a_program_that_cannot_carry_the_state_says_so_when_traced(program):
 
 # -- dense configurations: nothing moved ---------------------------------------------------------
 
-#: sha256 of what the parent commit (PR 26) gives: ``lowered.as_text()`` of
-#: the two programs at the sizes of ``tests/test_trace.py`` ``_lower_program``,
-#: and of ``init_params``' leaves (path and float32 bytes) under PRNGKey(7).
+#: sha256 of ``lowered.as_text()`` of the two programs at the sizes of
+#: ``tests/test_trace.py`` ``_lower_program``, and of ``init_params``' leaves
+#: (path and float32 bytes) under PRNGKey(7).  The two
+#: ``generate_tokens_shared_trunk`` pins and the four weight pins are of PR 26,
+#: the commit before the hybrid block came; the two ``paged_score_chunk`` pins
+#: are of PR 28, which gave every configuration's score chunk the streamed
+#: head (and nothing of the hybrid block to a dense one).
 PARENT = {
     "tiny-gemma2/generate_tokens_shared_trunk":
         "8b7d2a366dcd6b1b6e76ba76e13c0a9dc3f3e72ec8dcb2544f0d9dac0fcf8d9a",
     "tiny-gemma2/paged_score_chunk":
-        "de6b3ce7f952c5fb09aa50f465a0c8943eff591495401dcc310abcc1d50d6c56",
+        "27747fbb7ec3bfc7e391849fe25616d1fde98444f096d89f2c40fd591c120e18",
     "tiny-gemma2/init/float32":
         "31d40293605e5407fe31a6a79ee7e4b983c1b492ddae03cf78db6cf00b0b96d6",
     "tiny-gemma2/init/bfloat16":
@@ -588,7 +600,7 @@ PARENT = {
     "tiny-llama3/generate_tokens_shared_trunk":
         "0c837804c098a8311221312a20e17a262bbd4d6f03d0579c612237eaa0cb5b02",
     "tiny-llama3/paged_score_chunk":
-        "56a0dc2578bb8c0c48104d813604da9958e879db21d5ad1c37a6166c1a4d4240",
+        "280e18621c60c88e68e5af9bf66ca9d9bd6f76b833933120bb8f4406324c481f",
     "tiny-llama3/init/float32":
         "a22fc02ac159dd2f1fa24f75a89472b9ceee55ac6f47545fe05a0d62e11ca07b",
     "tiny-llama3/init/bfloat16":

@@ -18,6 +18,8 @@ The contract pinned here:
   from conftest.py).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -413,6 +415,218 @@ class TestFusedParity:
             len(backend.tokenizer.encode(c)) for c in request.candidates
         )
         assert scored == len(request.agents) * cont_tokens
+
+
+# ---------------------------------------------------------------------------
+# The score chunk's head: every position against streamed vocabulary tiles
+# (PR 28)
+# ---------------------------------------------------------------------------
+
+_PAGE = 16
+_ROWS, _WIDTH = 8, 32
+#: Stream lengths by row (a row's query block is its stream but the last
+#: token: 7 to 31 real columns of 32, the rest padding) and where each row's
+#: scored columns start.  Row 5 scores nothing.
+_STREAMS = (32, 20, 9, 27, 16, 12, 8, 31)
+_SCORE_FROM = (4, 10, 0, 25, 3, None, 6, 1)
+
+
+def _chunk_inputs(vocab):
+    """A chunk of rows that hold their whole stream in pages of their own."""
+    rng = np.random.default_rng(28)
+    blocks = _WIDTH // _PAGE
+    sink = _ROWS * blocks
+    tokens = np.zeros((_ROWS, _WIDTH), np.int32)
+    targets = np.zeros((_ROWS, _WIDTH), np.int32)
+    score_mask = np.zeros((_ROWS, _WIDTH), bool)
+    chunk_valid = np.zeros((_ROWS, _WIDTH), bool)
+    streams = np.zeros((_ROWS, _WIDTH), np.int32)
+    stream_valid = np.zeros((_ROWS, _WIDTH), bool)
+    write_pages = np.full((_ROWS, _WIDTH), sink, np.int32)
+    cols = np.arange(_WIDTH)
+    for r, (n, start) in enumerate(zip(_STREAMS, _SCORE_FROM)):
+        ids = rng.integers(12, vocab, size=n)
+        streams[r, :n], stream_valid[r, :n] = ids, True
+        tokens[r, : n - 1], targets[r, : n - 1] = ids[:-1], ids[1:]
+        chunk_valid[r, : n - 1] = True
+        write_pages[r, : n - 1] = r * blocks + cols[: n - 1] // _PAGE
+        if start is not None:
+            # Past the real columns too: the program has to drop those.
+            score_mask[r, start:] = True
+    tables = np.arange(_ROWS * blocks, dtype=np.int32).reshape(_ROWS, blocks)
+    lengths = np.asarray(_STREAMS, np.int32) - 1
+    offsets = np.tile(cols % _PAGE, (_ROWS, 1)).astype(np.int32)
+    return dict(
+        tokens=tokens, targets=targets, score_mask=score_mask,
+        chunk_valid=chunk_valid, tables=tables, lengths=lengths,
+        write_pages=write_pages, write_offsets=offsets,
+        streams=streams, stream_valid=stream_valid, num_pages=sink,
+    )
+
+
+def _score_chunk_program(monkeypatch, tile):
+    """``paged_score_chunk`` under a ``jit`` of its own, with the tile's
+    width fixed where the program would take it from the shapes.  A new
+    function object a call: ``jit`` keeps its traces by function, and a
+    trace made under one tile would serve the next."""
+    import jax
+
+    from consensus_tpu.models import stepper
+
+    if tile is not None:
+        monkeypatch.setattr(stepper, "score_vocab_tile", lambda positions: tile)
+
+    def program(*args, **kwargs):
+        return stepper.paged_score_chunk.__wrapped__(*args, **kwargs)
+
+    return jax.jit(program, static_argnums=(1,))
+
+
+def _run_score_chunk(program, params, config, chunk):
+    import jax.numpy as jnp
+
+    from consensus_tpu.models.stepper import make_page_state
+
+    state = make_page_state(config, chunk["num_pages"], _PAGE, ssm_rows=8)
+    stats, _ = program(
+        params, config, jnp.asarray(chunk["tokens"]), jnp.asarray(chunk["targets"]),
+        jnp.asarray(chunk["score_mask"]), jnp.asarray(chunk["chunk_valid"]),
+        state, jnp.asarray(chunk["tables"]), jnp.asarray(chunk["lengths"]),
+        jnp.asarray(chunk["write_pages"]), jnp.asarray(chunk["write_offsets"]),
+        ssm_rows=jnp.zeros((_ROWS,), jnp.int32) if config.has_ssm else None,
+    )
+    return [np.asarray(s) for s in stats]
+
+
+def _expected_reductions(params, config, chunk):
+    """The four reductions from ``token_logprobs``: full float32 logits."""
+    import jax.numpy as jnp
+
+    from consensus_tpu.models.transformer import token_logprobs
+
+    full = np.asarray(token_logprobs(
+        params, config, jnp.asarray(chunk["streams"]),
+        jnp.asarray(chunk["stream_valid"]),
+    ))
+    lp = np.zeros((_ROWS, _WIDTH), np.float64)
+    lp[:, :-1] = full[:, 1:]  # column p scores stream token p + 1
+    mask = chunk["score_mask"] & chunk["chunk_valid"]
+    last = np.zeros((_ROWS,))
+    for r in range(_ROWS):
+        scored = np.flatnonzero(mask[r])
+        if scored.size:
+            last[r] = lp[r, scored[-1]]
+    return (
+        np.where(mask, lp, 0.0).sum(axis=1), last,
+        np.where(mask, np.exp(lp), 0.0).sum(axis=1), mask.sum(axis=1),
+    )
+
+
+@pytest.fixture(scope="module")
+def head_models():
+    """(config, float32 weights) by name: a final softcap and a tied head,
+    a plain untied head, and the hybrid block's ``lm_head_multiplier``."""
+    import jax
+    import jax.numpy as jnp
+
+    from consensus_tpu.models.config import get_model_config
+    from consensus_tpu.models.transformer import init_params
+
+    models = {}
+    for name in ("tiny-gemma2", "tiny-llama3", "tiny-falcon-h1"):
+        config = get_model_config(name)
+        models[name] = (
+            config, init_params(config, jax.random.PRNGKey(28), jnp.float32)
+        )
+    return models
+
+
+class TestStreamedHead:
+    #: A tile that divides the vocabulary (268 = 4 x 67, 512 = 4 x 128), one
+    #: that leaves a ragged last tile, one wider than the vocabulary, and the
+    #: program's own rule.
+    @pytest.mark.parametrize("tile", ["divides", 100, 1000, None])
+    @pytest.mark.parametrize(
+        "model", ["tiny-gemma2", "tiny-llama3", "tiny-falcon-h1"]
+    )
+    def test_reductions_match_full_logits(self, head_models, monkeypatch, model, tile):
+        config, params = head_models[model]
+        if tile == "divides":
+            tile = config.vocab_size // 4
+        chunk = _chunk_inputs(config.vocab_size)
+        program = _score_chunk_program(monkeypatch, tile)
+        sum_lp, last_lp, sum_exp, counts = _run_score_chunk(
+            program, params, config, chunk
+        )
+        want_sum, want_last, want_exp, want_counts = _expected_reductions(
+            params, config, chunk
+        )
+        assert counts.tolist() == want_counts.tolist()
+        # float32 sums of at most 31 log-probabilities of order 5.
+        np.testing.assert_allclose(sum_lp, want_sum, atol=2e-4, rtol=0)
+        np.testing.assert_allclose(last_lp, want_last, atol=2e-5, rtol=0)
+        np.testing.assert_allclose(sum_exp, want_exp, atol=1e-6, rtol=1e-4)
+        # The row that scores nothing: what the old scan's carry gave.
+        assert counts[5] == 0 and last_lp[5] == 0.0 and sum_lp[5] == 0.0
+        # Columns past a row's stream are masked however the caller's
+        # ``score_mask`` reads there: row 3 scores its one last real column.
+        assert counts[3] == 1 and sum_lp[3] == last_lp[3]
+
+    @pytest.mark.parametrize("tile", [100, None])
+    def test_an_int8_head_goes_through_the_tile(self, head_models, monkeypatch, tile):
+        from consensus_tpu.models.quant import QTensor, quantize_params
+
+        config, params = head_models["tiny-llama3"]
+        quantized = quantize_params(params)
+        assert isinstance(quantized["lm_head"], QTensor)
+        chunk = _chunk_inputs(config.vocab_size)
+        got = _run_score_chunk(
+            _score_chunk_program(monkeypatch, tile), quantized, config, chunk
+        )
+        want = _expected_reductions(quantized, config, chunk)
+        assert got[3].tolist() == want[3].tolist()
+        np.testing.assert_allclose(got[0], want[0], atol=2e-4, rtol=0)
+        np.testing.assert_allclose(got[1], want[1], atol=2e-5, rtol=0)
+        # And it is the quantised head that was scored, not the plain one.
+        plain = _expected_reductions(params, config, chunk)
+        assert np.abs(want[0] - plain[0]).max() > 1e-3
+
+    def test_lowered_text_streams_tiles_over_all_positions(
+        self, head_models, monkeypatch
+    ):
+        """No (rows, columns, vocabulary) array and no product of a single
+        column with the head: one product a tile, every position deep."""
+        import jax
+        import jax.numpy as jnp
+
+        from consensus_tpu.models.stepper import make_page_state
+
+        config, params = head_models["tiny-gemma2"]
+        vocab, tile = config.vocab_size, 100
+        shapes = jax.eval_shape(lambda: params)
+        state = jax.eval_shape(lambda: make_page_state(config, 16, _PAGE))
+        ints = jnp.zeros((_ROWS, _WIDTH), jnp.int32)
+        bools = jnp.ones((_ROWS, _WIDTH), bool)
+        text = _score_chunk_program(monkeypatch, tile).lower(
+            shapes, config, ints, ints, bools, bools, state,
+            jnp.zeros((_ROWS, 2), jnp.int32), jnp.full((_ROWS,), _WIDTH, jnp.int32),
+            ints, ints,
+        ).as_text()
+        assert f"tensor<{_ROWS}x{_WIDTH}x{tile}xf32>" in text
+        assert f"x{vocab}xf32>" not in text  # (B, S, V) and (B, V) alike
+        products = re.findall(r"stablehlo\.dot_general.*-> tensor<([0-9x]+)xf32>", text)
+        assert f"{_ROWS}x{_WIDTH}x{tile}" in products
+        assert f"{_ROWS}x{tile}" not in products and f"{_ROWS}x{vocab}" not in products
+
+    @pytest.mark.parametrize(
+        "rows, width, columns",
+        [(8, 16, 4096), (32, 256, 4096), (64, 256, 4096), (64, 512, 2048),
+         (64, 1024, 1024), (64, 2048, 1024)],
+    )
+    def test_tile_width_comes_from_the_shapes(self, rows, width, columns):
+        from consensus_tpu.models.stepper import score_vocab_tile
+
+        assert score_vocab_tile(rows * width) == columns
 
 
 # ---------------------------------------------------------------------------
