@@ -49,16 +49,12 @@ from consensus_tpu.models.config import (
     ModelConfig,
 )
 from consensus_tpu.models.generate import left_pad_positions
-from consensus_tpu.models.quant import matmul
 from consensus_tpu.models.transformer import (
     KVCache,
     RecurrentStateUnsupported,
     SSMState,
     _streamed_target_logprobs,
-    _times,
-    attn_out_block,
     embed_tokens,
-    ffn_block,
     final_norm,
     forward,
     forward_shared_trunk,
@@ -67,10 +63,8 @@ from consensus_tpu.models.transformer import (
     make_cache,
     make_ssm_state,
     project_logits,
-    rms_norm,
-    ssm_mixer,
-    apply_rope,
-    _softcap,
+    scan_layers,
+    windowed,
 )
 from consensus_tpu.models.sampling import ban_undecodable, sample_tokens
 from consensus_tpu.ops.decode_attention import paged_attention
@@ -773,61 +767,34 @@ def _paged_forward(
     through its slot's block table.  Returns (hidden (B, S, D), state); the
     state's ``ssm`` is the rows' recurrent state after their ``valid``
     columns (a configuration with recurrent layers has to be handed both)."""
-    b, s = tokens.shape
-    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     if c.has_ssm and (ssm is None or valid is None):
         raise RecurrentStateUnsupported(
             "this paged program", "its rows carry pages and no recurrent state")
     x = embed_tokens(params, c, tokens)
-    local_flags = jnp.asarray(c.local_flags)
 
-    def layer_step(x, scanned):
-        lp, kp_l, vp_l, is_local, ssm_l = scanned
-        with jax.named_scope("attn_qkv"):
-            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            qkv_in = _times(attn_in, c.attention_in_multiplier)
-            q = matmul(qkv_in, lp["wq"]).reshape(b, s, h, hd)
-            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
-                b, s, kv, hd)
-            v = matmul(qkv_in, lp["wv"]).reshape(b, s, kv, hd)
-            q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
-            k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
-        mixed = None
-        if c.has_ssm:
-            mixed, ssm_l = ssm_mixer(c, lp, attn_in, ssm_l, valid)
+    def call_paged(window, q, kp_l, vp_l):
+        return paged_attention(
+            q, kp_l, vp_l, block_tables, lengths, positions,
+            scale=c.q_scale, softcap=c.attn_softcap, window=window,
+        )
 
-        # Scatter the fresh K/V into their pages.  Cursor pairs are unique
-        # across rows (slots own disjoint pages) except the sink, which is
-        # never read, so duplicate-index order doesn't matter.
+    def attend(q, k, v, pages_l, is_local):
+        """This call's K/V scattered into the pages the cursors name; every
+        query attends through its slot's block table."""
+        kp_l, vp_l = pages_l
+        # Cursor pairs are unique across rows (slots own disjoint pages)
+        # except the sink, which is never read, so duplicate-index order
+        # doesn't matter.
         with jax.named_scope("kv_write"):
             kp_l = kp_l.at[write_pages, write_offsets].set(k)
             vp_l = vp_l.at[write_pages, write_offsets].set(v)
-
-        def attend(window):
-            return paged_attention(
-                q, kp_l, vp_l, block_tables, lengths, positions,
-                scale=c.q_scale, softcap=c.attn_softcap, window=window,
-            )
-
         with jax.named_scope("attention"):
-            if c.sliding_window is None:
-                attn = attend(None)
-            else:
-                attn = jax.lax.cond(
-                    is_local,
-                    lambda _: attend(c.sliding_window),
-                    lambda _: attend(None),
-                    None,
-                )
-        x = attn_out_block(c, lp, x, attn.reshape(b, s, h * hd), mixed)
-        return ffn_block(c, lp, x), (kp_l, vp_l, ssm_l)
+            attn = windowed(c, is_local, call_paged, q, kp_l, vp_l)
+        return attn, (kp_l, vp_l)
 
-    with jax.named_scope("layers"):
-        x, (new_k, new_v, new_ssm) = jax.lax.scan(
-            layer_step, x,
-            (params["layers"], state.k_pages, state.v_pages, local_flags,
-             ssm if c.has_ssm else None),
-        )
+    x, (new_k, new_v), new_ssm = scan_layers(
+        params, c, x, positions, attend, (state.k_pages, state.v_pages),
+        ssm if c.has_ssm else None, valid)
     return final_norm(params, c, x), PagedSlotState(new_k, new_v, new_ssm)
 
 

@@ -582,6 +582,99 @@ def _attention_masks(
     return global_mask, local_mask
 
 
+def _layer_mask(is_local: jax.Array, local_mask, global_mask) -> jax.Array:
+    """This layer's mask of the einsum paths: ``is_local`` is a traced scan
+    input, so both masks are built outside the loop and one is picked here."""
+    return jnp.where(is_local, local_mask, global_mask)
+
+
+def windowed(c: ModelConfig, is_local: jax.Array, call: Callable, *operands):
+    """``call(window, *operands)`` under this layer's window, for the kernels
+    that take the window as a static argument: ``is_local`` is a traced scan
+    input, so the choice is a ``lax.cond`` between two statically windowed
+    calls (and no choice at all for a configuration without a window)."""
+    if c.sliding_window is None:
+        return call(None, *operands)
+    return jax.lax.cond(
+        is_local,
+        functools.partial(call, c.sliding_window),
+        functools.partial(call, None),
+        *operands,
+    )
+
+
+def layer_block(
+    c: ModelConfig, lp, x: jax.Array, positions: jax.Array, attend: Callable,
+    operands_l, is_local: jax.Array, ssm_l, valid: Optional[jax.Array],
+):
+    """One transformer layer, the only one written out: norm, the three
+    products, rope; the recurrent mixer where the configuration has one;
+    ``attend``; the output product and the feed-forward with their residuals.
+
+    ``x`` is (B, S, D) with ``positions`` (B, S), or a decode step's (B, D)
+    with ``positions`` (B,): a span of one without the axis.  ``attend(q, k,
+    v, operands_l, is_local)`` is the caller's cache layout: it gets the roped
+    (B, S, heads, hd) queries, keys and values of this call and the layer's
+    slice of whatever the caller scans over, writes K/V where the layout
+    keeps them and attends over what it holds, and returns (the heads'
+    values, any shape that flattens to ``x``'s rows x H*hd; what the caller
+    wants stacked over the layers).  ``valid`` (B, S) marks the positions
+    that move the mixer's state; None is all of them.
+
+    Returns (x, what ``attend`` handed back, the mixer's state after the
+    span or None)."""
+    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+    one = x.ndim == 2
+    span_of = (lambda t: t[:, None]) if one else (lambda t: t)
+    rows = x.shape[:-1] + ((1,) if one else ())  # (B, S)
+
+    with jax.named_scope("attn_qkv"):
+        attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
+        qkv_in = _times(attn_in, c.attention_in_multiplier)
+        q = matmul(qkv_in, lp["wq"]).reshape(rows + (h, hd))
+        k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+            rows + (kv, hd))
+        v = matmul(qkv_in, lp["wv"]).reshape(rows + (kv, hd))
+        q = apply_rope(q, span_of(positions), c.rope_theta, c.rope_scaling)
+        k = apply_rope(k, span_of(positions), c.rope_theta, c.rope_scaling)
+    mixed = None
+    if c.has_ssm:
+        mixed, ssm_l = ssm_mixer(
+            c, lp, span_of(attn_in), ssm_l,
+            jnp.ones(rows, bool) if valid is None else valid)
+        if one:
+            mixed = mixed[:, 0]
+    attn, attend_out = attend(q, k, v, operands_l, is_local)
+    x = attn_out_block(c, lp, x, attn.reshape(x.shape[:-1] + (h * hd,)), mixed)
+    return ffn_block(c, lp, x), attend_out, ssm_l
+
+
+def scan_layers(
+    params: Params, c: ModelConfig, x: jax.Array, positions: jax.Array,
+    attend: Callable, operands, ssm: Optional["SSMState"],
+    valid: Optional[jax.Array],
+):
+    """The one loop over the layers: ``layer_block`` under a ``lax.scan`` over
+    the stacked weights, the caller's stacked ``operands`` (its cache, any
+    pytree with the layer axis leading, or None), the window flags and the
+    recurrent state.  Returns (x, what ``attend`` handed back stacked over
+    the layers, the state after the span).  A state is handed on only where
+    one was handed in: rows that start a sequence (``ssm`` None) drop it."""
+
+    def step(x, scanned):
+        lp, operands_l, is_local, ssm_l = scanned
+        x, attend_out, new_ssm_l = layer_block(
+            c, lp, x, positions, attend, operands_l, is_local, ssm_l, valid)
+        return x, (attend_out, None if ssm is None else new_ssm_l)
+
+    with jax.named_scope("layers"):
+        x, (attend_outs, new_ssm) = jax.lax.scan(
+            step, x,
+            (params["layers"], operands, jnp.asarray(c.local_flags), ssm),
+        )
+    return x, attend_outs, new_ssm
+
+
 def forward(
     params: Params,
     config: ModelConfig,
@@ -616,119 +709,65 @@ def forward(
         k_valid = jax.lax.dynamic_update_slice(cache.key_valid, valid, (0, write_index))
 
     global_mask, local_mask = _attention_masks(c, positions, valid, k_positions, k_valid)
-    local_flags = jnp.asarray(c.local_flags)
+    reps = c.n_heads // c.n_kv_heads
 
-    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    batch, span = tokens.shape
+    def call_flash(window, q, keys, values):
+        # Pallas blockwise kernel: no (B, H, S, S) logits in HBM.  The
+        # kernel's masking model is one contiguous valid span per row,
+        # described by (start, length) scalars — start=0 covers the
+        # right-padded scoring layout, start=argmax(valid) the
+        # left-padded next-token/embed layout (rows with no valid token
+        # get length 0 and an empty mask either way).
+        from consensus_tpu.ops.flash_attention import flash_attention
 
-    def layer_step(x, scanned, ssm_l=None):
-        lp, k_cache_l, v_cache_l, is_local = scanned
+        return flash_attention(
+            q, keys, values,
+            jnp.sum(valid.astype(jnp.int32), axis=1),
+            jnp.argmax(valid, axis=1).astype(jnp.int32),
+            scale=c.q_scale, softcap=c.attn_softcap, window=window,
+            causal=True, interpret=jax.default_backend() == "cpu",
+        )
 
-        with jax.named_scope("attn_qkv"):
-            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            qkv_in = _times(attn_in, c.attention_in_multiplier)
-            q = matmul(qkv_in, lp["wq"]).reshape(batch, span, h, hd)
-            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
-                batch, span, kv, hd)
-            v = matmul(qkv_in, lp["wv"]).reshape(batch, span, kv, hd)
-            q = apply_rope(q, positions, c.rope_theta, c.rope_scaling)
-            k = apply_rope(k, positions, c.rope_theta, c.rope_scaling)
-        mixed = None
-        if c.has_ssm:
-            mixed, ssm_l = ssm_mixer(c, lp, attn_in, ssm_l, valid)
-
-        if k_cache_l is None:
+    def attend(q, k, v, cache_l, is_local):
+        """Own keys without a cache; with one, this call's K/V written at
+        ``write_index`` and the whole buffer attended."""
+        if cache_l is None:
             keys, values = k, v
         else:
             with jax.named_scope("kv_write"):
                 keys = jax.lax.dynamic_update_slice(
-                    k_cache_l, k, (0, write_index, 0, 0))
+                    cache_l[0], k, (0, write_index, 0, 0))
                 values = jax.lax.dynamic_update_slice(
-                    v_cache_l, v, (0, write_index, 0, 0))
-
-        reps = h // kv
-
+                    cache_l[1], v, (0, write_index, 0, 0))
         with jax.named_scope("attention"):
             if c.use_flash_attention and cache is None:
                 # The pallas kernel takes equal q/kv head counts; expand here.
-                keys_r = jnp.repeat(keys, reps, axis=2)  # (B, T, H, hd)
-                values_r = jnp.repeat(values, reps, axis=2)
-                # Pallas blockwise kernel: no (B, H, S, S) logits in HBM.  The
-                # kernel's masking model is one contiguous valid span per row,
-                # described by (start, length) scalars — start=0 covers the
-                # right-padded scoring layout, start=argmax(valid) the
-                # left-padded next-token/embed layout (rows with no valid token
-                # get length 0 and an empty mask either way).
-                # ``is_local`` is a traced scan input, so window selection is a
-                # lax.cond between two statically-windowed kernel calls.
-                from consensus_tpu.ops.flash_attention import flash_attention
-
-                interp = jax.default_backend() == "cpu"
-                lengths = jnp.sum(valid.astype(jnp.int32), axis=1)
-                starts = jnp.argmax(valid, axis=1).astype(jnp.int32)
-
-                def call_flash(window):
-                    def fn(operands):
-                        qq, kk, vv = operands
-                        return flash_attention(
-                            qq, kk, vv, lengths, starts,
-                            scale=c.q_scale, softcap=c.attn_softcap,
-                            window=window, causal=True, interpret=interp,
-                        )
-                    return fn
-
-                operands = (q, keys_r, values_r)
-                if c.sliding_window is None:
-                    attn = call_flash(None)(operands)
-                else:
-                    attn = jax.lax.cond(
-                        is_local,
-                        call_flash(c.sliding_window),
-                        call_flash(None),
-                        operands,
-                    )
-                attn = attn.astype(x.dtype)
+                attn = windowed(
+                    c, is_local, call_flash, q,
+                    jnp.repeat(keys, reps, axis=2),  # (B, T, H, hd)
+                    jnp.repeat(values, reps, axis=2),
+                ).astype(x.dtype)
             else:
                 # GQA without materializing repeated KV: group q heads by their
                 # kv head — on the decode path jnp.repeat would re-write the
                 # whole (B, T, H, hd) cache expansion every layer every step,
                 # doubling HBM traffic for nothing.
-                qg = q.reshape(batch, span, kv, reps, hd)
+                qg = q.reshape(q.shape[:2] + (c.n_kv_heads, reps, c.head_dim))
                 logits = jnp.einsum("bsgrd,btgd->bgrst", qg, keys).astype(jnp.float32)
                 logits = logits * c.q_scale
                 logits = _softcap(logits, c.attn_softcap)
-                mask = jnp.where(is_local, local_mask, global_mask)
+                mask = _layer_mask(is_local, local_mask, global_mask)
                 logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
                 weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
                 attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
-        x = attn_out_block(c, lp, x, attn.reshape(batch, span, h * hd), mixed)
-        x = ffn_block(c, lp, x)
+        return attn, (None if cache_l is None else (keys, values))
 
-        return x, (keys if k_cache_l is not None else None,
-                   values if k_cache_l is not None else None, ssm_l)
-
-    layer_params = params["layers"]
     if cache is None:
-        with jax.named_scope("layers"):
-            x, _ = jax.lax.scan(
-                lambda carry, xs: (
-                    layer_step(carry, (xs[0], None, None, xs[1]))[0],
-                    None,
-                ),
-                x,
-                (layer_params, local_flags),
-            )
+        x, _, _ = scan_layers(params, c, x, positions, attend, None, None, valid)
         new_cache = None
     else:
-        def scan_fn(carry, xs):
-            lp, kc, vc, flag, ssm_l = xs  # ssm_l: None without a mixer
-            return layer_step(carry, (lp, kc, vc, flag), ssm_l)
-
-        with jax.named_scope("layers"):
-            x, (new_k, new_v, new_ssm) = jax.lax.scan(
-                scan_fn, x,
-                (layer_params, cache.k, cache.v, local_flags, cache.ssm),
-            )
+        x, (new_k, new_v), new_ssm = scan_layers(
+            params, c, x, positions, attend, (cache.k, cache.v), cache.ssm, valid)
         new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions,
                             key_valid=k_valid, ssm=new_ssm)
 
@@ -820,7 +859,6 @@ def forward_trunk_tail(
             "forward_trunk_tail", "rows without their recurrent state")
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     reps = h // kv
-    rows = tokens.shape[0]
     frozen_k = tuple(frozen_k)
     frozen_v = tuple(frozen_v)
     frozen_positions = tuple(frozen_positions)
@@ -865,26 +903,29 @@ def forward_trunk_tail(
             frozen_locals.append(qp[:, :, None] - fkp < c.sliding_window)
         else:
             frozen_locals.append(mask)
-    local_flags = jnp.asarray(c.local_flags)
 
-    def layer_step(x, scanned):
-        (lp, k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail, is_local,
-         ssm_l) = scanned
+    def call_decode(window, q, k_trunk, v_trunk, k_tail, v_tail):
+        # Fused pallas kernel (ops/decode_attention.py): one VMEM pass
+        # per (role, kv-head) instead of four einsums with an fp32
+        # logits intermediate.  Session call sites guarantee per-role
+        # query positions (slots advance in lockstep) — qpos from slot
+        # 0's rows; trunk spans from key_valid (left-padded prefills).
+        from consensus_tpu.ops.decode_attention import decode_attention
 
-        with jax.named_scope("attn_qkv"):
-            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            qkv_in = _times(attn_in, c.attention_in_multiplier)
-            q = matmul(qkv_in, lp["wq"]).reshape(rows, 1, h, hd)
-            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
-                rows, 1, kv, hd)
-            v = matmul(qkv_in, lp["wv"]).reshape(rows, 1, kv, hd)
-            q = apply_rope(q, positions[:, None], c.rope_theta, c.rope_scaling)
-            k = apply_rope(k, positions[:, None], c.rope_theta, c.rope_scaling)
-        mixed = None
-        if c.has_ssm:
-            mixed, ssm_l = ssm_mixer(
-                c, lp, attn_in[:, None], ssm_l, jnp.ones((rows, 1), bool))
-            mixed = mixed[:, 0]
+        return decode_attention(
+            q, k_trunk, v_trunk, k_tail, v_tail,
+            jnp.argmax(trunk.key_valid, axis=1).astype(jnp.int32),
+            positions.reshape(n_slots, n_roles)[0], write_col,
+            n_slots=n_slots, n_roles=n_roles, scale=c.q_scale,
+            softcap=c.attn_softcap, window=window,
+            interpret=jax.default_backend() == "cpu",
+        )
+
+    def attend(q, k, v, operands_l, is_local):
+        """This step's K/V written (quantised where the tail is) at the
+        tail's ``write_col``; then [trunk | frozen blocks | tail] attended,
+        the trunk broadcast over the slots."""
+        k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail = operands_l
 
         with jax.named_scope("kv_write"):
             if tail_quantized:
@@ -914,39 +955,10 @@ def forward_trunk_tail(
                 and not tail_quantized
                 and not trunk_quantized
             ):
-                # Fused pallas kernel (ops/decode_attention.py): one VMEM pass
-                # per (role, kv-head) instead of four einsums with an fp32
-                # logits intermediate.  Session call sites guarantee per-role
-                # query positions (slots advance in lockstep) — qpos from slot
-                # 0's rows; trunk spans from key_valid (left-padded prefills).
-                from consensus_tpu.ops.decode_attention import decode_attention
-
-                interp = jax.default_backend() == "cpu"
-                starts = jnp.argmax(trunk.key_valid, axis=1).astype(jnp.int32)
-                qpos_r = positions.reshape(n_slots, n_roles)[0]
-
-                def call_decode(win):
-                    def fn(operands):
-                        qq, tk, tv, lk, lv = operands
-                        return decode_attention(
-                            qq, tk, tv, lk, lv, starts, qpos_r, write_col,
-                            n_slots=n_slots, n_roles=n_roles, scale=c.q_scale,
-                            softcap=c.attn_softcap, window=win,
-                            interpret=interp,
-                        )
-                    return fn
-
-                operands = (q[:, 0], k_trunk, v_trunk, new_k_tail, new_v_tail)
-                if c.sliding_window is None:
-                    attn = call_decode(None)(operands)
-                else:
-                    attn = jax.lax.cond(
-                        is_local,
-                        call_decode(c.sliding_window),
-                        call_decode(None),
-                        operands,
-                    )
-                attn = attn.astype(x.dtype)
+                attn = windowed(
+                    c, is_local, call_decode,
+                    q[:, 0], k_trunk, v_trunk, new_k_tail, new_v_tail,
+                ).astype(x.dtype)
             else:
                 qg = q.reshape(n_slots, n_roles, kv, reps, hd)
 
@@ -1002,14 +1014,12 @@ def forward_trunk_tail(
                 blocks = [lt] + [
                     key_logits(b, w) for b, w in zip(froz_k, frozen_widths)
                 ] + [key_logits(new_k_tail, t_tail)]
-                masks = (
-                    [jnp.where(is_local, trunk_local, trunk_mask)]
-                    + [
-                        jnp.where(is_local, fl, fm)
-                        for fl, fm in zip(frozen_locals, frozen_masks)
-                    ]
-                    + [jnp.where(is_local, tail_local, tail_mask)]
-                )
+                masks = [
+                    _layer_mask(is_local, local, everywhere)
+                    for local, everywhere in zip(
+                        [trunk_local] + frozen_locals + [tail_local],
+                        [trunk_mask] + frozen_masks + [tail_mask])
+                ]
                 logits = jnp.concatenate(blocks, axis=-1) * c.q_scale
                 logits = _softcap(logits, c.attn_softcap)
                 mask = jnp.concatenate(masks, axis=-1)[:, :, None, None]
@@ -1034,19 +1044,14 @@ def forward_trunk_tail(
                         block, width, weights[..., offset : offset + width]
                     )
                     offset += width
-        x = attn_out_block(c, lp, x, attn.reshape(rows, h * hd), mixed)
-        return ffn_block(c, lp, x), (new_k_tail, new_v_tail, ssm_l)
+        return attn, (new_k_tail, new_v_tail)
 
     # One scanned pytree serves every variant: lax.scan slices each leaf
     # along the layer axis, including nested (int8, scale) pairs and the
     # per-block frozen tuples.
-    scanned = (
-        params["layers"], trunk.k, trunk.v, frozen_k, frozen_v,
-        tail_k, tail_v, local_flags, ssm,
-    )
-    with jax.named_scope("layers"):
-        x, (new_tail_k, new_tail_v, new_ssm) = jax.lax.scan(
-            layer_step, x, scanned)
+    x, (new_tail_k, new_tail_v), new_ssm = scan_layers(
+        params, c, x, positions, attend,
+        (trunk.k, trunk.v, frozen_k, frozen_v, tail_k, tail_v), ssm, None)
     x = final_norm(params, c, x)
     return x, new_tail_k, new_tail_v, new_ssm
 
@@ -1086,7 +1091,10 @@ def forward_shared_trunk(
     n_roles = cache.key_valid.shape[0]
 
     x = embed_tokens(params, c, suffix_tokens)  # (P, L, D)
-    x = jnp.broadcast_to(x[:, None], (n_paths, n_roles) + x.shape[1:])  # (P,R,L,D)
+    # One row a (path, role), as the products and the mixer take them.
+    x = jnp.broadcast_to(
+        x[:, None], (n_paths, n_roles) + x.shape[1:]
+    ).reshape(n_paths * n_roles, span, -1)  # (P*R, L, D)
 
     # Suffix positions continue each role's trunk: (R, L).
     positions = cur_pos[:, None] + 1 + jnp.arange(span)[None, :]
@@ -1099,19 +1107,16 @@ def forward_shared_trunk(
     trunk_mask = cache.key_valid[:, None, :] & jnp.ones(
         (1, span, 1), bool
     )  # (R, L, T)
-    suffix_causal = (
-        jnp.arange(span)[:, None] >= jnp.arange(span)[None, :]
-    )  # (L, L)
+    suffix_mask = jnp.broadcast_to(
+        jnp.arange(span)[:, None] >= jnp.arange(span)[None, :],
+        (n_roles, span, span),
+    )  # (R, L, L)
     if c.sliding_window is not None:
         trunk_local = trunk_mask & (qp - trunk_kp < c.sliding_window)
         suffix_kp = positions[:, None, :]  # (R, 1, L)
-        suffix_local = suffix_causal[None] & (qp - suffix_kp < c.sliding_window)
+        suffix_local = suffix_mask & (qp - suffix_kp < c.sliding_window)
     else:
-        trunk_local = trunk_mask
-        suffix_local = jnp.broadcast_to(
-            suffix_causal[None], (n_roles, span, span)
-        )
-    local_flags = jnp.asarray(c.local_flags)
+        trunk_local, suffix_local = trunk_mask, suffix_mask
 
     ssm_rows = None
     if c.has_ssm:
@@ -1122,39 +1127,21 @@ def forward_shared_trunk(
         # end; what the suffix makes of it is not kept.
         ssm_rows = fork_ssm(cache.ssm, jnp.tile(jnp.arange(n_roles), n_paths))
 
-    def layer_step(x, scanned):
-        # k/v_trunk: (R, T, kv, hd)
-        lp, k_trunk, v_trunk, is_local, ssm_l = scanned
-
-        with jax.named_scope("attn_qkv"):
-            attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
-            flat = attn_in.reshape(n_paths * n_roles, span, -1)
-            qkv_in = _times(flat, c.attention_in_multiplier)
-            q = matmul(qkv_in, lp["wq"]).reshape(n_paths * n_roles, span, h, hd)
-            ks = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
-                n_paths * n_roles, span, kv, hd)
-            vs = matmul(qkv_in, lp["wv"]).reshape(n_paths * n_roles, span, kv, hd)
-            rope_pos = jnp.tile(positions, (n_paths, 1))  # (P*R, L)
-            q = apply_rope(q, rope_pos, c.rope_theta, c.rope_scaling)
-            ks = apply_rope(ks, rope_pos, c.rope_theta, c.rope_scaling)
-            qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
-            ks = ks.reshape(n_paths, n_roles, span, kv, hd)
-            vs = vs.reshape(n_paths, n_roles, span, kv, hd)
-
+    def attend(q, ks, vs, trunk_l, is_local):
+        """The trunk's (R, T) keys broadcast over the paths, beside each
+        path's own suffix; nothing is written."""
+        k_trunk, v_trunk = trunk_l  # (R, T, kv, hd)
+        qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
+        ks = ks.reshape(n_paths, n_roles, span, kv, hd)
+        vs = vs.reshape(n_paths, n_roles, span, kv, hd)
         with jax.named_scope("attention"):
-            # Trunk attention broadcasts the shared (R, T) keys over paths.
             lt = jnp.einsum("prsgmd,rtgd->prgmst", qg, k_trunk).astype(jnp.float32)
             ls = jnp.einsum("prsgmd,prtgd->prgmst", qg, ks).astype(jnp.float32)
             logits = jnp.concatenate([lt, ls], axis=-1) * c.q_scale
             logits = _softcap(logits, c.attn_softcap)
-            t_mask = jnp.where(is_local, trunk_local, trunk_mask)
-            s_mask = jnp.where(
-                is_local, suffix_local, jnp.broadcast_to(
-                    suffix_causal[None], suffix_local.shape
-                )
-            )
             mask = jnp.concatenate(
-                [t_mask, s_mask], axis=-1
+                [_layer_mask(is_local, trunk_local, trunk_mask),
+                 _layer_mask(is_local, suffix_local, suffix_mask)], axis=-1
             )[None, :, None, None]  # (1, R, 1, 1, L, T+L)
             logits = jnp.where(mask, logits, MASK_FILL)
             weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
@@ -1164,22 +1151,12 @@ def forward_shared_trunk(
             ) + jnp.einsum(
                 "prgmst,prtgd->prsgmd", weights[..., t_len:], vs
             )
-        mixed = None
-        if c.has_ssm:
-            mixed, _ = ssm_mixer(
-                c, lp, flat, ssm_l,
-                jnp.ones((n_paths * n_roles, span), bool))
-            mixed = mixed.reshape(n_paths, n_roles, span, -1)
-        x = attn_out_block(
-            c, lp, x, attn.reshape(n_paths, n_roles, span, h * hd), mixed)
-        return ffn_block(c, lp, x), ((ks, vs) if return_suffix_kv else None)
+        return attn, ((ks, vs) if return_suffix_kv else None)
 
-    with jax.named_scope("layers"):
-        x, suffix_kv = jax.lax.scan(
-            layer_step, x,
-            (params["layers"], cache.k, cache.v, local_flags, ssm_rows),
-        )
-    x = final_norm(params, c, x)
+    x, suffix_kv, _ = scan_layers(
+        params, c, x, jnp.tile(positions, (n_paths, 1)), attend,
+        (cache.k, cache.v), ssm_rows, None)
+    x = final_norm(params, c, x).reshape(n_paths, n_roles, span, -1)
     if return_all_positions:
         out = x  # (P, R, L, D) — the shared-context scorer needs every slot
     else:

@@ -3,13 +3,14 @@
 
 Each golden is one welfare-gap table (see
 consensus_tpu/data/scenarios/fairness.py) for one corpus scenario on one
-backend.  The fake-backend tables are exact (hash-deterministic); the
-tiny-gemma2 tables come from PRNGKey(0) random weights, so they are
-deterministic for a fixed jax version and are compared exactly by
-tests/test_fairness_regression.py.
+backend.  The fake-backend scores are hash-deterministic; the tiny-gemma2
+tables come from PRNGKey(0) random weights, so they hold for a fixed jax
+version.  tests/test_fairness_regression.py compares every float at rel
+1e-4 / abs 1e-6 and everything else exactly.
 
 Run from the repo root after any intentional change to the corpus, the
-prompts, or the score-matrix numerics:
+prompts, or the score-matrix numerics, and after a toolchain change that
+moves what ``init_params`` draws from a key:
 
     JAX_PLATFORMS=cpu python scripts/gen_fairness_goldens.py
 """

@@ -281,7 +281,11 @@ class TestEngineChaos:
             {"kind": "nan_logprobs", "op": "score", "call_index": 0,
              "row_index": 1}]}
         registry = Registry()
-        batching = self._engine_stack(plan, registry)
+        # The fault needs a row 1 in the first device batch, so the three
+        # calls have to merge into one dispatch whatever the threads'
+        # timing: the engine is stepped by hand, once all three are queued.
+        batching = self._engine_stack(plan, registry, auto_start=False)
+        engine = batching.engine
         reqs = [ScoreRequest(context="ctx", continuation=f"row {i}")
                 for i in range(3)]
         clean = FakeBackend().score(reqs)
@@ -289,25 +293,29 @@ class TestEngineChaos:
 
         import threading
 
-        barrier = threading.Barrier(3)
-
         def worker(i):
             with batching.session():
-                barrier.wait(timeout=10)
                 try:
                     results[i] = batching.score([reqs[i]])[0]
                 except Exception as exc:  # noqa: BLE001 - asserted below
                     results[i] = exc
 
-        threads = [threading.Thread(target=worker, args=(i,))
+        threads = [threading.Thread(target=worker, args=(i,), daemon=True)
                    for i in range(3)]
         try:
             for t in threads:
                 t.start()
+            # ``submit`` notifies this condition once its call is queued.
+            with engine._work:
+                assert engine._work.wait_for(
+                    lambda: engine._queue_depth() == 3, timeout=30)
+            engine.run_iteration()
             for t in threads:
                 t.join(timeout=30)
         finally:
             batching.close()
+        assert not any(t.is_alive() for t in threads)
+        assert engine.dispatch_counts["score"] == 1  # one merged dispatch
 
         failed = [i for i in range(3) if isinstance(results[i], Exception)]
         assert len(failed) == 1

@@ -2,10 +2,14 @@
 adversarial corpus family, through the PR 10 score-matrix path.
 
 Goldens live under tests/golden/fairness/ and are regenerated with
-``scripts/gen_fairness_goldens.py``.  The fake-backend tables are exact
-(blake2b-deterministic scores); the tiny-gemma2 tables come from
-PRNGKey(0) random weights and are likewise deterministic for a fixed
-jax build.  The adversarial families make the rules disagree for a
+``scripts/gen_fairness_goldens.py``.  The fake-backend scores are exact
+(blake2b-deterministic), but the welfare rules sum them in float32 on the
+device, so a table's sixth significant digit moves with the toolchain:
+every table is held with ``_assert_close``.  The tiny-gemma2 tables come
+from PRNGKey(0) random weights, which are other weights under another
+default PRNG (``jax_threefry_partitionable`` went on by default in JAX
+0.5): regenerate them when the toolchain changes what ``init_params``
+draws.  The adversarial families make the rules disagree for a
 *structural* reason: blocs/sybils repeat opinion text verbatim, so
 candidate utilities repeat per clone — multiplicity moves the
 utilitarian sum but never the egalitarian min.
@@ -52,8 +56,9 @@ def _golden(name):
 
 def _assert_close(got, want, path="table", rel=1e-4, abs_tol=1e-6):
     """Structural equality with float tolerance: XLA's threaded CPU
-    reductions make 500-term float32 sums run-to-run different in the
-    last ulp, so the tiny-gemma2 tables can't be compared bit-exactly."""
+    reductions make 500-term float32 sums run-to-run (and build-to-build)
+    different in the last ulps, so no table can be compared bit-exactly.
+    Winners, flags and counts are compared exactly."""
     if isinstance(want, dict):
         assert isinstance(got, dict) and set(got) == set(want), path
         for key in want:
@@ -70,7 +75,7 @@ def _assert_close(got, want, path="table", rel=1e-4, abs_tol=1e-6):
 
 
 # ---------------------------------------------------------------------------
-# Fake backend: exact tables + the rule-separation acceptance bar
+# Fake backend: pinned tables + the rule-separation acceptance bar
 # ---------------------------------------------------------------------------
 
 
@@ -90,7 +95,7 @@ class TestFakeWelfareGaps:
 
     @pytest.mark.parametrize("sid", FAKE_SCENARIOS)
     def test_table_matches_golden(self, tables, sid):
-        assert tables[sid] == _golden(f"fake_{sid}")
+        _assert_close(tables[sid], _golden(f"fake_{sid}"))
 
     def test_rules_separated_on_at_least_three_families(self, tables):
         families = separated_families(tables.values(), channel="mean_prob")
