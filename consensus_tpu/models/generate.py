@@ -286,8 +286,17 @@ def _decode_segment(
 
     The live KV tail in the while_loop carry is only ``seg_len`` columns —
     a carry is state the compiler may copy every step, where an operand is
-    only read (what that costs on this toolchain is not measured;
-    scripts/decode_step_bench.py is the arm that measures it).
+    only read.  Measured on one v5e (PERF.md 5 and 6, PR 31): while the
+    layer loop took the tail as a scanned input and gave it back as a
+    stacked output, the compiler copied the carried tail twice a step
+    (0.2 GB a side at 32 rows x 64 columns, 0.4 as the device pads a head
+    size of 64: 2.45 ms) and sliced and stacked it a layer (1.12 and
+    1.24 ms), 4.8 of a 13.7 ms step on SmolLM2-1.7B; and the carried
+    recurrent state of Falcon-H1 (537 MB) once a step beside its stacking,
+    0.055 s of a 0.40 s generation call.  Since the layer loop carries the
+    tail and the state whole (``transformer.scan_layers``) a step writes
+    one column of the tail and one layer's state at a time, in place, and
+    nothing of the carry is copied: 8.3 ms a step on SmolLM2-1.7B.
     Earlier segments ride in ``frozen_k/v``: read-only operand BLOCKS, one
     per frozen segment, never copied or concatenated.  With
     ``quantize_tail`` the live tail itself is int8+scale — the carry bytes

@@ -60,6 +60,7 @@ from consensus_tpu.models.transformer import (
     forward_shared_trunk,
     forward_trunk_tail,
     fork_ssm,
+    layer_of,
     make_cache,
     make_ssm_state,
     project_logits,
@@ -772,29 +773,54 @@ def _paged_forward(
             "this paged program", "its rows carry pages and no recurrent state")
     x = embed_tokens(params, c, tokens)
 
-    def call_paged(window, q, kp_l, vp_l):
+    # The pools ride the layer loop's carry whole, (L, pages + 1, page, KV,
+    # hd), and a layer addresses its pages by its index: the layer and page
+    # axes read as one axis of L * (pages + 1) pages, layer ``l``'s page
+    # ``p`` at ``l * (pages + 1) + p``.  One scatter over (layer, page) in
+    # place; no layer's pool is stacked back.
+    pool_shape = state.k_pages.shape
+    pages_a_layer = pool_shape[1]
+    # Rows that share context pages gather each of them many times: more
+    # pages gathered than a layer's pool holds.  On a v5e the gather reads
+    # them twice as fast from a copy of the layer's pool as from inside the
+    # carried buffer (21.8 against 43.6 ms over the 24 layers of a 64-row
+    # score chunk, PERF.md 6, PR 31), which pays for the copy; a prefill's
+    # few rows gather less than the pool holds, and read it where it lies.
+    gather_from_a_copy = block_tables.size > pages_a_layer
+
+    def as_pages(pool):
+        return pool.reshape((-1,) + pool_shape[2:])
+
+    def call_paged(window, q, k_pages, v_pages, layer):
+        if gather_from_a_copy:
+            pools = layer_of(k_pages, layer), layer_of(v_pages, layer)
+            tables = block_tables
+        else:
+            pools = as_pages(k_pages), as_pages(v_pages)
+            tables = jnp.maximum(block_tables, 0) + layer * pages_a_layer
         return paged_attention(
-            q, kp_l, vp_l, block_tables, lengths, positions,
+            q, *pools, tables, lengths, positions,
             scale=c.q_scale, softcap=c.attn_softcap, window=window,
         )
 
-    def attend(q, k, v, pages_l, is_local):
+    def attend(q, k, v, _, pools, layer, is_local):
         """This call's K/V scattered into the pages the cursors name; every
         query attends through its slot's block table."""
-        kp_l, vp_l = pages_l
         # Cursor pairs are unique across rows (slots own disjoint pages)
         # except the sink, which is never read, so duplicate-index order
         # doesn't matter.
         with jax.named_scope("kv_write"):
-            kp_l = kp_l.at[write_pages, write_offsets].set(k)
-            vp_l = vp_l.at[write_pages, write_offsets].set(v)
+            at = write_pages + layer * pages_a_layer
+            k_pages, v_pages = (
+                as_pages(pool).at[at, write_offsets].set(new).reshape(pool_shape)
+                for pool, new in zip(pools, (k, v)))
         with jax.named_scope("attention"):
-            attn = windowed(c, is_local, call_paged, q, kp_l, vp_l)
-        return attn, (kp_l, vp_l)
+            attn = windowed(c, is_local, call_paged, q, k_pages, v_pages, layer)
+        return attn, None, (k_pages, v_pages)
 
-    x, (new_k, new_v), new_ssm = scan_layers(
-        params, c, x, positions, attend, (state.k_pages, state.v_pages),
-        ssm if c.has_ssm else None, valid)
+    x, _, (new_k, new_v), new_ssm = scan_layers(
+        params, c, x, positions, attend, None,
+        (state.k_pages, state.v_pages), ssm if c.has_ssm else None, valid)
     return final_norm(params, c, x), PagedSlotState(new_k, new_v, new_ssm)
 
 

@@ -243,9 +243,32 @@ def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 # ``jax.named_scope`` that names it in a profile (models.MODEL_SCOPES): a
 # scope changes the operations' metadata and nothing else.  The scan over
 # the layers runs under ``layers``, which is left to what the loop itself
-# does: slicing a layer's weights and cache out of the stacked arrays and
-# stacking what the layer returns.
+# does: slicing a layer's weights and read-only operands out of the stacked
+# arrays and stacking what a layer merely produces.  What a layer reads AND
+# updates (a cache, the recurrent state) is not sliced: it rides the loop's
+# carry whole and the layer addresses its part by the layer's index.
 # ---------------------------------------------------------------------------
+
+
+def layer_of(stack: jax.Array, layer: jax.Array) -> jax.Array:
+    """Layer ``layer`` of a buffer whose layer axis leads: a read at an index,
+    for the consumer to fuse, not a slice handed out by the loop."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def put_layer(stack: jax.Array, layer: jax.Array, new: jax.Array) -> jax.Array:
+    """``stack`` with layer ``layer`` replaced by ``new``, in place where the
+    stack is a loop's carry."""
+    return jax.lax.dynamic_update_index_in_dim(stack, new, layer, 0)
+
+
+def put_columns(
+    stack: jax.Array, layer: jax.Array, column, new: jax.Array
+) -> jax.Array:
+    """A (L, B, T, ...) cache with ``new`` (B, S, ...) written from column
+    ``column`` of layer ``layer`` on: the columns a call adds, in place."""
+    return jax.lax.dynamic_update_slice(
+        stack, new[None], (layer, 0, column) + (0,) * (new.ndim - 2))
 
 
 def _times(x: jax.Array, multiplier: Optional[float]) -> jax.Array:
@@ -416,12 +439,16 @@ def _ssm_scan_chunked(c: ModelConfig, x, dt, a, bm, cm, h0):
 
 def ssm_mixer(
     c: ModelConfig, lp, u: jax.Array, state: Optional["SSMState"],
-    valid: jax.Array,
+    valid: jax.Array, layer: Optional[jax.Array] = None,
 ):
     """The Mamba-2 branch of a block, on the block's normed input ``u`` (B,
     S, D): returns (its contribution to the residual (B, S, D), the state
     after the span).  ``state`` is one layer's ``SSMState`` (conv window (B,
-    K-1, C), h (B, H, P, N) float32), or None at a sequence's start.  ``valid`` (B, S)
+    K-1, C), h (B, H, P, N) float32), or None at a sequence's start; with
+    ``layer`` it is every layer's (the layer axis leading, as the layer loop
+    carries it): this layer's window and ``h`` are read at ``layer`` and
+    written back there under the scopes that made them, and the whole comes
+    back.  ``valid`` (B, S)
     is one run of real positions a row; the others leave the state and the
     window as they found them, and what is returned at them is not read.  A
     span of one position takes the recurrence's one-step form, a longer one
@@ -429,6 +456,7 @@ def ssm_mixer(
     b, s, _ = u.shape
     heads, p, n, g = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
     inner, gn = c.ssm_inner, c.ssm_groups * c.ssm_state
+    stacked = state is not None and layer is not None
     if state is None:
         window = jnp.zeros((b, c.ssm_conv - 1, c.ssm_conv_dim), u.dtype)
         h = jnp.zeros((b, heads, p, n), jnp.float32)
@@ -442,9 +470,14 @@ def ssm_mixer(
         z = proj[..., :inner]
         xbc = proj[..., inner : inner + c.ssm_conv_dim]
         dt = proj[..., inner + c.ssm_conv_dim :]
+    # The stacked state is read and written inside the scopes that use it,
+    # so that the state's traffic is the mixer's in a profile.
     with jax.named_scope("ssm_conv"):
-        xbc, window = _ssm_conv(c, lp, xbc, window, valid)
+        xbc, after = _ssm_conv(
+            c, lp, xbc, layer_of(window, layer) if stacked else window, valid)
+        window = put_layer(window, layer, after) if stacked else after
     with jax.named_scope("ssm_scan"):
+        before = layer_of(h, layer) if stacked else h
         x = xbc[..., :inner].reshape(b, s, heads, p)
         bm = xbc[..., inner : inner + gn].reshape(b, s, g, n)
         cm = xbc[..., inner + gn :].reshape(b, s, g, n)
@@ -454,10 +487,12 @@ def ssm_mixer(
         a = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
         if s == 1:
             by_head = lambda t: jnp.repeat(t[:, 0], heads // g, axis=1)
-            y, h = _ssm_scan_step(x[:, 0], dt[:, 0], a, by_head(bm), by_head(cm), h)
+            y, after = _ssm_scan_step(
+                x[:, 0], dt[:, 0], a, by_head(bm), by_head(cm), before)
             y = y[:, None]
         else:
-            y, h = _ssm_scan_chunked(c, x, dt, a, bm, cm, h)
+            y, after = _ssm_scan_chunked(c, x, dt, a, bm, cm, before)
+        h = put_layer(h, layer, after) if stacked else after
         y = y + lp["ssm_d"].astype(jnp.float32)[None, None, :, None] * x.astype(
             jnp.float32)
     with jax.named_scope("ssm_out"):
@@ -605,7 +640,8 @@ def windowed(c: ModelConfig, is_local: jax.Array, call: Callable, *operands):
 
 def layer_block(
     c: ModelConfig, lp, x: jax.Array, positions: jax.Array, attend: Callable,
-    operands_l, is_local: jax.Array, ssm_l, valid: Optional[jax.Array],
+    operands_l, written, ssm, layer: jax.Array, is_local: jax.Array,
+    valid: Optional[jax.Array],
 ):
     """One transformer layer, the only one written out: norm, the three
     products, rope; the recurrent mixer where the configuration has one;
@@ -613,16 +649,18 @@ def layer_block(
 
     ``x`` is (B, S, D) with ``positions`` (B, S), or a decode step's (B, D)
     with ``positions`` (B,): a span of one without the axis.  ``attend(q, k,
-    v, operands_l, is_local)`` is the caller's cache layout: it gets the roped
-    (B, S, heads, hd) queries, keys and values of this call and the layer's
-    slice of whatever the caller scans over, writes K/V where the layout
-    keeps them and attends over what it holds, and returns (the heads'
-    values, any shape that flattens to ``x``'s rows x H*hd; what the caller
-    wants stacked over the layers).  ``valid`` (B, S) marks the positions
-    that move the mixer's state; None is all of them.
+    v, operands_l, written, layer, is_local)`` is the caller's cache layout:
+    it gets the roped (B, S, heads, hd) queries, keys and values of this
+    call, the layer's slice of what the caller only reads, and ``written``,
+    every layer's part of what the caller reads and updates, which it
+    addresses by ``layer``: it writes K/V where the layout keeps them and
+    attends over what it holds, and returns (the heads' values, any shape
+    that flattens to ``x``'s rows x H*hd; what the caller wants stacked over
+    the layers; ``written`` updated).  ``ssm`` is every layer's recurrent
+    state, or None for rows that start a sequence.  ``valid`` (B, S) marks the
+    positions that move the mixer's state; None is all of them.
 
-    Returns (x, what ``attend`` handed back, the mixer's state after the
-    span or None)."""
+    Returns (x, what ``attend`` produced, ``written``, ``ssm``)."""
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     one = x.ndim == 2
     span_of = (lambda t: t[:, None]) if one else (lambda t: t)
@@ -639,40 +677,55 @@ def layer_block(
         k = apply_rope(k, span_of(positions), c.rope_theta, c.rope_scaling)
     mixed = None
     if c.has_ssm:
-        mixed, ssm_l = ssm_mixer(
-            c, lp, span_of(attn_in), ssm_l,
-            jnp.ones(rows, bool) if valid is None else valid)
+        mixed, after = ssm_mixer(
+            c, lp, span_of(attn_in), ssm,
+            jnp.ones(rows, bool) if valid is None else valid, layer)
+        if ssm is not None:  # a state is handed on only where one came in
+            ssm = after
         if one:
             mixed = mixed[:, 0]
-    attn, attend_out = attend(q, k, v, operands_l, is_local)
+    attn, produced, written = attend(
+        q, k, v, operands_l, written, layer, is_local)
     x = attn_out_block(c, lp, x, attn.reshape(x.shape[:-1] + (h * hd,)), mixed)
-    return ffn_block(c, lp, x), attend_out, ssm_l
+    return ffn_block(c, lp, x), produced, written, ssm
 
 
 def scan_layers(
     params: Params, c: ModelConfig, x: jax.Array, positions: jax.Array,
-    attend: Callable, operands, ssm: Optional["SSMState"],
+    attend: Callable, operands, written, ssm: Optional["SSMState"],
     valid: Optional[jax.Array],
 ):
-    """The one loop over the layers: ``layer_block`` under a ``lax.scan`` over
-    the stacked weights, the caller's stacked ``operands`` (its cache, any
-    pytree with the layer axis leading, or None), the window flags and the
-    recurrent state.  Returns (x, what ``attend`` handed back stacked over
-    the layers, the state after the span).  A state is handed on only where
-    one was handed in: rows that start a sequence (``ssm`` None) drop it."""
+    """The one loop over the layers: ``layer_block`` under a ``lax.scan``.
 
-    def step(x, scanned):
-        lp, operands_l, is_local, ssm_l = scanned
-        x, attend_out, new_ssm_l = layer_block(
-            c, lp, x, positions, attend, operands_l, is_local, ssm_l, valid)
-        return x, (attend_out, None if ssm is None else new_ssm_l)
+    What a layer reads AND updates rides the loop's carry whole, beside
+    ``x``, and each layer addresses its part by the layer's index:
+    ``written`` (the caller's cache: page pools, a dense cache, a decode
+    tail; any pytree with the layer axis leading, or None) and ``ssm`` (the
+    recurrent state).  Handed to the scan as an input and taken back as an
+    output, such a buffer is sliced out of the stack and stacked back whole
+    every layer for the few rows a layer writes.  What a layer only reads is
+    scanned over: the stacked weights, the caller's ``operands`` (a trunk's
+    K/V, frozen blocks; or None), the window flags, and the layer's index.
+
+    Returns (x, what ``attend`` produced stacked over the layers, ``written``
+    and ``ssm`` after the span).  A state is handed on only where one was
+    handed in: rows that start a sequence (``ssm`` None) drop it."""
+
+    def step(carry, scanned):
+        x, written, ssm = carry
+        lp, operands_l, is_local, layer = scanned
+        x, produced, written, ssm = layer_block(
+            c, lp, x, positions, attend, operands_l, written, ssm, layer,
+            is_local, valid)
+        return (x, written, ssm), produced
 
     with jax.named_scope("layers"):
-        x, (attend_outs, new_ssm) = jax.lax.scan(
-            step, x,
-            (params["layers"], operands, jnp.asarray(c.local_flags), ssm),
+        (x, written, ssm), produced = jax.lax.scan(
+            step, (x, written, ssm),
+            (params["layers"], operands, jnp.asarray(c.local_flags),
+             jnp.arange(c.n_layers, dtype=jnp.int32)),
         )
-    return x, attend_outs, new_ssm
+    return x, produced, written, ssm
 
 
 def forward(
@@ -728,17 +781,17 @@ def forward(
             causal=True, interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, cache_l, is_local):
+    def attend(q, k, v, _, kv_cache, layer, is_local):
         """Own keys without a cache; with one, this call's K/V written at
-        ``write_index`` and the whole buffer attended."""
-        if cache_l is None:
+        ``(layer, 0, write_index)`` and the layer's whole buffer attended."""
+        if kv_cache is None:
             keys, values = k, v
         else:
             with jax.named_scope("kv_write"):
-                keys = jax.lax.dynamic_update_slice(
-                    cache_l[0], k, (0, write_index, 0, 0))
-                values = jax.lax.dynamic_update_slice(
-                    cache_l[1], v, (0, write_index, 0, 0))
+                kv_cache = tuple(
+                    put_columns(buffer, layer, write_index, new)
+                    for buffer, new in zip(kv_cache, (k, v)))
+            keys, values = (layer_of(buffer, layer) for buffer in kv_cache)
         with jax.named_scope("attention"):
             if c.use_flash_attention and cache is None:
                 # The pallas kernel takes equal q/kv head counts; expand here.
@@ -760,14 +813,16 @@ def forward(
                 logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
                 weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
                 attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
-        return attn, (None if cache_l is None else (keys, values))
+        return attn, None, kv_cache
 
     if cache is None:
-        x, _, _ = scan_layers(params, c, x, positions, attend, None, None, valid)
+        x, _, _, _ = scan_layers(
+            params, c, x, positions, attend, None, None, None, valid)
         new_cache = None
     else:
-        x, (new_k, new_v), new_ssm = scan_layers(
-            params, c, x, positions, attend, (cache.k, cache.v), cache.ssm, valid)
+        x, _, (new_k, new_v), new_ssm = scan_layers(
+            params, c, x, positions, attend, None, (cache.k, cache.v),
+            cache.ssm, valid)
         new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions,
                             key_valid=k_valid, ssm=new_ssm)
 
@@ -825,9 +880,10 @@ def forward_trunk_tail(
     ``frozen_*``: optional read-only KV blocks holding tokens the row
     generated in EARLIER decode segments (models/generate.py's segmented
     decode), one block per frozen segment, in chronological order.  The
-    live tail rides the while_loop carry, state the compiler may copy every
-    step (scripts/decode_step_bench.py times it; not measured on this
-    toolchain).  Frozen blocks are plain operands: read
+    live tail rides the while_loop carry and the layer loop's carry inside
+    it, whole: a step writes one column of it in place
+    (``generate._decode_segment`` has what the carry cost while it was
+    sliced and stacked a layer).  Frozen blocks are plain operands: read
     once per step by attention, never copied, never concatenated (the
     per-block list replaces round 3's single concatenated block, whose
     append transient dominated the segmented HBM row allowance), and always
@@ -904,16 +960,19 @@ def forward_trunk_tail(
         else:
             frozen_locals.append(mask)
 
-    def call_decode(window, q, k_trunk, v_trunk, k_tail, v_tail):
+    def call_decode(window, q, k_trunk, v_trunk, k_tail, v_tail, layer):
         # Fused pallas kernel (ops/decode_attention.py): one VMEM pass
         # per (role, kv-head) instead of four einsums with an fp32
         # logits intermediate.  Session call sites guarantee per-role
         # query positions (slots advance in lockstep) — qpos from slot
         # 0's rows; trunk spans from key_valid (left-padded prefills).
+        # The tails come whole, as the loop carries them (through
+        # ``windowed``'s cond too); the kernel takes this layer's.
         from consensus_tpu.ops.decode_attention import decode_attention
 
         return decode_attention(
-            q, k_trunk, v_trunk, k_tail, v_tail,
+            q, k_trunk, v_trunk, layer_of(k_tail, layer),
+            layer_of(v_tail, layer),
             jnp.argmax(trunk.key_valid, axis=1).astype(jnp.int32),
             positions.reshape(n_slots, n_roles)[0], write_col,
             n_slots=n_slots, n_roles=n_roles, scale=c.q_scale,
@@ -921,31 +980,29 @@ def forward_trunk_tail(
             interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, operands_l, is_local):
+    def attend(q, k, v, operands_l, tails, layer, is_local):
         """This step's K/V written (quantised where the tail is) at the
-        tail's ``write_col``; then [trunk | frozen blocks | tail] attended,
+        tails' ``(layer, 0, write_col)``, one column of the carried
+        buffers; then [trunk | frozen blocks | this layer's tail] attended,
         the trunk broadcast over the slots."""
-        k_trunk, v_trunk, froz_k, froz_v, k_tail, v_tail = operands_l
+        k_trunk, v_trunk, froz_k, froz_v = operands_l
+
+        def write_column(tail, new):
+            """``new`` (Rows, 1, KV, hd) into every layer's ``tail`` (or its
+            (int8, scale) pair) at this layer's column."""
+            if tail_quantized:
+                new = quantize_kv(new)
+            return jax.tree.map(
+                lambda buffer, column: put_columns(
+                    buffer, layer, write_col, column),
+                tail, new)
 
         with jax.named_scope("kv_write"):
-            if tail_quantized:
-                qk, ks = quantize_kv(k)
-                qv, vs = quantize_kv(v)
-                new_k_tail = (
-                    jax.lax.dynamic_update_slice(k_tail[0], qk, (0, write_col, 0, 0)),
-                    jax.lax.dynamic_update_slice(k_tail[1], ks, (0, write_col, 0, 0)),
-                )
-                new_v_tail = (
-                    jax.lax.dynamic_update_slice(v_tail[0], qv, (0, write_col, 0, 0)),
-                    jax.lax.dynamic_update_slice(v_tail[1], vs, (0, write_col, 0, 0)),
-                )
-            else:
-                new_k_tail = jax.lax.dynamic_update_slice(
-                    k_tail, k, (0, write_col, 0, 0)
-                )
-                new_v_tail = jax.lax.dynamic_update_slice(
-                    v_tail, v, (0, write_col, 0, 0)
-                )
+            tails = (write_column(tails[0], k), write_column(tails[1], v))
+        # This layer's tail, read where it lies: the index goes into the
+        # einsums' operand reads.
+        new_k_tail, new_v_tail = jax.tree.map(
+            lambda buffer: layer_of(buffer, layer), tails)
 
         with jax.named_scope("attention"):
             if (
@@ -957,7 +1014,7 @@ def forward_trunk_tail(
             ):
                 attn = windowed(
                     c, is_local, call_decode,
-                    q[:, 0], k_trunk, v_trunk, new_k_tail, new_v_tail,
+                    q[:, 0], k_trunk, v_trunk, tails[0], tails[1], layer,
                 ).astype(x.dtype)
             else:
                 qg = q.reshape(n_slots, n_roles, kv, reps, hd)
@@ -1044,14 +1101,14 @@ def forward_trunk_tail(
                         block, width, weights[..., offset : offset + width]
                     )
                     offset += width
-        return attn, (new_k_tail, new_v_tail)
+        return attn, None, tails
 
-    # One scanned pytree serves every variant: lax.scan slices each leaf
-    # along the layer axis, including nested (int8, scale) pairs and the
-    # per-block frozen tuples.
-    x, (new_tail_k, new_tail_v), new_ssm = scan_layers(
+    # One pytree a kind serves every variant: lax.scan slices each read-only
+    # leaf along the layer axis, including nested (int8, scale) pairs and
+    # the per-block frozen tuples, and carries the tails as they come.
+    x, _, (new_tail_k, new_tail_v), new_ssm = scan_layers(
         params, c, x, positions, attend,
-        (trunk.k, trunk.v, frozen_k, frozen_v, tail_k, tail_v), ssm, None)
+        (trunk.k, trunk.v, frozen_k, frozen_v), (tail_k, tail_v), ssm, None)
     x = final_norm(params, c, x)
     return x, new_tail_k, new_tail_v, new_ssm
 
@@ -1127,9 +1184,10 @@ def forward_shared_trunk(
         # end; what the suffix makes of it is not kept.
         ssm_rows = fork_ssm(cache.ssm, jnp.tile(jnp.arange(n_roles), n_paths))
 
-    def attend(q, ks, vs, trunk_l, is_local):
+    def attend(q, ks, vs, trunk_l, _, layer, is_local):
         """The trunk's (R, T) keys broadcast over the paths, beside each
-        path's own suffix; nothing is written."""
+        path's own suffix; nothing is written, so nothing is carried, and
+        the suffix's K/V are produced: stacked by the loop."""
         k_trunk, v_trunk = trunk_l  # (R, T, kv, hd)
         qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
         ks = ks.reshape(n_paths, n_roles, span, kv, hd)
@@ -1151,11 +1209,11 @@ def forward_shared_trunk(
             ) + jnp.einsum(
                 "prgmst,prtgd->prsgmd", weights[..., t_len:], vs
             )
-        return attn, ((ks, vs) if return_suffix_kv else None)
+        return attn, ((ks, vs) if return_suffix_kv else None), None
 
-    x, suffix_kv, _ = scan_layers(
+    x, suffix_kv, _, _ = scan_layers(
         params, c, x, jnp.tile(positions, (n_paths, 1)), attend,
-        (cache.k, cache.v), ssm_rows, None)
+        (cache.k, cache.v), None, ssm_rows, None)
     x = final_norm(params, c, x).reshape(n_paths, n_roles, span, -1)
     if return_all_positions:
         out = x  # (P, R, L, D) — the shared-context scorer needs every slot

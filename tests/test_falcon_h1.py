@@ -583,24 +583,27 @@ def test_a_program_that_cannot_carry_the_state_says_so_when_traced(program):
 
 #: sha256 of ``lowered.as_text()`` of the two programs at the sizes of
 #: ``tests/test_trace.py`` ``_lower_program``, and of ``init_params``' leaves
-#: (path and float32 bytes) under PRNGKey(7).  The two
-#: ``generate_tokens_shared_trunk`` pins and the four weight pins are of PR 26,
-#: the commit before the hybrid block came; the two ``paged_score_chunk`` pins
-#: are of PR 28, which gave every configuration's score chunk the streamed
-#: head (and nothing of the hybrid block to a dense one).
+#: (path and float32 bytes) under PRNGKey(7).  The four weight pins are of
+#: PR 26, the commit before the hybrid block came.  The four program pins are
+#: of PR 31, which changed every program with a cache by design: the layer
+#: loop carries the pools, the cache and the tail and a layer writes its part
+#: in place (before it, the two ``generate_tokens_shared_trunk`` pins were of
+#: PR 26 and the two ``paged_score_chunk`` pins of PR 28's streamed head).  A
+#: dense program still has nothing of the hybrid block: the ``ssm``
+#: assertions below.
 PARENT = {
     "tiny-gemma2/generate_tokens_shared_trunk":
-        "8b7d2a366dcd6b1b6e76ba76e13c0a9dc3f3e72ec8dcb2544f0d9dac0fcf8d9a",
+        "2dedda7649ac8ab14cef8a7754a528747bbd7dd0ae9ecc6ce0ffae86ccf2fb45",
     "tiny-gemma2/paged_score_chunk":
-        "27747fbb7ec3bfc7e391849fe25616d1fde98444f096d89f2c40fd591c120e18",
+        "c599befca3063d85159c91afaf327db96f1edd64144c22a4c994188e9f985342",
     "tiny-gemma2/init/float32":
         "31d40293605e5407fe31a6a79ee7e4b983c1b492ddae03cf78db6cf00b0b96d6",
     "tiny-gemma2/init/bfloat16":
         "f4df2bac294da12861c4ccda42b003721b67df7e6381f200c92a6262db29ff25",
     "tiny-llama3/generate_tokens_shared_trunk":
-        "0c837804c098a8311221312a20e17a262bbd4d6f03d0579c612237eaa0cb5b02",
+        "2b529415ac0ad18d2a77e82dcebfdfa0c49b5e5b7dc7ce2ec6d4c44ad85b89c5",
     "tiny-llama3/paged_score_chunk":
-        "280e18621c60c88e68e5af9bf66ca9d9bd6f76b833933120bb8f4406324c481f",
+        "8c0d22e16f9d9e2d057cb8a3ba95a2a3fc068cb547772ad527505174d94d6947",
     "tiny-llama3/init/float32":
         "a22fc02ac159dd2f1fa24f75a89472b9ceee55ac6f47545fe05a0d62e11ca07b",
     "tiny-llama3/init/bfloat16":

@@ -15,6 +15,10 @@ file already holds at this level are left there (``tests/test_engine.py``
 
 (b) The structure itself: one reader of the layer's weights and one scan
 under ``layers`` in ``consensus_tpu/models``.
+
+(c) What a layer reads and updates rides that scan's carry whole (page pools,
+the dense cache, the decode tail, the recurrent state), and a write in place
+touches the rows it names and nothing else.
 """
 
 import ast
@@ -261,3 +265,186 @@ class TestTheLayerIsWrittenOnce:
             "stepper.py:_paged_forward", "transformer.py:forward",
             "transformer.py:forward_trunk_tail",
             "transformer.py:forward_shared_trunk"]
+
+
+# ---------------------------------------------------------------------------
+# (c) What a layer writes is carried, and written where it lies
+# ---------------------------------------------------------------------------
+
+PAGES, PAGE, BLOCKS, CHUNK = 3 * ROWS, 4, 3, 8
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _layer_scans(jaxpr):
+    """Every ``scan`` equation under the scope ``layers``, at any depth."""
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "scan"
+                and str(eqn.source_info.name_stack).split("/")[-1] == "layers"):
+            yield eqn
+        for sub in _subjaxprs(eqn):
+            yield from _layer_scans(sub)
+
+
+def _paged_arguments(c, rows=ROWS):
+    tokens = jnp.zeros((rows, CHUNK), jnp.int32)
+    return dict(
+        tokens=tokens, valid=jnp.ones((rows, CHUNK), bool),
+        tables=jnp.zeros((rows, BLOCKS), jnp.int32),
+        lengths=jnp.full((rows,), CHUNK, jnp.int32),
+        state=jax.eval_shape(lambda: stepper.make_page_state(
+            c, PAGES, PAGE, jnp.float32, ssm_rows=rows)))
+
+
+def _trace(program, c, params):
+    """(the program's jaxpr, the shapes of every buffer its layers read and
+    update, those of them that some layer scan of the program may only
+    read)."""
+    shapes = jax.eval_shape(lambda: params)
+    a = _paged_arguments(c)
+    ssm = jax.tree.leaves(a["state"].ssm)
+    written = [a["state"].k_pages.shape] + [leaf.shape for leaf in ssm]
+    read_elsewhere = []
+    if program == "paged_prefill_chunk":
+        jaxpr = jax.make_jaxpr(lambda p, state: stepper.paged_prefill_chunk(
+            p, c, a["tokens"], a["valid"], state, a["tables"], a["lengths"],
+            a["tokens"], a["tokens"]))(shapes, a["state"])
+    elif program == "paged_score_chunk":
+        more = dict(ssm_rows=jnp.zeros((ROWS,), jnp.int32)) if c.has_ssm else {}
+        jaxpr = jax.make_jaxpr(lambda p, state: stepper.paged_score_chunk(
+            p, c, a["tokens"], a["tokens"], a["valid"], a["valid"], state,
+            a["tables"], a["lengths"], a["tokens"], a["tokens"], **more))(
+                shapes, a["state"])
+    else:
+        from consensus_tpu.models.generate import generate_tokens_shared_trunk
+
+        jaxpr = jax.make_jaxpr(lambda p: generate_tokens_shared_trunk(
+            p, c, jnp.zeros((1, PROMPT), jnp.int32), jnp.ones((1, PROMPT), bool),
+            ROWS, jnp.zeros((ROWS, 2), jnp.uint32), max_new_tokens=STEPS,
+            temperature=jnp.ones((ROWS,)),
+            eos_ids=jnp.asarray([-1], jnp.int32)))(shapes)
+        kv = (c.n_kv_heads, c.head_dim)
+        written = [(c.n_layers, 1, PROMPT) + kv, (c.n_layers, ROWS, STEPS) + kv]
+        read_elsewhere = written[:1]  # the prefill writes the trunk, a step reads it
+        for rows in (1, ROWS) if c.has_ssm else ():
+            written += [leaf.shape for leaf in jax.tree.leaves(
+                jax.eval_shape(lambda: tf.make_ssm_state(c, rows)))]
+    return jaxpr.jaxpr, set(written), set(read_elsewhere)
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+@pytest.mark.parametrize("program", [
+    "paged_score_chunk", "paged_prefill_chunk", "generate_tokens_shared_trunk"])
+def test_the_layer_scan_carries_what_a_layer_writes(preset, program):
+    """Pools, cache, tail and state are among the carried values of the scan
+    under ``layers`` and among neither its scanned inputs nor its stacked
+    outputs: handed in and taken back that way, each is sliced out of the
+    stack and stacked back whole every layer."""
+    c, params, _, _ = _model(preset)
+    jaxpr, written, read_elsewhere = _trace(program, c, params)
+    scans = list(_layer_scans(jaxpr))
+    assert scans
+    carried = set()
+    for scan in scans:
+        consts, carry = scan.params["num_consts"], scan.params["num_carry"]
+        carried |= {v.aval.shape for v in scan.invars[consts:consts + carry]}
+        scanned = {v.aval.shape for v in scan.invars[consts + carry:]}
+        stacked = {v.aval.shape for v in scan.outvars[carry:]}
+        assert not scanned & (written - read_elsewhere), scanned & written
+        assert not stacked & written, stacked & written
+    assert written <= carried, written - carried
+
+
+def _filled_pool(c, seed):
+    """A pool with something in every cell of every page, and a state a row."""
+    state = stepper.make_page_state(c, PAGES, PAGE, jnp.float32, ssm_rows=ROWS)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    return jax.tree.map(
+        lambda leaf: jax.random.normal(next(keys), leaf.shape, leaf.dtype), state)
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+@pytest.mark.parametrize("program", ["paged_prefill_chunk", "paged_score_chunk"])
+def test_a_paged_write_touches_the_cells_it_names_and_no_other(preset, program):
+    """On a donated pool: rows 0 and 1 write their columns into their own
+    pages, row 2 has no valid column and writes to the sink.  Every cell of
+    every layer that no cursor names (the pages of the context both rows
+    read, row 2's pages, the offsets past a row's columns) is bit for bit
+    what it was; the row with no valid column keeps its state at every
+    layer."""
+    c, params, prompts, _ = _model(preset)
+    state = _filled_pool(c, 3)
+    before = jax.tree.map(np.asarray, state)
+    sink = PAGES
+    columns = np.arange(CHUNK)
+    # Pages 0-1: a context all rows read.  Row r writes pages 2 + 2r, 3 + 2r.
+    tables = np.stack([[0, 1, 2 + 2 * r, 3 + 2 * r] for r in range(ROWS)])
+    valid = np.ones((ROWS, CHUNK), bool)
+    valid[1, 5:] = False
+    valid[2] = False
+    own = 2 + 2 * np.arange(ROWS)[:, None] + columns[None, :] // PAGE
+    write_pages = np.where(valid, own, sink).astype(np.int32)
+    write_offsets = np.where(valid, columns[None, :] % PAGE, 0).astype(np.int32)
+    lengths = 2 * PAGE + valid.sum(axis=1).astype(np.int32)
+    tokens = jnp.asarray(np.resize(prompts, (ROWS, CHUNK)))
+    args = (jnp.asarray(valid), state, jnp.asarray(tables.astype(np.int32)),
+            jnp.asarray(lengths), jnp.asarray(write_pages),
+            jnp.asarray(write_offsets))
+    if program == "paged_prefill_chunk":
+        _, after = stepper.paged_prefill_chunk(params, c, tokens, *args)
+    else:
+        more = dict(ssm_rows=jnp.arange(ROWS)) if c.has_ssm else {}
+        _, after = stepper.paged_score_chunk(
+            params, c, tokens, tokens, args[0], *args, **more)
+    named = np.zeros((PAGES + 1, PAGE), bool)
+    named[write_pages, write_offsets] = True
+    assert named[:sink].sum() == valid.sum()
+    for was, now in ((before.k_pages, after.k_pages),
+                     (before.v_pages, after.v_pages)):
+        now = np.asarray(now)
+        assert now.shape == was.shape
+        np.testing.assert_array_equal(now[:, ~named], was[:, ~named])
+        changed = (now[:, :sink] != was[:, :sink]).any(axis=(-1, -2))
+        np.testing.assert_array_equal(
+            changed, np.broadcast_to(named[:sink], changed.shape))
+    if c.has_ssm:
+        for was, now in zip(before.ssm, after.ssm):
+            now = np.asarray(now)
+            if program == "paged_score_chunk":  # the snapshots stay
+                np.testing.assert_array_equal(now, was)
+            else:
+                np.testing.assert_array_equal(now[:, 2], was[:, 2])
+                moved = (now[:, :2] != was[:, :2]).reshape(len(now), 2, -1)
+                assert moved.any(axis=-1).all()
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+def test_a_decode_step_writes_one_column_of_the_tail(preset, quantized):
+    """The step's K/V land in column ``write_col`` of every layer of the
+    carried tail; the columns before and past it are the zeros they were."""
+    c, params, prompts, tails = _model(preset)
+    trunk = _prefilled(c, params, prompts[:1], PROMPT)
+    shape = (c.n_layers, ROWS, STEPS, c.n_kv_heads, c.head_dim)
+    empty = ((jnp.zeros(shape, jnp.int8), jnp.zeros(shape[:-1] + (1,)))
+             if quantized else jnp.zeros(shape))
+    column = 2
+    _, tail_k, tail_v, _ = tf.forward_trunk_tail(
+        params, c, jnp.asarray(tails[:, 0]), jnp.full((ROWS,), PROMPT), trunk,
+        empty, empty,
+        jnp.broadcast_to(PROMPT + jnp.arange(STEPS), (ROWS, STEPS)),
+        jnp.asarray(column, jnp.int32), n_slots=ROWS, n_roles=1,
+        ssm=tf.fork_ssm(trunk.ssm, ROWS) if c.has_ssm else None)
+    for tail in (tail_k, tail_v):
+        for leaf, was in zip(jax.tree.leaves(tail), jax.tree.leaves(empty)):
+            leaf = np.asarray(leaf)
+            assert leaf.shape == was.shape and leaf.dtype == was.dtype
+            others = np.delete(leaf, column, axis=2)
+            np.testing.assert_array_equal(others, np.zeros_like(others))
+            assert (leaf[:, :, column] != 0).any(axis=(-1, -2)).all()
