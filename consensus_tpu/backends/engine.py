@@ -261,6 +261,23 @@ class DecodeEngine:
                 "paged_verify_steps, paged_gather_step)",
                 STREAM_NEEDS_STATE,
             )
+        #: A configuration with layers of more than one kind
+        #: (``has_layer_kinds`` of the inner backend) keeps a pool a kind of
+        #: attention, which the stream path's one pool is not: refused here
+        #: too, and the prefix cache declines its runs as it declines a
+        #: recurrent configuration's.
+        self.layer_kinds = bool(getattr(inner, "has_layer_kinds", False))
+        if self.layer_kinds and (decode_steps is not None or speculative):
+            from consensus_tpu.models.config import (
+                NEEDS_ONE_KIND,
+                LayerKindsUnsupported,
+            )
+
+            raise LayerKindsUnsupported(
+                "the engine's stream path (decode_steps: paged_decode_steps, "
+                "paged_verify_steps, paged_gather_step)",
+                NEEDS_ONE_KIND,
+            )
         self.speculative = bool(speculative)
         if self.speculative and self.decode_steps is None:
             # The draft window IS the decode window; speculative alone
@@ -337,7 +354,8 @@ class DecodeEngine:
                 "record_prefix_run_declined", None)
             self.prefix_caches = [
                 PrefixCache(pool, budget, identity=identity,
-                            needs_state=self.recurrent, on_declined=declined)
+                            needs_state=self.recurrent or self.layer_kinds,
+                            on_declined=declined)
                 for pool in self.pools
             ]
         self.prefix_cache = self.prefix_caches[0]

@@ -52,8 +52,10 @@ from consensus_tpu.backends.base import (
     TokenCandidate,
 )
 from consensus_tpu.models.config import (
+    NEEDS_ONE_KIND,
     SEARCH_NEEDS_STATE,
     STREAM_NEEDS_STATE,
+    LayerKindsUnsupported,
     ModelConfig,
     RecurrentStateUnsupported,
     get_model_config,
@@ -98,6 +100,16 @@ _SESSION_CACHE_BYTES_CAP = 8 * 1024**3
 _HBM_BYTES = 15 * 1024**3
 _ACTIVATION_RESERVE_BYTES = 3 * 1024**3
 _SESSION_MIN_BUDGET_BYTES = 1 * 1024**3
+
+#: Pages a score matrix's pool and blocks its rows' tables grow by, with
+#: layers of more than one kind.  Such a configuration's paged program is a
+#: loop a run of equal layers (four at MiMo-V2-Flash's period, 7-10 s of
+#: compiling each on the chip's host), and a pool sized to the page makes two
+#: new programs of nearly every matrix: 103 over the benchmark's warm-up
+#: ladder, 20 in these steps.  The price: at most 255 pages that no table
+#: names and 15 blocks a row that the rows' lengths mask.
+_KINDS_POOL_STEP_PAGES = 256
+_KINDS_TABLE_STEP_BLOCKS = 16
 
 
 class _SessionBudget:
@@ -271,7 +283,9 @@ class TPUBackend:
         # alias so older configs keep working.
         if quantize_frozen_kv is not None:
             kv_quant = bool(quantize_frozen_kv)
-        self.kv_quant = bool(kv_quant)
+        # (A cache a kind of attention has no int8 form: such a
+        # configuration keeps its generated keys and values as they are.)
+        self.kv_quant = bool(kv_quant) and not self.config.has_layer_kinds
         # Timing mode (VERDICT r2 #4): pin every generation to its full
         # max_tokens budget (no EOS early-exit, no stop-string truncation)
         # so random-weight timing runs can't flatter themselves with 1-token
@@ -280,6 +294,12 @@ class TPUBackend:
 
         if quantization not in (None, "none", "int8"):
             raise ValueError(f"unknown quantization mode: {quantization!r}")
+        if self.config.has_layer_kinds:
+            if quantization == "int8":
+                raise LayerKindsUnsupported("int8 weights", NEEDS_ONE_KIND)
+            if tp > 1 or (dp is not None and dp > 1):
+                raise LayerKindsUnsupported(
+                    "a mesh of several chips (tp > 1 or dp > 1)", NEEDS_ONE_KIND)
         want_int8 = quantization == "int8" and params is None
 
         jax_dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[dtype]
@@ -416,6 +436,14 @@ class TPUBackend:
         # cache events per padded program shape, H2D/D2H transfer timings —
         # recorded into the process registry (metrics.json / bench extra).
         self.instruments = BackendInstruments("tpu")
+        if self.config.has_layer_kinds:
+            itemsize = jnp.dtype(jax_dtype).itemsize
+            for name, n, heads in self.config.cache_kinds:
+                self.instruments.record_kv_bytes_per_token(
+                    name, n * heads * itemsize
+                    * (self.config.head_dim + self.config.value_dim))
+            self.instruments.record_kv_bytes_per_token(
+                "all", self.config.kv_bytes_per_token(itemsize))
         self.call_counts = {
             "generate": 0, "score": 0, "next_token": 0, "embed": 0,
             "score_matrix": 0,
@@ -476,7 +504,19 @@ class TPUBackend:
             # Pages alone, or pages that mean nothing without the recurrent
             # state at their end: the two kinds of model never share a run.
             ("state", "recurrent" if self.config.has_ssm else "pages"),
-        )
+        ) + ((
+            # A pool a kind of attention, each at its own heads and widths:
+            # (kind, layers, key-value heads, key width, value width).
+            ("kinds", tuple(
+                (name, n, heads, self.config.head_dim, self.config.value_dim)
+                for name, n, heads in self.config.cache_kinds)),
+        ) if self.config.has_layer_kinds else ())
+
+    @property
+    def has_layer_kinds(self) -> bool:
+        """The configuration has layers of more than one kind: a cache a
+        kind of attention, which one pool of one shape is not."""
+        return self.config.has_layer_kinds
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -497,7 +537,7 @@ class TPUBackend:
             else jnp.dtype(self.params["embed"].dtype).itemsize
         )
         bytes_per_token = int(
-            2 * c.n_layers * c.n_kv_heads * c.head_dim * kv_itemsize
+            c.kv_bytes_per_token(kv_itemsize)
         ) // self._shard_count or 1
         return bytes_per_token * page_size
 
@@ -726,6 +766,9 @@ class TPUBackend:
                 "generate_stream (the engine's decode_steps)",
                 STREAM_NEEDS_STATE,
             )
+        if self.config.has_layer_kinds:
+            raise LayerKindsUnsupported(
+                "generate_stream (the engine's decode_steps)", NEEDS_ONE_KIND)
         return _PagedGenerateStream(
             self, list(requests), decode_steps, speculative=speculative
         )
@@ -805,9 +848,7 @@ class TPUBackend:
         copies (old and new value of the carry live together)."""
         c = self.config
         itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
-        unit = (
-            2 * c.n_layers * c.n_kv_heads * c.head_dim * itemsize
-        ) // self._shard_count
+        unit = int(c.kv_bytes_per_token(itemsize)) // self._shard_count
         # A recurrent layer's state rides the loop's carry beside the tail:
         # two copies a row, whatever the prompt's length (0 bytes dense).
         per_row = (
@@ -1107,6 +1148,16 @@ class TPUBackend:
             out = fn(self.params, self.config, tokens, valid, keys, **kwargs)
         return self._finish_generation(requests, out, rows=target, max_new=max_new)
 
+    def _record_moe(self, tally) -> None:
+        """A program's tally of its routed layers (``transformer.MOE_TALLY``;
+        None without routed experts) into the backend's counters."""
+        if tally is None:
+            return
+        held, rows, passes = (int(n) for n in np.asarray(tally))
+        self.instruments.record_moe(
+            held, rows * self.config.experts_per_token,
+            passes * self.config.experts_held[1])
+
     def _finish_generation(
         self,
         requests: Sequence[GenerationRequest],
@@ -1119,6 +1170,7 @@ class TPUBackend:
         generated, counts, hit_eos = self._fetch(
             out.tokens, out.num_generated, out.hit_eos
         )
+        self._record_moe(out.moe_held)
         # Decode-grid padding efficiency from the tokens actually emitted:
         # EOS early exits and bucket-pad rows both show up as empty slots.
         self.instruments.record_padding(
@@ -1539,15 +1591,13 @@ class TPUBackend:
                     max_q = max(max_q, q_len)
                     max_private = max(max_private, n_private)
                     max_blocks = max(max_blocks, npg + n_private)
+            max_blocks = self._table_blocks(max_blocks)
 
             # Chunk the row batch under the live-session HBM budget: pow2 row
             # buckets so the compiled-variant space stays small, halved until
             # the page pool (shared + per-row private + sink) fits.
             dtype = jnp.dtype(self.params["embed"].dtype)
-            page_bytes = (
-                self.config.n_layers * ps * self.config.n_kv_heads
-                * self.config.head_dim * dtype.itemsize * 2
-            )
+            page_bytes = int(self.config.kv_bytes_per_token(dtype.itemsize)) * ps
 
             # With recurrent layers the pool holds, beside the pages, one
             # snapshot a unique context (at its page boundary) and every
@@ -1558,21 +1608,28 @@ class TPUBackend:
                 _bucket(len(prefix_ids), minimum=8) if state_bytes else 0
             )
 
+            def pool_pages(n_rows: int) -> int:
+                pages = shared_total + n_rows * max_private
+                if self.config.has_layer_kinds:
+                    pages += -pages % _KINDS_POOL_STEP_PAGES
+                return pages
+
             def pool_bytes(n_rows: int) -> int:
                 return (
-                    (shared_total + n_rows * max_private + 1) * page_bytes
+                    (pool_pages(n_rows) + 1) * page_bytes
                     + (snapshot_rows + 2 * n_rows) * state_bytes
                 )
 
             width = _bucket(max_q, minimum=ps)
 
             def fits_beside_weights(n_rows: int) -> bool:
-                """With recurrent layers alone: the chunk's own temporaries
+                """With recurrent layers or layers of more than one kind
+                alone: the chunk's own temporaries
                 (``_score_chunk_transient_bytes``) and its pool fit what the
                 weights leave.  Such a model is large beside its cache, and
                 the 3 GiB the budget leaves for temporaries is not what a
                 wide chunk of it takes."""
-                if not state_bytes:
+                if not state_bytes and not self.config.has_layer_kinds:
                     return True
                 return pool_bytes(n_rows) + self._score_chunk_transient_bytes(
                     n_rows, width, max_blocks * ps
@@ -1592,7 +1649,7 @@ class TPUBackend:
             if pool_bytes(chunk_rows) > budget:
                 return None  # even one row over-commits; per-call path chunks finer
             chunk_rows = max(chunk_rows, self._dp)
-            num_pages = shared_total + chunk_rows * max_private
+            num_pages = pool_pages(chunk_rows)
             sink = num_pages
 
         nbytes = pool_bytes(chunk_rows)
@@ -1626,6 +1683,7 @@ class TPUBackend:
             fetched = self._fetch(
                 *([utilities, welfare_vals] + ([aux] if aux is not None else []))
             )
+            self._record_moe(state.moe_held)
         finally:
             self._session_budget.release(nbytes)
         utilities_np, welfare_np = fetched[0], fetched[1]
@@ -1643,6 +1701,14 @@ class TPUBackend:
             d2h_bytes=d2h,
             path="fused",
         )
+
+    def _table_blocks(self, blocks: int) -> int:
+        """Blocks a score matrix's tables name a row: the most a row needs,
+        in steps with layers of more than one kind
+        (``_KINDS_TABLE_STEP_BLOCKS``)."""
+        if self.config.has_layer_kinds:
+            blocks += -blocks % _KINDS_TABLE_STEP_BLOCKS
+        return blocks
 
     def _score_chunk_transient_bytes(
         self, n_rows: int, width: int, keys: int
@@ -1675,6 +1741,23 @@ class TPUBackend:
         )
         tile = min(score_vocab_tile(cells), c.vocab_size)
         head = cells * tile * 4 + (cells + tile) * c.d_model * itemsize
+        if c.has_layer_kinds:
+            # The keys and values gathered through the tables, at the kind
+            # with the most heads; and a routed layer's block of rows: every
+            # assignment's row gathered and returned, its gate, up and their
+            # product, and the float32 rows the weighted sum reads.
+            from consensus_tpu.models.transformer import _MOE_BLOCK_ROWS
+
+            attention += n_rows * keys * itemsize * (
+                max(kv for _, _, kv in c.cache_kinds)
+                * (c.head_dim + c.value_dim))
+            if c.swa_sink:  # the shares beside the sink's, float32 too
+                attention += cells * c.n_heads * keys * 4
+            if c.has_moe:
+                sent = min(cells, _MOE_BLOCK_ROWS) * c.experts_per_token
+                ffn = max(ffn, sent * (
+                    (2 * c.d_model + 3 * c.expert_hidden) * itemsize
+                    + 4 * c.d_model))
         return attention + ffn + mixer + head
 
     def _prefill_shared_pages(self, state, prefix_ids, shared, sink, mesh):
@@ -1696,7 +1779,7 @@ class TPUBackend:
         n_rows = _bucket(len(pre), minimum=8)
         max_n0 = max(shared[p][2] for p in pre)
         chunk = min(256, _bucket(max_n0, minimum=ps))
-        n_blocks = max(shared[p][1] for p in pre)
+        n_blocks = self._table_blocks(max(shared[p][1] for p in pre))
         tables = np.full((n_rows, n_blocks), -1, np.int32)
         for r, p in enumerate(pre):
             first, npg, _ = shared[p]
@@ -1923,23 +2006,64 @@ class TPUBackend:
                 "search_prefill, search_step, the rollouts)",
                 SEARCH_NEEDS_STATE,
             )
+        if self.config.has_layer_kinds:
+            raise LayerKindsUnsupported(
+                "a token-search session (open_fused_token_search, "
+                "search_prefill, search_step, the rollouts)",
+                NEEDS_ONE_KIND,
+            )
         return TPUTokenSearchSession(self, spec)
 
     # -- embeddings ------------------------------------------------------------
 
     def embed(self, texts: Sequence[str]) -> np.ndarray:
+        limit = self._embed_rows_allowed(texts)
         pieces = [
-            self._embed_impl(texts[i : i + self.max_batch_rows])
-            for i in range(0, len(texts), self.max_batch_rows)
+            self._embed_impl(texts[i : i + limit], rows=limit)
+            for i in range(0, len(texts), limit)
         ] or [np.zeros((0, self.config.d_model), np.float32)]
         return np.vstack(pieces)
 
-    def _embed_impl(self, texts: Sequence[str]) -> np.ndarray:
+    def _dense_attention_bytes(self, rows: int, width: int, keys: int) -> int:
+        """What the einsum attention of one layer holds at once for ``rows``
+        x ``width`` queries over ``keys`` keys: float32 logits and their
+        weights in the activations' type, for the query heads it takes at a
+        time.  That is all of them, but with layers of more than one kind:
+        ``forward`` then attends a key-value group at a time, and the widest
+        group counts."""
+        c = self.config
+        itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
+        heads = c.n_heads
+        if c.has_layer_kinds:
+            heads = c.n_heads // min(kv for _, _, kv in c.cache_kinds)
+        return rows * width * heads * keys * (4 + itemsize)
+
+    def _embed_rows_allowed(self, texts: Sequence[str]) -> int:
+        """Rows one embedding batch takes.  ``max_batch_rows``, as ever, but
+        with layers of more than one kind: their many query heads make the
+        batch's attention logits the larger part of what the weights leave
+        (32 rows x 1,536 of 64 heads: 19 GB), so the rows halve, down the
+        powers of two, until the logits fit the reserve for temporaries."""
+        if not self.config.has_layer_kinds or not texts:
+            return self.max_batch_rows
+        width = _width_bucket(
+            min(max(len(t.encode("utf-8")) for t in texts) + 1, self.max_context))
+        rows = _bucket(min(len(texts), self.max_batch_rows), minimum=1)
+        while rows > 1 and self._dense_attention_bytes(
+                rows, width, width) > _ACTIVATION_RESERVE_BYTES:
+            rows //= 2
+        return rows
+
+    def _embed_impl(
+        self, texts: Sequence[str], rows: Optional[int] = None
+    ) -> np.ndarray:
         self.call_counts["embed"] += len(texts)
         with span("backend.tokenize", rows=len(texts)):
             token_lists = [self.tokenizer.encode(t, add_bos=True) for t in texts]
         with span("backend.layout", rows=len(texts)):
-            pad_rows = _bucket(len(texts), minimum=8) - len(texts)
+            # (A batch held to fewer than 8 rows is padded to those rows.)
+            pad_rows = _bucket(
+                len(texts), minimum=min(8, rows or 8)) - len(texts)
             token_lists += [[]] * pad_rows
             tokens, valid = self._left_pad_batch(token_lists)
             width = int(tokens.shape[1])

@@ -25,8 +25,18 @@ MODEL_PHASES = ("prefill", "decode_step")
 #: or one-step recurrence, the skip term) and ``ssm_out`` (gate, grouped
 #: norm, output product); ``state_fork`` is a program copying one recurrent
 #: state to many rows (``transformer.fork_ssm``).
+#: A configuration with layers of more than one kind names a window layer's
+#: attention ``attention_window`` (a full layer's stays ``attention``), and
+#: its routed feed-forward (``transformer.moe_block``) ``moe_router`` (norm,
+#: the router's float32 product, sigmoid, selection, weights),
+#: ``moe_dispatch`` (the rows' assignments sorted and gathered by expert, or
+#: the mask of a decode step's few rows), ``moe_experts`` (the held experts'
+#: three products) and ``moe_combine`` (each row's weighted sum of what its
+#: assignments returned, and the residual).
 MODEL_SCOPES = (
     "embed", "layers", "attn_qkv", "kv_write", "attention", "attn_out", "ffn",
     "final_norm", "vocab_projection", "logsumexp", "sample",
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "state_fork",
+    "attention_window", "moe_router", "moe_dispatch", "moe_experts",
+    "moe_combine",
 ) + MODEL_PHASES

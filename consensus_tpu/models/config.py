@@ -9,19 +9,34 @@ families for local TPU execution, plus tiny variants for tests.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 
-class RecurrentStateUnsupported(ValueError):
-    """A program or method that cannot carry a recurrent layer's state was
-    asked to run a configuration that has one.  ``what`` names it.  A
-    ``ValueError``: asking again cannot succeed, so the scheduler does not
-    retry, and the service answers a client error with this message."""
+class ConfigurationUnsupported(ValueError):
+    """A program or method was asked to run a configuration it cannot run.
+    ``what`` names it.  A ``ValueError``: asking again cannot succeed, so
+    the scheduler does not retry, and the service answers a client error
+    with this message."""
+
+    #: What of the configuration the program cannot run.
+    HAS = "this configuration"
 
     def __init__(self, what: str, why: str):
-        super().__init__(
-            f"{what} does not run a configuration with recurrent layers: {why}")
+        super().__init__(f"{what} does not run {self.HAS}: {why}")
         self.what = what
+
+
+class RecurrentStateUnsupported(ConfigurationUnsupported):
+    """The program cannot carry a recurrent layer's state."""
+
+    HAS = "a configuration with recurrent layers"
+
+
+class LayerKindsUnsupported(ConfigurationUnsupported):
+    """The program holds one cache for every layer and one stack of layer
+    weights: it cannot run layers of more than one kind."""
+
+    HAS = "a configuration with layers of more than one kind"
 
 
 #: Why the two families of programs that cannot carry the state refuse.
@@ -29,6 +44,35 @@ SEARCH_NEEDS_STATE = ("beams reorder and rollouts roll back rows, which needs "
                       "a gather and a checkpoint of the recurrent state")
 STREAM_NEEDS_STATE = ("the stream path's slots hold pages by position and no "
                       "recurrent state by row")
+#: Why the same programs, the prefix cache, the Pallas attention kernels, the
+#: int8 paths and a tensor-parallel mesh refuse layers of more than one kind.
+NEEDS_ONE_KIND = ("it holds one cache of one shape for every layer, and this "
+                  "configuration keeps a cache for each kind of attention")
+KERNEL_NEEDS_PLAIN_HEADS = ("the Pallas attention kernels take no sink logit "
+                            "and no value heads narrower than the key heads")
+
+
+class LayerKind(NamedTuple):
+    """What one run of equal layers is, as static data of the layer loop."""
+
+    name: str  # the key of the kind's stack of weights under ``layers``
+    attention: str  # "full" or "window": the cache the layer addresses
+    kv_heads: int
+    rope_theta: float
+    window: Optional[int]  # keys a query sees at most; None = all before it
+    sink: bool  # a learned logit a head in the softmax's denominator
+    routed: bool  # routed experts in the feed-forward's place
+
+
+class LayerRun(NamedTuple):
+    """Consecutive layers of one kind: ``count`` of them, from ``at`` of the
+    kind's stack of weights and from ``cache_at`` of its attention kind's
+    cache."""
+
+    kind: LayerKind
+    at: int
+    cache_at: int
+    count: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,8 +153,64 @@ class ModelConfig:
     # (on the gate product, inside the activation; on the down product).
     mlp_multipliers: Optional[Tuple[float, float]] = None
     lm_head_multiplier: Optional[float] = None
+    # -- layers of more than one kind (MiMo-V2-Flash) -------------------------
+    # One entry a layer: 0 full attention, 1 window attention.  Empty: every
+    # layer is of one kind, as before (``local_layer_pattern``'s flag picks a
+    # mask and nothing else).  Not empty: each kind has its own stack of
+    # weights and each kind of attention its own cache; the layer loop runs
+    # the runs of equal layers one after the other (``layer_runs``).
+    hybrid_layer_pattern: Tuple[int, ...] = ()
+    # One entry a layer: 0 the dense feed-forward, 1 routed experts.
+    moe_layer_freq: Tuple[int, ...] = ()
+    # A window layer's key-value heads and rotary base; None: as full ones.
+    swa_kv_heads: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    # A learned logit a query head in every softmax of a window layer: it
+    # takes mass and adds no value.
+    swa_sink: bool = False
+    # Width of a value head where it is not the key heads' (``head_dim``).
+    v_head_dim: Optional[int] = None
+    # Leading dimensions of a head that the rotary embedding turns; the rest
+    # pass.  None: all of them.
+    rotary_dim: Optional[int] = None
+    # The value states are multiplied by this.
+    value_scale: Optional[float] = None
+    # Routed experts: the router's width (every expert of the model), how
+    # many a token is sent to, an expert's hidden width, and which experts
+    # this program holds, (first, count): it routes over all ``n_experts``
+    # and computes the part of the result that its own experts give.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    expert_hidden: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
+        if self.hybrid_layer_pattern:
+            if len(self.hybrid_layer_pattern) != self.n_layers or (
+                    self.moe_layer_freq
+                    and len(self.moe_layer_freq) != self.n_layers):
+                raise ValueError(
+                    "hybrid_layer_pattern and moe_layer_freq have one entry "
+                    f"a layer ({self.n_layers})")
+            if any(self.hybrid_layer_pattern) and self.sliding_window is None:
+                raise ValueError("a window layer needs sliding_window")
+            if (self.has_ssm or any(self.local_layer_pattern)
+                    or self.use_post_norms):
+                raise ValueError(
+                    "layers of more than one kind come with no recurrent "
+                    "mixer, no local_layer_pattern and no post-norms")
+        elif self.moe_layer_freq or self.swa_sink or self.swa_kv_heads:
+            raise ValueError(
+                "moe_layer_freq and the swa_* keys need hybrid_layer_pattern")
+        if any(self.moe_layer_freq):
+            first, count = self.experts_held or (0, 0)
+            if not (0 < self.experts_per_token <= self.n_experts
+                    and self.expert_hidden > 0 and count > 0
+                    and 0 <= first and first + count <= self.n_experts):
+                raise ValueError(
+                    "routed layers need n_experts, experts_per_token, "
+                    "expert_hidden and experts_held = (first, count) inside "
+                    "the router's width")
         if self.ssm_heads and self.ssm_inner != self.ssm_heads * self.ssm_head_dim:
             raise ValueError(
                 f"ssm_inner={self.ssm_inner} is not ssm_heads x ssm_head_dim "
@@ -150,6 +250,84 @@ class ModelConfig:
         h = 4 * self.ssm_heads * self.ssm_head_dim * self.ssm_state
         conv = itemsize * (self.ssm_conv - 1) * self.ssm_conv_dim
         return self.n_layers * (h + conv)
+
+    @property
+    def has_layer_kinds(self) -> bool:
+        """Layers of more than one kind: a stack of weights a kind, a cache
+        a kind of attention."""
+        return bool(self.hybrid_layer_pattern)
+
+    @property
+    def has_moe(self) -> bool:
+        return any(self.moe_layer_freq)
+
+    @property
+    def value_dim(self) -> int:
+        """Width of a value head."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def layer_kinds(self) -> Tuple[LayerKind, ...]:
+        """Each layer's kind, for a configuration with ``has_layer_kinds``."""
+        routed = self.moe_layer_freq or (0,) * self.n_layers
+        kinds = []
+        for window, moe in zip(self.hybrid_layer_pattern, routed):
+            attention = "window" if window else "full"
+            kinds.append(LayerKind(
+                name=f"{attention}_{'moe' if moe else 'dense'}",
+                attention=attention,
+                kv_heads=(self.swa_kv_heads or self.n_kv_heads) if window
+                else self.n_kv_heads,
+                rope_theta=float(
+                    (self.swa_rope_theta or self.rope_theta) if window
+                    else self.rope_theta),
+                window=self.sliding_window if window else None,
+                sink=bool(window and self.swa_sink),
+                routed=bool(moe),
+            ))
+        return tuple(kinds)
+
+    @property
+    def layer_runs(self) -> Tuple[LayerRun, ...]:
+        """The runs of equal layers, in the model's order."""
+        runs, stacked, cached = [], {}, {}
+        for kind in self.layer_kinds:
+            if runs and runs[-1].kind == kind:
+                runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+            else:
+                runs.append(LayerRun(kind, stacked.get(kind.name, 0),
+                                     cached.get(kind.attention, 0), 1))
+            stacked[kind.name] = stacked.get(kind.name, 0) + 1
+            cached[kind.attention] = cached.get(kind.attention, 0) + 1
+        return tuple(runs)
+
+    @property
+    def kind_layers(self) -> Tuple[Tuple[LayerKind, int], ...]:
+        """(kind, how many layers are of it), in order of first appearance."""
+        counts = {}
+        for kind in self.layer_kinds:
+            counts[kind] = counts.get(kind, 0) + 1
+        return tuple(counts.items())
+
+    @property
+    def cache_kinds(self) -> Tuple[Tuple[str, int, int], ...]:
+        """(kind of attention, its layers, its key-value heads), in order of
+        first appearance: the caches a configuration with ``has_layer_kinds``
+        holds.  Without: one cache, named ``None``."""
+        if not self.has_layer_kinds:
+            return ((None, self.n_layers, self.n_kv_heads),)
+        layers, heads = {}, {}
+        for kind in self.layer_kinds:
+            layers[kind.attention] = layers.get(kind.attention, 0) + 1
+            heads[kind.attention] = kind.kv_heads
+        return tuple((name, n, heads[name]) for name, n in layers.items())
+
+    def kv_bytes_per_token(self, itemsize: float) -> float:
+        """Bytes of one position's keys and values over all layers: the sum
+        over the kinds of attention, each at its own heads and widths."""
+        return sum(
+            n * heads * (self.head_dim + self.value_dim) * itemsize
+            for _, n, heads in self.cache_kinds)
 
     @property
     def q_scale(self) -> float:
@@ -295,6 +473,36 @@ MODEL_CONFIGS = {
         ssm_out_multiplier=0.09,
         mlp_multipliers=(0.18, 0.011),
         lm_head_multiplier=0.0078,
+    ),
+    # MiMo-V2-Flash's layers at a test's size: three kinds in seven layers
+    # (full attention + a dense feed-forward; window attention with its own
+    # key-value heads, sinks and routed experts; full attention + routed
+    # experts), key heads wider than value heads, rotary on a third of a
+    # head, 8 experts a token of 32 with 8 held.  The published sizes live in
+    # benchmark/configs/.
+    "tiny-mimo-v2": _llama3(
+        "tiny-mimo-v2",
+        vocab_size=320,
+        d_model=64,
+        n_layers=7,
+        n_heads=8,
+        n_kv_heads=2,
+        head_dim=24,
+        ffn_hidden=128,
+        rope_theta=5e6,
+        sliding_window=8,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+        moe_layer_freq=(0, 1, 1, 1, 1, 1, 1),
+        swa_kv_heads=4,
+        swa_rope_theta=1e4,
+        swa_sink=True,
+        v_head_dim=16,
+        rotary_dim=8,
+        value_scale=0.707,
+        n_experts=32,
+        experts_per_token=8,
+        expert_hidden=32,
+        experts_held=(8, 8),
     ),
 }
 

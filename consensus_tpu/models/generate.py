@@ -20,13 +20,19 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from consensus_tpu.models.config import ModelConfig
+from consensus_tpu.models.config import (
+    NEEDS_ONE_KIND,
+    LayerKindsUnsupported,
+    ModelConfig,
+)
 from consensus_tpu.models.sampling import ban_undecodable, sample_tokens
 from consensus_tpu.models.transformer import (
+    MOE_TALLY,
     KVCache,
     fork_ssm,
     forward,
     forward_trunk_tail,
+    kv_buffers,
     make_cache,
     project_logits,
 )
@@ -47,9 +53,13 @@ class GenerateOutput(NamedTuple):
     tokens: jax.Array  # (B, max_new_tokens) int32; pad_id after EOS
     num_generated: jax.Array  # (B,) int32 — tokens before (excluding) EOS
     hit_eos: jax.Array  # (B,) bool
+    #: With routed experts: the decode steps' tally (int32
+    #: ``transformer.MOE_TALLY``); None without.
+    moe_held: Optional[jax.Array] = None
 
 
-def _assemble_output(tokens_buf, emitted_buf, max_new_tokens, pad_id):
+def _assemble_output(tokens_buf, emitted_buf, max_new_tokens, pad_id,
+                     moe_held=None):
     """(T, B) step buffers -> GenerateOutput (works traced or concrete)."""
     tokens = tokens_buf.T  # (B, T)
     emitted = emitted_buf.T
@@ -57,7 +67,8 @@ def _assemble_output(tokens_buf, emitted_buf, max_new_tokens, pad_id):
     hit_eos = num_generated < max_new_tokens
     tokens = jnp.where(emitted, tokens, pad_id)
     return GenerateOutput(
-        tokens=tokens, num_generated=num_generated, hit_eos=hit_eos
+        tokens=tokens, num_generated=num_generated, hit_eos=hit_eos,
+        moe_held=moe_held,
     )
 
 
@@ -147,7 +158,7 @@ def generate_tokens(
         if rep_penalty is not None
         else None
     )
-    tokens_buf, emitted_buf, *_ = _decode_segment(
+    tokens_buf, emitted_buf, *_, moe_held = _decode_segment(
         params, config, trunk, None, None, cur_pos,
         jnp.asarray(0, jnp.int32), next_logits, key, init_done,
         n_slots=1, n_roles=batch, seg_len=max_new_tokens,
@@ -155,7 +166,8 @@ def generate_tokens(
         logit_bias=logit_bias, bias_table=bias_table, bias_index=bias_index,
         pad_id=pad_id, presence=presence, rep_penalty=rep_penalty,
     )
-    return _assemble_output(tokens_buf, emitted_buf, max_new_tokens, pad_id)
+    return _assemble_output(
+        tokens_buf, emitted_buf, max_new_tokens, pad_id, moe_held)
 
 
 @functools.partial(
@@ -217,7 +229,7 @@ def generate_tokens_shared_trunk(
         if rep_penalty is not None
         else None
     )
-    tokens_buf, emitted_buf, *_ = _decode_segment(
+    tokens_buf, emitted_buf, *_, moe_held = _decode_segment(
         params, config, trunk, None, None, cur_pos,
         jnp.asarray(0, jnp.int32), next_logits, key, init_done,
         n_slots=batch, n_roles=1, seg_len=max_new_tokens,
@@ -225,7 +237,8 @@ def generate_tokens_shared_trunk(
         bias_table=bias_table, bias_index=bias_index, pad_id=pad_id,
         presence=presence, rep_penalty=rep_penalty,
     )
-    return _assemble_output(tokens_buf, emitted_buf, max_new_tokens, pad_id)
+    return _assemble_output(
+        tokens_buf, emitted_buf, max_new_tokens, pad_id, moe_held)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -281,6 +294,7 @@ def _decode_segment(
     presence: Optional[jax.Array] = None,  # (B, V) bool seen-token mask
     rep_penalty: Optional[jax.Array] = None,  # (B,) float32
     ssm=None,  # (L, B, ...) recurrent state a later segment starts from
+    moe_held=None,  # transformer.MOE_TALLY, which a later segment adds to
 ):
     """One ``seg_len``-step slice of a decode, B = n_slots * n_roles rows.
 
@@ -314,9 +328,19 @@ def _decode_segment(
     row for row in the classic layout, one row forked to all B in the shared
     one (there is no broadcasting a state that every row then changes).  A
     later segment is handed ``ssm``, what the one before returned last.
+
+    With layers of more than one kind the trunk's K/V, the tail and every
+    frozen block are dictionaries by kind of attention (``kv_buffers``), and
+    with routed experts the loop carries the routed layers' tally last
+    (``transformer.MOE_TALLY``), which a later segment is handed as
+    ``moe_held`` and every segment returns tenth.
     """
     c = config
     batch = n_slots * n_roles
+    if c.has_layer_kinds and quantize_tail:
+        raise LayerKindsUnsupported("an int8 key-value tail", NEEDS_ONE_KIND)
+    if c.has_moe and moe_held is None:
+        moe_held = jnp.zeros((len(MOE_TALLY),), jnp.int32)
     if c.has_ssm and ssm is None:
         ssm = trunk.ssm if n_roles == batch else fork_ssm(trunk.ssm, batch)
     if eos_ids is None:
@@ -330,6 +354,8 @@ def _decode_segment(
     frozen_positions = []
     offset = 0
     for block in frozen_k:
+        if isinstance(block, dict):  # by kind of attention: alike in width
+            block = next(iter(block.values()))
         width = (block[0] if isinstance(block, tuple) else block).shape[2]
         frozen_positions.append(
             base_pos[:, None] + 1 + offset + jnp.arange(width)[None, :]
@@ -347,8 +373,7 @@ def _decode_segment(
             jnp.zeros(tail_shape, jnp.int8), jnp.zeros(scale_shape, jnp.float32)
         )
     else:
-        tail_k = jnp.zeros(tail_shape, params["embed"].dtype)
-        tail_v = jnp.zeros(tail_shape, params["embed"].dtype)
+        tail_k, tail_v = kv_buffers(c, (batch, seg_len), params["embed"].dtype)
 
     def is_eos(token: jax.Array) -> jax.Array:
         if eos_ids.shape[0] == 0:
@@ -371,6 +396,7 @@ def _decode_segment(
          emitted_buf) = carry[:9]
         pres = carry[9] if use_rp else None
         state = carry[-1] if c.has_ssm else None
+        held = carry[-1] if c.has_moe else None
         if key.ndim == 2:  # per-row keys: rows draw independently
             pairs = jax.vmap(jax.random.split)(key)
             key, sub = pairs[:, 0], pairs[:, 1]
@@ -391,12 +417,12 @@ def _decode_segment(
         new_done = done | token_is_eos
 
         pos = cur_pos + 1
-        hidden, tail_k, tail_v, state = forward_trunk_tail(
+        hidden, tail_k, tail_v, state, *step_held = forward_trunk_tail(
             params, config, token, pos, trunk, tail_k, tail_v,
             tail_positions, i, n_slots, n_roles,
             frozen_k=frozen_k, frozen_v=frozen_v,
             frozen_positions=tuple(frozen_positions),
-            ssm=state,
+            ssm=state, moe_held=held,
         )
         logits = project_logits(params, config, hidden)
         tokens_buf = jax.lax.dynamic_update_slice(tokens_buf, token[None], (i, 0))
@@ -408,12 +434,14 @@ def _decode_segment(
             tokens_buf, emitted_buf,
         )
         return out + ((pres,) if use_rp else ()) + (
-            (state,) if c.has_ssm else ())
+            (state,) if c.has_ssm else ()) + (
+            tuple(step_held))
 
     init = (
         jnp.asarray(0, jnp.int32), next_logits, tail_k, tail_v,
         done, keys, cur_pos, tokens_buf, emitted_buf,
-    ) + ((presence,) if use_rp else ()) + ((ssm,) if c.has_ssm else ())
+    ) + ((presence,) if use_rp else ()) + ((ssm,) if c.has_ssm else ()) + (
+        (moe_held,) if c.has_moe else ())
     with jax.named_scope("decode_step"):
         final = jax.lax.while_loop(cond, body, init)
     (_, next_logits, tail_k, tail_v, done, keys, _, tokens_buf, emitted_buf) = final[:9]
@@ -421,6 +449,7 @@ def _decode_segment(
     return (
         tokens_buf, emitted_buf, next_logits, tail_k, tail_v, done, keys,
         presence, final[-1] if c.has_ssm else None,
+        final[-1] if c.has_moe else None,
     )
 
 
@@ -487,12 +516,13 @@ def _segmented_loop(
     frozen_k: list = []
     frozen_v: list = []
     ssm = None  # the first segment takes the rows' state from the trunk
+    moe_held = None  # and the first starts the tally of held assignments
     tokens = np.full((orig_batch, max_new_tokens), pad_id, np.int32)
     emitted = np.zeros((orig_batch, max_new_tokens), bool)
     n_segs = max_new_tokens // seg_len
     for seg in range(n_segs):
         (tokens_buf, emitted_buf, next_logits, tail_k, tail_v, done, keys,
-         presence, ssm) = (
+         presence, ssm, moe_held) = (
             _decode_segment(
                 params, config, trunk, tuple(frozen_k), tuple(frozen_v),
                 base_pos, jnp.asarray(seg * seg_len, jnp.int32),
@@ -506,6 +536,7 @@ def _segmented_loop(
                 bias_table=bias_table, bias_index=bias_index, pad_id=pad_id,
                 quantize_tail=kv_quant,
                 presence=presence, rep_penalty=rep_penalty, ssm=ssm,
+                moe_held=moe_held,
             )
         )
         col = seg * seg_len
@@ -574,7 +605,8 @@ def _segmented_loop(
     # tests) immediately np.asarray()s the fields — shipping them back to
     # the device would be a pointless round trip.
     return GenerateOutput(
-        tokens=tokens, num_generated=num_generated, hit_eos=hit_eos
+        tokens=tokens, num_generated=num_generated, hit_eos=hit_eos,
+        moe_held=moe_held,
     )
 
 
@@ -727,6 +759,8 @@ def generate_tokens_segmented(
     next_logits, trunk, last_pos = _prefill_classic(
         params, config, prompt_tokens, prompt_valid
     )
+    if kv_quant and config.has_layer_kinds:
+        raise LayerKindsUnsupported("an int8 key-value trunk", NEEDS_ONE_KIND)
     if kv_quant:
         # The per-row prompt cache is the dominant per-step read of a
         # classic-layout decode (B rows x ctx columns, re-read every step);

@@ -51,6 +51,13 @@ def load_params(
     """Read a local HF checkpoint directory into the runtime pytree."""
     model_dir_path = pathlib.Path(model_dir)
     c = config
+    if c.has_layer_kinds:
+        from consensus_tpu.models.config import LayerKindsUnsupported
+
+        raise LayerKindsUnsupported(
+            "load_params (a HuggingFace checkpoint)",
+            "it fills one stack of leaves for every layer, and no checkpoint "
+            "of such a model is on this machine to hold a mapping to")
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
 
     def blank(*shape):

@@ -44,22 +44,29 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from consensus_tpu.models.config import (
+    NEEDS_ONE_KIND,
     SEARCH_NEEDS_STATE,
     STREAM_NEEDS_STATE,
+    LayerKindsUnsupported,
     ModelConfig,
 )
 from consensus_tpu.models.generate import left_pad_positions
 from consensus_tpu.models.transformer import (
+    MOE_TALLY,
     KVCache,
     RecurrentStateUnsupported,
     SSMState,
     _streamed_target_logprobs,
+    attention_scope,
     embed_tokens,
     final_norm,
     forward,
     forward_shared_trunk,
     forward_trunk_tail,
     fork_ssm,
+    from_kinds,
+    by_kind,
+    kv_buffers,
     layer_of,
     make_cache,
     make_ssm_state,
@@ -79,9 +86,13 @@ from consensus_tpu.ops.welfare import (
 def refuse_recurrent(config: ModelConfig, what: str, why: str) -> None:
     """Programs that reorder, roll back or page rows by position alone have
     nowhere to keep a recurrent layer's state: they say so by name, when
-    they are traced, for a configuration that has one."""
+    they are traced, for a configuration that has one.  The same programs
+    gather and build one cache of one shape for every layer: they refuse a
+    configuration with layers of more than one kind too, by its own error."""
     if config.has_ssm:
         raise RecurrentStateUnsupported(what, why)
+    if config.has_layer_kinds:
+        raise LayerKindsUnsupported(what, NEEDS_ONE_KIND)
 
 
 class SearchState(NamedTuple):
@@ -680,9 +691,15 @@ class PagedSlotState(NamedTuple):
     from chunk to chunk here; the score chunk reads the same table as its
     rows' snapshots (``ssm_rows``) and hands it back untouched."""
 
+    #: With layers of more than one kind, a pool a kind of attention:
+    #: ``{"full": (its layers, pages + 1, page, its KV, hd), "window": ...}``
+    #: (values at the value heads' width), one table of page ids for all.
     k_pages: jax.Array
     v_pages: jax.Array
     ssm: Optional[SSMState] = None
+    #: With routed experts: the routed layers' tally over the programs run
+    #: on this state so far (int32 ``transformer.MOE_TALLY``).
+    moe_held: Optional[jax.Array] = None
 
 
 def _constrain(x: jax.Array, mesh: Optional[Mesh], *axes) -> jax.Array:
@@ -713,10 +730,9 @@ def _constrain_state(
     never by a device axis."""
     if mesh is None:
         return state
-    return PagedSlotState(
-        _constrain(state.k_pages, mesh, None, None, None, "model", None),
-        _constrain(state.v_pages, mesh, None, None, None, "model", None),
-        state.ssm,
+    return state._replace(
+        k_pages=_constrain(state.k_pages, mesh, None, None, None, "model", None),
+        v_pages=_constrain(state.v_pages, mesh, None, None, None, "model", None),
     )
 
 
@@ -732,12 +748,14 @@ def make_page_state(
     (one a context the pool's prefill will run); a configuration without
     recurrent layers holds none whatever is asked."""
     c = config
-    shape = (c.n_layers, num_pages + 1, page_size, c.n_kv_heads, c.head_dim)
     state = PagedSlotState(
-        jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+        *kv_buffers(c, (num_pages + 1, page_size), dtype),
         make_ssm_state(c, ssm_rows, dtype) if ssm_rows else None,
+        jnp.zeros((len(MOE_TALLY),), jnp.int32) if c.has_moe else None,
     )
     if mesh is not None:
+        if c.has_layer_kinds:
+            raise LayerKindsUnsupported("a mesh of several chips", NEEDS_ONE_KIND)
         kv_axis = "model" if c.n_kv_heads % mesh.shape["model"] == 0 else None
         sharding = NamedSharding(
             mesh, PartitionSpec(None, None, None, kv_axis, None)
@@ -777,9 +795,10 @@ def _paged_forward(
     # hd), and a layer addresses its pages by its index: the layer and page
     # axes read as one axis of L * (pages + 1) pages, layer ``l``'s page
     # ``p`` at ``l * (pages + 1) + p``.  One scatter over (layer, page) in
-    # place; no layer's pool is stacked back.
-    pool_shape = state.k_pages.shape
-    pages_a_layer = pool_shape[1]
+    # place; no layer's pool is stacked back.  (With layers of more than one
+    # kind: a pool a kind of attention, each with its own layers leading and
+    # the same pages; a layer's index is its place in its kind's pool.)
+    pages_a_layer = jax.tree.leaves(state.k_pages)[0].shape[1]
     # Rows that share context pages gather each of them many times: more
     # pages gathered than a layer's pool holds.  On a v5e the gather reads
     # them twice as fast from a copy of the layer's pool as from inside the
@@ -789,9 +808,9 @@ def _paged_forward(
     gather_from_a_copy = block_tables.size > pages_a_layer
 
     def as_pages(pool):
-        return pool.reshape((-1,) + pool_shape[2:])
+        return pool.reshape((-1,) + pool.shape[2:])
 
-    def call_paged(window, q, k_pages, v_pages, layer):
+    def call_paged(window, q, k_pages, v_pages, layer, sink=None):
         if gather_from_a_copy:
             pools = layer_of(k_pages, layer), layer_of(v_pages, layer)
             tables = block_tables
@@ -801,9 +820,10 @@ def _paged_forward(
         return paged_attention(
             q, *pools, tables, lengths, positions,
             scale=c.q_scale, softcap=c.attn_softcap, window=window,
+            **({} if sink is None else {"sink": sink}),
         )
 
-    def attend(q, k, v, _, pools, layer, is_local):
+    def attend(q, k, v, _, pools, layer, is_local, sink=None):
         """This call's K/V scattered into the pages the cursors name; every
         query attends through its slot's block table."""
         # Cursor pairs are unique across rows (slots own disjoint pages)
@@ -812,16 +832,22 @@ def _paged_forward(
         with jax.named_scope("kv_write"):
             at = write_pages + layer * pages_a_layer
             k_pages, v_pages = (
-                as_pages(pool).at[at, write_offsets].set(new).reshape(pool_shape)
+                as_pages(pool).at[at, write_offsets].set(new).reshape(pool.shape)
                 for pool, new in zip(pools, (k, v)))
-        with jax.named_scope("attention"):
-            attn = windowed(c, is_local, call_paged, q, k_pages, v_pages, layer)
+        with attention_scope(is_local):
+            attn = windowed(
+                c, is_local, call_paged, q, k_pages, v_pages, layer,
+                *(() if sink is None else (sink,)))
         return attn, None, (k_pages, v_pages)
 
-    x, _, (new_k, new_v), new_ssm = scan_layers(
+    x, held, written, new_ssm = scan_layers(
         params, c, x, positions, attend, None,
-        (state.k_pages, state.v_pages), ssm if c.has_ssm else None, valid)
-    return final_norm(params, c, x), PagedSlotState(new_k, new_v, new_ssm)
+        by_kind(c, state.k_pages, state.v_pages),
+        ssm if c.has_ssm else None, valid)
+    new_k, new_v = from_kinds(c, written)
+    moe_held = None if state.moe_held is None else state.moe_held + held
+    return final_norm(params, c, x), PagedSlotState(
+        new_k, new_v, new_ssm, moe_held)
 
 
 @functools.partial(

@@ -28,7 +28,14 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from consensus_tpu.models.config import ModelConfig, RecurrentStateUnsupported
+from consensus_tpu.models.config import (
+    KERNEL_NEEDS_PLAIN_HEADS,
+    NEEDS_ONE_KIND,
+    LayerKind,
+    LayerKindsUnsupported,
+    ModelConfig,
+    RecurrentStateUnsupported,
+)
 from consensus_tpu.models.quant import (
     gather_target_logits,
     head_matmul,
@@ -36,6 +43,7 @@ from consensus_tpu.models.quant import (
     slice_rows,
     take_rows,
 )
+from consensus_tpu.ops.decode_attention import softmax_with_sink
 
 Params = Dict[str, Any]
 
@@ -68,7 +76,7 @@ def init_params(
     h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
     a_in = c.attention_in_multiplier
     mlp = c.mlp_multipliers or (None, None)
-    layers = {
+    layers = _init_kind_params(c, key, dtype) if c.has_layer_kinds else {
         "attn_norm": jnp.zeros((c.n_layers, c.d_model), dtype)
         if c.rmsnorm_style == "gemma"
         else jnp.ones((c.n_layers, c.d_model), dtype),
@@ -125,6 +133,69 @@ def init_params(
 #: ``fold_in`` data of the mixer's leaves, beside the eight keys that
 #: ``init_params`` splits (a split's keys are no fold of its parent).
 _SSM_KEY_BASE = 100
+#: ``fold_in`` data of a kind's key, for a configuration with layers of more
+#: than one kind: kind ``i`` (in order of first appearance) draws its leaves
+#: from the twelve keys that ``fold_in(key, 200 + i)`` splits into.
+_KIND_KEY_BASE = 200
+
+
+def _init_kind_params(c: ModelConfig, key: jax.Array, dtype) -> Params:
+    """A stack of leaves a kind of layer, ``{kind's name: {leaf: (layers of
+    the kind, ...)}}``: every matrix a normal draw at fan-in scale, so that a
+    branch and the logits come out of unit order.  A window kind's sinks are
+    unit normal draws and a routed kind's selection bias a normal draw at
+    0.1, both float32 like the router, so that a sink takes a share of a
+    softmax and the bias decides the selection near a tie.  Expert ``e``'s
+    matrices are drawn from ``fold_in(leaf's key, e)`` with ``e`` counted over
+    the whole router: the experts held here are the same numbers whichever
+    share of them a program holds."""
+    d, h, hd, vd = c.d_model, c.n_heads, c.head_dim, c.value_dim
+    norm_init = jnp.zeros if c.rmsnorm_style == "gemma" else jnp.ones
+
+    def dense(k, *shape):
+        return (jax.random.normal(k, shape) * shape[-2] ** -0.5).astype(dtype)
+
+    def by_expert(k, n, *shape):
+        first, count = c.experts_held
+        drawn = jax.vmap(
+            lambda e: dense(jax.random.fold_in(k, e), n, *shape)
+        )(first + jnp.arange(count))
+        return jnp.moveaxis(drawn, 0, 1)  # (layers of the kind, held, ...)
+
+    layers = {}
+    for index, (kind, n) in enumerate(c.kind_layers):
+        keys = jax.random.split(
+            jax.random.fold_in(key, _KIND_KEY_BASE + index), 12)
+        kv = kind.kv_heads
+        leaves = {
+            "attn_norm": norm_init((n, d), dtype),
+            "wq": dense(keys[0], n, d, h * hd),
+            "wk": dense(keys[1], n, d, kv * hd),
+            "wv": dense(keys[2], n, d, kv * vd),
+            "wo": dense(keys[3], n, h * vd, d),
+            "ffn_norm": norm_init((n, d), dtype),
+        }
+        if kind.sink:
+            leaves["attn_sink"] = jax.random.normal(keys[4], (n, h))
+        if kind.routed:
+            f = c.expert_hidden
+            leaves.update({
+                "router": jax.random.normal(keys[5], (n, d, c.n_experts))
+                * d ** -0.5,
+                "router_bias": jax.random.normal(keys[6], (n, c.n_experts))
+                * 0.1,
+                "experts_gate": by_expert(keys[7], n, d, f),
+                "experts_up": by_expert(keys[8], n, d, f),
+                "experts_down": by_expert(keys[9], n, f, d),
+            })
+        else:
+            leaves.update({
+                "w_gate": dense(keys[7], n, d, c.ffn_hidden),
+                "w_up": dense(keys[8], n, d, c.ffn_hidden),
+                "w_down": dense(keys[9], n, c.ffn_hidden, d),
+            })
+        layers[kind.name] = leaves
+    return layers
 
 
 def _init_ssm_params(c: ModelConfig, key: jax.Array, dtype) -> Params:
@@ -232,6 +303,18 @@ def apply_rope(
     return rotated.astype(x.dtype)
 
 
+def rope_heads(
+    c: ModelConfig, x: jax.Array, positions: jax.Array, theta: float
+) -> jax.Array:
+    """:func:`apply_rope` on the leading ``rotary_dim`` dimensions of every
+    head of (B, S, H, hd); the rest pass.  All of them where the
+    configuration names no ``rotary_dim``."""
+    if c.rotary_dim is None or c.rotary_dim == x.shape[-1]:
+        return apply_rope(x, positions, theta, c.rope_scaling)
+    turned = apply_rope(x[..., : c.rotary_dim], positions, theta, c.rope_scaling)
+    return jnp.concatenate([turned, x[..., c.rotary_dim :]], axis=-1)
+
+
 def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
     if cap is None:
         return x
@@ -318,6 +401,191 @@ def ffn_block(c: ModelConfig, lp, x: jax.Array) -> jax.Array:
         if c.use_post_norms:
             ffn = rms_norm(ffn, lp["post_ffn_norm"], c.rms_eps, c.rmsnorm_style)
         return x + ffn
+
+
+#: What a routed layer counts, summed over layers and launches: assignments
+#: to experts held here, rows routed (each makes ``experts_per_token``
+#: assignments), and passes through a routed layer (each a product of every
+#: held expert).
+MOE_TALLY = ("held", "rows", "layer_passes")
+
+#: Rows up to which the held experts run as one masked product over all of
+#: them, every expert on every row: the experts' matrices are read whole
+#: either way, and on a v5e reading one layer's 16 (0.8 GB, 1 ms) costs what
+#: the masked product of 256 rows does (16 x 256 x 50 MFLOP).  Past it the
+#: rows are grouped by expert and each expert multiplies its own.
+_MOE_MASKED_ROWS = 256
+#: Rows a grouped product takes at a time: ``experts_per_token`` assignments
+#: a row are gathered (every one may be to an expert held here), so the
+#: gathered rows of a block are 8 x 4,096 x 4,096 wide x 2 bytes = 268 MB.
+_MOE_BLOCK_ROWS = 4096
+
+
+def route(c: ModelConfig, lp, t: jax.Array):
+    """The router on normed rows ``t`` (N, D), in float32 whatever ``t`` is:
+    sigmoid scores over all ``n_experts``, the ``experts_per_token`` largest
+    of score + bias chosen (the bias selects and nothing else), their scores
+    renormalised over all that were chosen, held here or not.  Returns
+    (chosen (N, k) int32, weights (N, k) float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        t.astype(jnp.float32), lp["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(
+        scores + lp["router_bias"].astype(jnp.float32), c.experts_per_token)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+
+
+#: A routed layer's leaves that hold the experts, (held, ...) a layer.
+EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
+
+
+def _experts_masked(stacks, layer, t, local, held, weights):
+    """Few rows: every held expert's gated product on every row, and each
+    row's sum of them under the weight it gave the expert (0 where it was not
+    sent there).  The layer's experts are read where they lie in ``stacks``
+    (layers of the kind, held, ...), at ``layer``: the index goes into the
+    products' operand reads."""
+    gate_w, up_w, down_w = (layer_of(stacks[leaf], layer) for leaf in EXPERT_LEAVES)
+    count = gate_w.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        sent = held[:, :, None] & (
+            local[:, :, None] == jnp.arange(count)[None, None, :])
+        share = jnp.sum(jnp.where(sent, weights[:, :, None], 0.0), axis=1)
+    with jax.named_scope("moe_experts"):
+        gate = jax.nn.silu(jnp.einsum("nd,edf->enf", t, gate_w))
+        up = jnp.einsum("nd,edf->enf", t, up_w)
+        out = jnp.einsum("enf,efd->end", gate * up, down_w)
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("ne,end->nd", share, out.astype(jnp.float32)).astype(
+            t.dtype)
+
+
+#: Most rows, contracted columns and produced columns of one tile of the
+#: grouped product (a 512-row tile of 1,024 x 1,024 weights is 341 FLOPs a
+#: byte, past a v5e's 240, in 9 MB of its fast memory).
+_GROUPED_TILE = (512, 1024, 1024)
+
+
+def _grouped_dot(rows: jax.Array, stack: jax.Array, layer, sizes: jax.Array):
+    """``rows[group g's run] @ stack[layer, g]`` for every group: ``rows`` (M,
+    K) sorted by group, ``stack`` (layers, G, K, N), ``sizes`` (G,) int32 the
+    runs' lengths in order; rows past the last run come back undefined.  Only
+    the tiles that hold a run's rows are computed.
+
+    megablox's grouped matrix product, a Pallas kernel, called from here so
+    that its operations carry this call's scope in a profile: what XLA's TPU
+    compiler makes of ``lax.ragged_dot`` is the same kind of kernel under an
+    operation name of its own making (``ragged-dot-none``) and no scope, so
+    that no trace could say what the experts cost.  The kernel reads the
+    layer's matrices where they lie: it is handed every layer's as layers x G
+    groups, all empty but this layer's, and visits no empty group.  (Handed a
+    slice, it had the slice copied out for it first: 0.8 GB a layer a call at
+    the published sizes, 1.6 ms, PERF.md 6, PR 32.)"""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = rows.shape
+    layers, groups = stack.shape[:2]
+    tile = (min(_GROUPED_TILE[0], m), min(_GROUPED_TILE[1], k),
+            min(_GROUPED_TILE[2], stack.shape[3]))
+    pad = -m % tile[0]
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    every = jax.lax.dynamic_update_slice(
+        jnp.zeros((layers * groups,), jnp.int32), sizes, (layer * groups,))
+    out = gmm(rows, stack.reshape((layers * groups,) + stack.shape[2:]), every,
+              preferred_element_type=rows.dtype, tiling=tile,
+              interpret=jax.default_backend() == "cpu")
+    return out[:m] if pad else out
+
+
+def _experts_grouped(stacks, layer, t, local, held, weights):
+    """Many rows: the rows' assignments sorted by expert (those to absent
+    experts last, in no group), each held expert's gated product over its own
+    run of rows (``_grouped_dot``), and each row's weighted sum of what its
+    assignments returned.  No capacity: a group is as long as its expert was
+    chosen often."""
+    n, k = local.shape
+    count = stacks["experts_gate"].shape[1]
+    with jax.named_scope("moe_dispatch"):
+        expert = jnp.where(held, local, count).reshape(-1)  # (N * k,)
+        # The stable sort by expert, counted and not sorted (the experts are
+        # few): an assignment goes to its group's start plus the number of
+        # its group's assignments before it.  (The TPU compiler takes 14 s
+        # for one ``argsort`` of 32,768 and 2 s for this, PERF.md 6, PR 32.)
+        sent = (expert[:, None] == jnp.arange(count + 1)[None, :]).astype(
+            jnp.int32)
+        before = jnp.cumsum(sent, axis=0) - sent
+        every = jnp.sum(sent, axis=0)
+        starts = jnp.cumsum(every) - every
+        went = jnp.sum(sent * (before + starts[None, :]), axis=1)
+        order = jnp.zeros_like(went).at[went].set(
+            jnp.arange(n * k, dtype=went.dtype), unique_indices=True)
+        sizes = every[:count]
+        rows = t[order // k]  # (N * k, D): assignment a's row is a // k
+    with jax.named_scope("moe_experts"):
+        gate = jax.nn.silu(
+            _grouped_dot(rows, stacks["experts_gate"], layer, sizes))
+        up = _grouped_dot(rows, stacks["experts_up"], layer, sizes)
+        out = _grouped_dot(gate * up, stacks["experts_down"], layer, sizes)
+    with jax.named_scope("moe_combine"):
+        back = went.reshape(n, k)  # where each assignment went
+        share = jnp.where(held, weights, 0.0)
+        # Rows past the last group are no expert's: what the product left
+        # there is dropped by the mask, not multiplied by zero.
+        returned = jnp.where(held[:, :, None], out[back].astype(jnp.float32), 0.0)
+        return jnp.sum(returned * share[:, :, None], axis=1).astype(t.dtype)
+
+
+def moe_block(c: ModelConfig, lp, x: jax.Array):
+    """Norm, the routed experts, and the residual: the feed-forward of a
+    routed layer.  The program holds experts ``experts_held = (first,
+    count)`` of the router's ``n_experts``: it routes every row over all of
+    them and adds the part of the result that its own experts give; an
+    assignment to an absent expert is skipped (what it would have added is
+    another chip's to add), none to a held expert is dropped.  The form
+    follows the rows, from the shapes: one masked product for a decode step's
+    few, grouped products a block of rows at a time for a span's many.
+
+    ``lp`` holds the layer's own norm, router and bias, and the experts
+    either as the layer's own leaves (``EXPERT_LEAVES``, (held, ...)) or, from
+    the layer loop, as ``lp["experts"]`` = (every layer of the kind's, the
+    layer's index): the products read the layer's where they lie.
+
+    Returns (x + the held experts' part, the layer's tally: int32
+    (assignments to held experts, rows routed, 1), which the layer loop sums
+    over the routed layers)."""
+    shape = x.shape
+    first, count = c.experts_held
+    stacks, layer = lp.get("experts") or (
+        {leaf: lp[leaf][None] for leaf in EXPERT_LEAVES}, 0)
+    with jax.named_scope("moe_router"):
+        t = rms_norm(x, lp["ffn_norm"], c.rms_eps, c.rmsnorm_style).reshape(
+            -1, shape[-1])
+        chosen, weights = route(c, lp, t)
+        local = chosen - first
+        held = (local >= 0) & (local < count)
+    n = t.shape[0]
+    if n <= _MOE_MASKED_ROWS:
+        part = _experts_masked(stacks, layer, t, local, held, weights)
+    elif n <= _MOE_BLOCK_ROWS:
+        part = _experts_grouped(stacks, layer, t, local, held, weights)
+    else:
+        blocks = -(-n // _MOE_BLOCK_ROWS)
+        pad = blocks * _MOE_BLOCK_ROWS - n  # padding rows are sent nowhere
+
+        def blocked(a, fill=0):
+            a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
+                        constant_values=fill)
+            return a.reshape((blocks, _MOE_BLOCK_ROWS) + a.shape[1:])
+
+        part = jax.lax.map(
+            lambda block: _experts_grouped(stacks, layer, *block),
+            (blocked(t), blocked(local), blocked(held, False), blocked(weights)),
+        ).reshape(-1, shape[-1])[:n]
+    with jax.named_scope("moe_combine"):
+        return x + part.reshape(shape), jnp.stack(
+            [jnp.sum(held, dtype=jnp.int32), jnp.int32(n), jnp.int32(1)])
 
 
 def final_norm(params: Params, c: ModelConfig, x: jax.Array) -> jax.Array:
@@ -560,8 +828,12 @@ def fork_ssm(state: SSMState, rows: jax.Array | int) -> SSMState:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KVCache:
-    k: jax.Array  # (L, B, T, KV, hd)
-    v: jax.Array  # (L, B, T, KV, hd)
+    #: (L, B, T, KV, hd) keys and (L, B, T, KV, value width) values.  With
+    #: layers of more than one kind, a cache a kind of attention: ``{"full":
+    #: (its layers, B, T, its KV, hd), "window": ...}``, the two dictionaries
+    #: alike.
+    k: Any
+    v: Any
     key_positions: jax.Array  # (B, T) int32
     key_valid: jax.Array  # (B, T) bool
     #: The rows' recurrent state after the last position written; None for
@@ -578,14 +850,54 @@ class KVCache:
         return cls(*children)
 
 
+def kv_buffers(
+    config: ModelConfig, middle: Tuple[int, ...], dtype, make=jnp.zeros
+):
+    """(keys, values) of zeros, each ``(layers,) + middle + (KV heads, head
+    width)``: the shape of every cache layout (``middle`` is (rows, columns)
+    of a dense cache or a tail, (pages, page size) of a pool).  With layers
+    of more than one kind each is a dictionary by kind of attention, at that
+    kind's layers and heads (``ModelConfig.cache_kinds``)."""
+    c = config
+
+    def pair(layers, heads):
+        return (make((layers,) + middle + (heads, c.head_dim), dtype),
+                make((layers,) + middle + (heads, c.value_dim), dtype))
+
+    if not c.has_layer_kinds:
+        return pair(c.n_layers, c.n_kv_heads)
+    pairs = {name: pair(n, heads) for name, n, heads in c.cache_kinds}
+    return ({name: kv[0] for name, kv in pairs.items()},
+            {name: kv[1] for name, kv in pairs.items()})
+
+
+def by_kind(c: ModelConfig, *trees):
+    """What a caller hands ``scan_layers`` of several caches that are each a
+    dictionary by kind of attention (or each one array, for a configuration
+    of one kind): ``{kind: (tree[kind], ...)}``, or the trees as a tuple."""
+    if not c.has_layer_kinds:
+        return trees
+    return {name: tuple(tree[name] for tree in trees)
+            for name, _, _ in c.cache_kinds}
+
+
+def from_kinds(c: ModelConfig, written, n: int = 2):
+    """The inverse of :func:`by_kind`: ``n`` trees back from what
+    ``scan_layers`` returned."""
+    if not c.has_layer_kinds:
+        return tuple(written)
+    return tuple({name: pair[i] for name, pair in written.items()}
+                 for i in range(n))
+
+
 def make_cache(
     config: ModelConfig, batch: int, max_len: int, dtype: jnp.dtype = jnp.float32
 ) -> KVCache:
     c = config
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
+    k, v = kv_buffers(c, (batch, max_len), dtype)
     return KVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
+        k=k,
+        v=v,
         key_positions=jnp.zeros((batch, max_len), jnp.int32),
         key_valid=jnp.zeros((batch, max_len), jnp.bool_),
         ssm=make_ssm_state(c, batch, dtype),
@@ -617,9 +929,21 @@ def _attention_masks(
     return global_mask, local_mask
 
 
-def _layer_mask(is_local: jax.Array, local_mask, global_mask) -> jax.Array:
+def attention_scope(is_local):
+    """The scope of a layer's attention.  A run of window layers, whose window
+    is known when it is traced, has a name of its own."""
+    if is_local is True:
+        return jax.named_scope("attention_window")
+    return jax.named_scope("attention")
+
+
+def _layer_mask(is_local, local_mask, global_mask) -> jax.Array:
     """This layer's mask of the einsum paths: ``is_local`` is a traced scan
-    input, so both masks are built outside the loop and one is picked here."""
+    input, so both masks are built outside the loop and one is picked here.
+    (A run of layers of one kind knows its window when it is traced: a
+    Python bool picks the mask there.)"""
+    if isinstance(is_local, bool):
+        return local_mask if is_local else global_mask
     return jnp.where(is_local, local_mask, global_mask)
 
 
@@ -630,6 +954,8 @@ def windowed(c: ModelConfig, is_local: jax.Array, call: Callable, *operands):
     calls (and no choice at all for a configuration without a window)."""
     if c.sliding_window is None:
         return call(None, *operands)
+    if isinstance(is_local, bool):  # a run of layers of one kind
+        return call(c.sliding_window if is_local else None, *operands)
     return jax.lax.cond(
         is_local,
         functools.partial(call, c.sliding_window),
@@ -640,8 +966,8 @@ def windowed(c: ModelConfig, is_local: jax.Array, call: Callable, *operands):
 
 def layer_block(
     c: ModelConfig, lp, x: jax.Array, positions: jax.Array, attend: Callable,
-    operands_l, written, ssm, layer: jax.Array, is_local: jax.Array,
-    valid: Optional[jax.Array],
+    operands_l, written, ssm, layer: jax.Array, is_local,
+    valid: Optional[jax.Array], kind: Optional[LayerKind] = None,
 ):
     """One transformer layer, the only one written out: norm, the three
     products, rope; the recurrent mixer where the configuration has one;
@@ -660,8 +986,19 @@ def layer_block(
     state, or None for rows that start a sequence.  ``valid`` (B, S) marks the
     positions that move the mixer's state; None is all of them.
 
+    ``kind``: what this layer is, where the configuration has layers of more
+    than one kind (``lp``, ``operands_l`` and ``written`` are then its
+    kind's, and ``layer`` its index among them): its key-value heads, its
+    rotary base, its window as a Python bool in ``is_local``, its sinks
+    (handed to ``attend`` as ``sink=``), and routed experts in the
+    feed-forward's place, whose count of assignments to held experts is what
+    the layer produces.
+
     Returns (x, what ``attend`` produced, ``written``, ``ssm``)."""
-    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
+    h, kv, hd, vd = c.n_heads, c.n_kv_heads, c.head_dim, c.value_dim
+    theta = c.rope_theta
+    if kind is not None:
+        kv, theta = kind.kv_heads, kind.rope_theta
     one = x.ndim == 2
     span_of = (lambda t: t[:, None]) if one else (lambda t: t)
     rows = x.shape[:-1] + ((1,) if one else ())  # (B, S)
@@ -672,9 +1009,10 @@ def layer_block(
         q = matmul(qkv_in, lp["wq"]).reshape(rows + (h, hd))
         k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
             rows + (kv, hd))
-        v = matmul(qkv_in, lp["wv"]).reshape(rows + (kv, hd))
-        q = apply_rope(q, span_of(positions), c.rope_theta, c.rope_scaling)
-        k = apply_rope(k, span_of(positions), c.rope_theta, c.rope_scaling)
+        v = _times(matmul(qkv_in, lp["wv"]), c.value_scale).reshape(
+            rows + (kv, vd))
+        q = rope_heads(c, q, span_of(positions), theta)
+        k = rope_heads(c, k, span_of(positions), theta)
     mixed = None
     if c.has_ssm:
         mixed, after = ssm_mixer(
@@ -684,9 +1022,13 @@ def layer_block(
             ssm = after
         if one:
             mixed = mixed[:, 0]
+    sink = {"sink": lp["attn_sink"]} if kind is not None and kind.sink else {}
     attn, produced, written = attend(
-        q, k, v, operands_l, written, layer, is_local)
-    x = attn_out_block(c, lp, x, attn.reshape(x.shape[:-1] + (h * hd,)), mixed)
+        q, k, v, operands_l, written, layer, is_local, **sink)
+    x = attn_out_block(c, lp, x, attn.reshape(x.shape[:-1] + (h * vd,)), mixed)
+    if kind is not None and kind.routed:
+        x, produced = moe_block(c, lp, x)
+        return x, produced, written, ssm
     return ffn_block(c, lp, x), produced, written, ssm
 
 
@@ -709,7 +1051,54 @@ def scan_layers(
 
     Returns (x, what ``attend`` produced stacked over the layers, ``written``
     and ``ssm`` after the span).  A state is handed on only where one was
-    handed in: rows that start a sequence (``ssm`` None) drop it."""
+    handed in: rows that start a sequence (``ssm`` None) drop it.
+
+    A configuration with layers of more than one kind (``c.layer_runs``)
+    runs each run of equal layers as one ``lax.scan`` of the same
+    ``layer_block``, one after the other, with the run's kind as static
+    data.  ``params["layers"]``, ``operands`` and ``written`` are then
+    dictionaries: a stack of weights by the kind's name, and what the caller
+    reads or writes by the kind of attention (``"full"``, ``"window"``), each
+    with its own layers leading.  A run reads its weights and operands at
+    the layer's index in its kind's stack and carries its kind's ``written``;
+    nothing is sliced out for a run.  What comes back as produced is the
+    routed layers' tally, ``MOE_TALLY``: int32 (assignments to held experts,
+    rows routed, routed layers passed), zeros without routed layers."""
+    if c.has_layer_kinds:
+        held = jnp.zeros((len(MOE_TALLY),), jnp.int32)
+        for run in c.layer_runs:
+            kind = run.kind
+            stack = params["layers"][kind.name]
+            operands_k = None if operands is None else operands[kind.attention]
+
+            def run_step(carry, index, kind=kind, stack=stack,
+                         operands_k=operands_k, run=run):
+                x, cache, held = carry
+                # The layer's place in its kind's weights, and in its kind of
+                # attention's cache: read where they lie, nothing sliced out
+                # (the experts not even named here: ``moe_block`` reads them).
+                lp = {leaf: layer_of(a, run.at + index)
+                      for leaf, a in stack.items() if leaf not in EXPERT_LEAVES}
+                if kind.routed:
+                    lp["experts"] = (
+                        {leaf: stack[leaf] for leaf in EXPERT_LEAVES},
+                        run.at + index)
+                layer = run.cache_at + index
+                operands_l = jax.tree.map(
+                    lambda a: layer_of(a, layer), operands_k)
+                x, produced, cache, _ = layer_block(
+                    c, lp, x, positions, attend, operands_l, cache, None,
+                    layer, kind.window is not None, valid, kind)
+                return (x, cache, held + produced if kind.routed else held), None
+
+            cache = None if written is None else written[kind.attention]
+            with jax.named_scope("layers"):
+                (x, cache, held), _ = jax.lax.scan(
+                    run_step, (x, cache, held),
+                    jnp.arange(run.count, dtype=jnp.int32))
+            if written is not None:
+                written = {**written, kind.attention: cache}
+        return x, held, written, None
 
     def step(carry, scanned):
         x, written, ssm = carry
@@ -762,7 +1151,8 @@ def forward(
         k_valid = jax.lax.dynamic_update_slice(cache.key_valid, valid, (0, write_index))
 
     global_mask, local_mask = _attention_masks(c, positions, valid, k_positions, k_valid)
-    reps = c.n_heads // c.n_kv_heads
+    if c.use_flash_attention and (c.swa_sink or c.value_dim != c.head_dim):
+        raise LayerKindsUnsupported("flash_attention", KERNEL_NEEDS_PLAIN_HEADS)
 
     def call_flash(window, q, keys, values):
         # Pallas blockwise kernel: no (B, H, S, S) logits in HBM.  The
@@ -781,9 +1171,11 @@ def forward(
             causal=True, interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, _, kv_cache, layer, is_local):
+    def attend(q, k, v, _, kv_cache, layer, is_local, sink=None):
         """Own keys without a cache; with one, this call's K/V written at
         ``(layer, 0, write_index)`` and the layer's whole buffer attended."""
+        groups = k.shape[2]
+        reps = q.shape[2] // groups
         if kv_cache is None:
             keys, values = k, v
         else:
@@ -792,7 +1184,7 @@ def forward(
                     put_columns(buffer, layer, write_index, new)
                     for buffer, new in zip(kv_cache, (k, v)))
             keys, values = (layer_of(buffer, layer) for buffer in kv_cache)
-        with jax.named_scope("attention"):
+        with attention_scope(is_local):
             if c.use_flash_attention and cache is None:
                 # The pallas kernel takes equal q/kv head counts; expand here.
                 attn = windowed(
@@ -805,14 +1197,41 @@ def forward(
                 # kv head — on the decode path jnp.repeat would re-write the
                 # whole (B, T, H, hd) cache expansion every layer every step,
                 # doubling HBM traffic for nothing.
-                qg = q.reshape(q.shape[:2] + (c.n_kv_heads, reps, c.head_dim))
-                logits = jnp.einsum("bsgrd,btgd->bgrst", qg, keys).astype(jnp.float32)
-                logits = logits * c.q_scale
-                logits = _softcap(logits, c.attn_softcap)
-                mask = _layer_mask(is_local, local_mask, global_mask)
-                logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
-                weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-                attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
+                qg = q.reshape(q.shape[:2] + (groups, reps, c.head_dim))
+
+                def attend_groups(qg, keys, values, sink):
+                    """Logits, mask, softmax and values of the key-value
+                    groups handed in: (B, S, g, reps, hd) queries over (B, T,
+                    g, hd) keys; ``sink`` (g, reps) or None."""
+                    logits = jnp.einsum(
+                        "bsgrd,btgd->bgrst", qg, keys).astype(jnp.float32)
+                    logits = logits * c.q_scale
+                    logits = _softcap(logits, c.attn_softcap)
+                    mask = _layer_mask(is_local, local_mask, global_mask)
+                    logits = jnp.where(mask[:, :, None], logits, MASK_FILL)
+                    if sink is not None:  # one a query head
+                        sink = sink[:, :, None, None]
+                    weights = softmax_with_sink(logits, sink).astype(x.dtype)
+                    return jnp.einsum("bgrst,btgd->bsgrd", weights, values)
+
+                if sink is not None:
+                    sink = sink.reshape(groups, reps)
+                if c.has_layer_kinds and q.shape[1] > 1:
+                    # Many query heads over a long span: the float32 logits
+                    # of all of them at once pass what the weights leave
+                    # (64 heads x 4,096 x 4,096 x 4 B = 4.3 GB a row), so a
+                    # key-value group's heads at a time.
+                    by_group = [jnp.moveaxis(t, 2, 0)[:, :, :, None]
+                                for t in (qg, keys, values)]
+                    if sink is not None:
+                        by_group.append(sink[:, None])
+                    attn = jax.lax.map(
+                        lambda group: attend_groups(
+                            *group, *(() if sink is not None else (None,))),
+                        tuple(by_group))  # (g, B, S, 1, reps, vd)
+                    attn = jnp.moveaxis(attn[:, :, :, 0], 0, 2)
+                else:
+                    attn = attend_groups(qg, keys, values, sink)
         return attn, None, kv_cache
 
     if cache is None:
@@ -820,9 +1239,10 @@ def forward(
             params, c, x, positions, attend, None, None, None, valid)
         new_cache = None
     else:
-        x, _, (new_k, new_v), new_ssm = scan_layers(
-            params, c, x, positions, attend, None, (cache.k, cache.v),
+        x, _, written, new_ssm = scan_layers(
+            params, c, x, positions, attend, None, by_kind(c, cache.k, cache.v),
             cache.ssm, valid)
+        new_k, new_v = from_kinds(c, written)
         new_cache = KVCache(k=new_k, v=new_v, key_positions=k_positions,
                             key_valid=k_valid, ssm=new_ssm)
 
@@ -864,6 +1284,7 @@ def forward_trunk_tail(
     frozen_positions=(),  # sequence of (Rows, F_i) int32, one per block
     use_decode_kernel: bool = True,
     ssm: Optional[SSMState] = None,  # (L, Rows, ...) recurrent state by row
+    moe_held: Optional[jax.Array] = None,  # MOE_TALLY int32, so far
 ):
     """One-token decode step where every search slot shares ONE trunk cache.
 
@@ -906,6 +1327,13 @@ def forward_trunk_tail(
     here (forked from the trunk's where the rows share one); this step
     advances it by the one position.
 
+    With layers of more than one kind the trunk's ``k`` and ``v``, the tails
+    and every frozen block are dictionaries by kind of attention
+    (``KVCache``, ``kv_buffers``), plain arrays all: the int8 forms and the
+    Pallas kernel are refused by name.  ``moe_held``: the routed layers'
+    tally (``MOE_TALLY``); handed one, the step adds its own and returns it
+    fifth.
+
     Returns (final-norm hidden (Rows, D), new tail_k, new tail_v, new ssm)
     with the tail structure preserved; ``ssm`` is None where there is none.
     """
@@ -913,17 +1341,24 @@ def forward_trunk_tail(
     if c.has_ssm and ssm is None:
         raise RecurrentStateUnsupported(
             "forward_trunk_tail", "rows without their recurrent state")
-    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    reps = h // kv
     frozen_k = tuple(frozen_k)
     frozen_v = tuple(frozen_v)
     frozen_positions = tuple(frozen_positions)
     tail_quantized = isinstance(tail_k, tuple)
     trunk_quantized = isinstance(trunk.k, tuple)
-    t_tail = (tail_k[0] if tail_quantized else tail_k).shape[2]
+    if c.has_layer_kinds:
+        if tail_quantized or trunk_quantized:
+            raise LayerKindsUnsupported("an int8 key-value tail", NEEDS_ONE_KIND)
+        if c.use_decode_attention and use_decode_kernel:
+            raise LayerKindsUnsupported(
+                "decode_attention", KERNEL_NEEDS_PLAIN_HEADS)
 
     def block_width(block) -> int:
+        if isinstance(block, dict):  # by kind of attention: alike in width
+            block = next(iter(block.values()))
         return (block[0] if isinstance(block, tuple) else block).shape[2]
+
+    t_tail = block_width(tail_k)
 
     x = embed_tokens(params, c, tokens)  # (Rows, D)
 
@@ -980,12 +1415,14 @@ def forward_trunk_tail(
             interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, operands_l, tails, layer, is_local):
+    def attend(q, k, v, operands_l, tails, layer, is_local, sink=None):
         """This step's K/V written (quantised where the tail is) at the
         tails' ``(layer, 0, write_col)``, one column of the carried
         buffers; then [trunk | frozen blocks | this layer's tail] attended,
         the trunk broadcast over the slots."""
         k_trunk, v_trunk, froz_k, froz_v = operands_l
+        kv, hd = k.shape[-2:]
+        reps = q.shape[-2] // kv
 
         def write_column(tail, new):
             """``new`` (Rows, 1, KV, hd) into every layer's ``tail`` (or its
@@ -1004,7 +1441,7 @@ def forward_trunk_tail(
         new_k_tail, new_v_tail = jax.tree.map(
             lambda buffer: layer_of(buffer, layer), tails)
 
-        with jax.named_scope("attention"):
+        with attention_scope(is_local):
             if (
                 c.use_decode_attention
                 and use_decode_kernel
@@ -1041,7 +1478,7 @@ def forward_trunk_tail(
                     quantized = isinstance(block, tuple)
                     values = block[0] if quantized else block
                     vg = values.astype(x.dtype).reshape(
-                        n_slots, n_roles, width, kv, hd
+                        n_slots, n_roles, width, kv, values.shape[-1]
                     )
                     if quantized:
                         s = block[1].reshape(n_slots, n_roles, width, kv)
@@ -1081,7 +1518,9 @@ def forward_trunk_tail(
                 logits = _softcap(logits, c.attn_softcap)
                 mask = jnp.concatenate(masks, axis=-1)[:, :, None, None]
                 logits = jnp.where(mask, logits, MASK_FILL)
-                weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+                if sink is not None:  # one a query head: (KV, reps, 1)
+                    sink = sink.reshape(kv, reps)[:, :, None]
+                weights = softmax_with_sink(logits, sink).astype(x.dtype)
                 w0 = (k_trunk[0] if trunk_quantized else k_trunk).shape[1]
                 wt = weights[..., :w0]
                 if trunk_quantized:
@@ -1106,10 +1545,21 @@ def forward_trunk_tail(
     # One pytree a kind serves every variant: lax.scan slices each read-only
     # leaf along the layer axis, including nested (int8, scale) pairs and
     # the per-block frozen tuples, and carries the tails as they come.
-    x, _, (new_tail_k, new_tail_v), new_ssm = scan_layers(
-        params, c, x, positions, attend,
-        (trunk.k, trunk.v, frozen_k, frozen_v), (tail_k, tail_v), ssm, None)
+    if c.has_layer_kinds:
+        operands = {
+            name: (trunk.k[name], trunk.v[name],
+                   tuple(block[name] for block in frozen_k),
+                   tuple(block[name] for block in frozen_v))
+            for name, _, _ in c.cache_kinds}
+    else:
+        operands = (trunk.k, trunk.v, frozen_k, frozen_v)
+    x, held, written, new_ssm = scan_layers(
+        params, c, x, positions, attend, operands,
+        by_kind(c, tail_k, tail_v), ssm, None)
+    new_tail_k, new_tail_v = from_kinds(c, written)
     x = final_norm(params, c, x)
+    if moe_held is not None:
+        return x, new_tail_k, new_tail_v, new_ssm, moe_held + held
     return x, new_tail_k, new_tail_v, new_ssm
 
 
@@ -1143,9 +1593,10 @@ def forward_shared_trunk(
     """
     c = config
     n_paths, span = suffix_tokens.shape
-    h, kv, hd = c.n_heads, c.n_kv_heads, c.head_dim
-    reps = h // kv
     n_roles = cache.key_valid.shape[0]
+    if c.has_layer_kinds and return_suffix_kv:
+        raise LayerKindsUnsupported(
+            "forward_shared_trunk with suffix keys", NEEDS_ONE_KIND)
 
     x = embed_tokens(params, c, suffix_tokens)  # (P, L, D)
     # One row a (path, role), as the products and the mixer take them.
@@ -1184,15 +1635,17 @@ def forward_shared_trunk(
         # end; what the suffix makes of it is not kept.
         ssm_rows = fork_ssm(cache.ssm, jnp.tile(jnp.arange(n_roles), n_paths))
 
-    def attend(q, ks, vs, trunk_l, _, layer, is_local):
+    def attend(q, ks, vs, trunk_l, _, layer, is_local, sink=None):
         """The trunk's (R, T) keys broadcast over the paths, beside each
         path's own suffix; nothing is written, so nothing is carried, and
         the suffix's K/V are produced: stacked by the loop."""
         k_trunk, v_trunk = trunk_l  # (R, T, kv, hd)
+        kv, hd = ks.shape[-2:]
+        reps = q.shape[-2] // kv
         qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
         ks = ks.reshape(n_paths, n_roles, span, kv, hd)
-        vs = vs.reshape(n_paths, n_roles, span, kv, hd)
-        with jax.named_scope("attention"):
+        vs = vs.reshape(n_paths, n_roles, span, kv, vs.shape[-1])
+        with attention_scope(is_local):
             lt = jnp.einsum("prsgmd,rtgd->prgmst", qg, k_trunk).astype(jnp.float32)
             ls = jnp.einsum("prsgmd,prtgd->prgmst", qg, ks).astype(jnp.float32)
             logits = jnp.concatenate([lt, ls], axis=-1) * c.q_scale
@@ -1202,7 +1655,9 @@ def forward_shared_trunk(
                  _layer_mask(is_local, suffix_local, suffix_mask)], axis=-1
             )[None, :, None, None]  # (1, R, 1, 1, L, T+L)
             logits = jnp.where(mask, logits, MASK_FILL)
-            weights = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+            if sink is not None:  # one a query head: (KV, reps, 1, 1)
+                sink = sink.reshape(kv, reps)[:, :, None, None]
+            weights = softmax_with_sink(logits, sink).astype(x.dtype)
             t_len = k_trunk.shape[1]
             attn = jnp.einsum(
                 "prgmst,rtgd->prsgmd", weights[..., :t_len], v_trunk
@@ -1213,7 +1668,7 @@ def forward_shared_trunk(
 
     x, suffix_kv, _, _ = scan_layers(
         params, c, x, jnp.tile(positions, (n_paths, 1)), attend,
-        (cache.k, cache.v), None, ssm_rows, None)
+        by_kind(c, cache.k, cache.v), None, ssm_rows, None)
     x = final_norm(params, c, x).reshape(n_paths, n_roles, span, -1)
     if return_all_positions:
         out = x  # (P, R, L, D) — the shared-context scorer needs every slot

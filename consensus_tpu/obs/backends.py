@@ -100,6 +100,28 @@ class BackendInstruments:
             "no state at its end, by operation (lookup or insert).",
             labels=("backend", "op"),
         )
+        self._moe_assignments = reg.counter(
+            "backend_moe_assignments_total",
+            "Assignments of rows to routed experts that the counted program "
+            "launches made (generation's decode steps, the paged prefill and "
+            "score chunks): to experts held here, which the program "
+            "computed, and to absent ones, which it skipped.",
+            labels=("backend", "held"),
+        )
+        self._moe_expert_calls = reg.counter(
+            "backend_moe_expert_calls_total",
+            "Products of a held expert in those launches: held experts x "
+            "routed layers x passes through the layers (a decode step, a "
+            "chunk).  Held assignments over this is the rows an expert's "
+            "product sees.",
+            labels=("backend",),
+        )
+        self._kv_bytes_per_token = reg.gauge(
+            "backend_kv_bytes_per_token",
+            "Bytes of keys and values one position holds over the layers of "
+            "one kind of attention (all, full, window).",
+            labels=("backend", "kind"),
+        )
         self._seen_lock = threading.Lock()
         self._seen_shapes: Set[Tuple[str, Tuple[int, ...]]] = set()
 
@@ -146,6 +168,20 @@ class BackendInstruments:
 
     def record_prefix_run_declined(self, op: str, runs: int = 1) -> None:
         self._prefix_declined.labels(self.backend, op).inc(runs)
+
+    # -- routed experts, caches by kind --------------------------------------
+
+    def record_moe(self, held: int, assignments: int, expert_calls: int) -> None:
+        """Launches that made ``assignments`` assignments of rows to experts,
+        ``held`` of them to experts held here, in ``expert_calls`` products
+        of a held expert."""
+        self._moe_assignments.labels(self.backend, "held").inc(held)
+        self._moe_assignments.labels(self.backend, "absent").inc(
+            assignments - held)
+        self._moe_expert_calls.labels(self.backend).inc(expert_calls)
+
+    def record_kv_bytes_per_token(self, kind: str, nbytes: float) -> None:
+        self._kv_bytes_per_token.labels(self.backend, kind).set(nbytes)
 
     # -- transfers -----------------------------------------------------------
 

@@ -274,6 +274,19 @@ def decode_attention(
     return out
 
 
+def softmax_with_sink(logits: jax.Array, sink: Optional[jax.Array]) -> jax.Array:
+    """Softmax over the last axis of float32 ``logits`` with one more column
+    of logit ``sink`` (broadcast against ``logits[..., :1]``) in the
+    denominator: the sink takes mass and adds no value, so the weights sum to
+    less than one.  The plain softmax where there is no sink."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    sink = sink.astype(jnp.float32)
+    top = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), sink)
+    shares = jnp.exp(logits - top)
+    return shares / (jnp.sum(shares, axis=-1, keepdims=True) + jnp.exp(sink - top))
+
+
 # ---------------------------------------------------------------------------
 # Paged attention: gather K/V through per-slot block tables
 # ---------------------------------------------------------------------------
@@ -289,6 +302,7 @@ def paged_attention(
     scale: float,
     softcap: Optional[float] = None,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,  # (H,) float32: a logit a query head
 ) -> jax.Array:
     """GQA attention over a PAGED KV cache (Kwon et al., SOSP '23 layout).
 
@@ -306,7 +320,11 @@ def paged_attention(
     (and be pinned) under JAX_PLATFORMS=cpu; the pallas fusion of this
     gather is a later optimization behind the same signature.
 
-    Returns (B, S, H, hd) in q's dtype.
+    ``sink``: one more column in every softmax's denominator, of that logit
+    for the query's head; it takes mass and adds no value.  The value pages
+    may be narrower than the key pages.
+
+    Returns (B, S, H, value width) in q's dtype.
     """
     b, s, h, hd = q.shape
     page_size, kv = k_pages.shape[1], k_pages.shape[2]
@@ -316,7 +334,7 @@ def paged_attention(
 
     safe_tables = jnp.maximum(block_tables, 0)
     keys = k_pages[safe_tables].reshape(b, t_len, kv, hd)
-    values = v_pages[safe_tables].reshape(b, t_len, kv, hd)
+    values = v_pages[safe_tables].reshape(b, t_len, kv, v_pages.shape[-1])
 
     kpos = jnp.arange(t_len, dtype=jnp.int32)[None, :]  # (1, T)
     k_valid = kpos < lengths[:, None]  # (B, T)
@@ -333,6 +351,8 @@ def paged_attention(
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     logits = jnp.where(mask[:, None, None], logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    if sink is not None:  # one a query head: (KV, reps, 1, 1)
+        sink = sink.reshape(kv, reps)[:, :, None, None]
+    weights = softmax_with_sink(logits, sink).astype(q.dtype)
     attn = jnp.einsum("bgrst,btgd->bsgrd", weights, values)
-    return attn.reshape(b, s, h, hd)
+    return attn.reshape(b, s, h, values.shape[-1])

@@ -77,18 +77,34 @@ def make_mesh(
 #: any unmatched path, so a new param added to the runtime without a layout
 #: decision fails loudly instead of silently replicating (pinned in
 #: tests/test_mesh_serving.py against both tiny models).
+#: A configuration with layers of more than one kind keeps a stack of leaves
+#: a kind, ``layers/<kind>/<leaf>``: the same rule serves the leaf under
+#: either path.  (Such a configuration is refused on a mesh today, by name,
+#: for its caches; its leaves have their rules all the same, so that the
+#: coverage check names every one.)
+_KIND = r"(?:\w+/)?"
 PARTITION_RULES: Tuple[Tuple[str, P], ...] = (
     # Norm vectors replicate (tiny; every shard needs them whole).
-    (r"^layers/(attn_norm|ffn_norm|post_attn_norm|post_ffn_norm)$",
+    (rf"^layers/{_KIND}(attn_norm|ffn_norm|post_attn_norm|post_ffn_norm)$",
      P(None, None)),
     # (L, D, H*hd): split heads (output features) over model.
-    (r"^layers/(wq|wk|wv)$", P(None, None, MODEL_AXIS)),
+    (rf"^layers/{_KIND}(wq|wk|wv)$", P(None, None, MODEL_AXIS)),
     # (L, H*hd, D): split input features — contraction psum follows.
-    (r"^layers/wo$", P(None, MODEL_AXIS, None)),
+    (rf"^layers/{_KIND}wo$", P(None, MODEL_AXIS, None)),
     # (L, D, F): split hidden features.
-    (r"^layers/(w_gate|w_up)$", P(None, None, MODEL_AXIS)),
+    (rf"^layers/{_KIND}(w_gate|w_up)$", P(None, None, MODEL_AXIS)),
     # (L, F, D): split input features.
-    (r"^layers/w_down$", P(None, MODEL_AXIS, None)),
+    (rf"^layers/{_KIND}w_down$", P(None, MODEL_AXIS, None)),
+    # A window layer's sinks (L, H), one a query head: with the heads.
+    (rf"^layers/{_KIND}attn_sink$", P(None, MODEL_AXIS)),
+    # The router (L, D, experts) and its bias replicate: every shard routes
+    # over all the experts.
+    (rf"^layers/{_KIND}(router|router_bias)$", P()),
+    # Held experts (L, E, D, F) and (L, E, F, D): the expert axis whole on
+    # every shard, an expert's hidden features split as a dense layer's.
+    (rf"^layers/{_KIND}(experts_gate|experts_up)$",
+     P(None, None, None, MODEL_AXIS)),
+    (rf"^layers/{_KIND}experts_down$", P(None, None, MODEL_AXIS, None)),
     # (V, D): shard vocab rows; logits come out sharded over vocab.
     (r"^(embed|lm_head)$", P(MODEL_AXIS, None)),
     (r"^final_norm$", P(None)),

@@ -59,7 +59,7 @@ from consensus_tpu.serve.scheduler import (
     RequestTimeout,
     SchedulerRejected,
 )
-from consensus_tpu.models.config import RecurrentStateUnsupported
+from consensus_tpu.models.config import ConfigurationUnsupported
 from consensus_tpu.serve.service import RequestValidationError, parse_request
 
 logger = logging.getLogger(__name__)
@@ -270,10 +270,11 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
             except SchedulerRejected as exc:
                 status = self._send_rejection(exc, request_id=request_id)
                 return
-            except RecurrentStateUnsupported as exc:
-                # The served configuration has recurrent layers and this
-                # method needs a program that cannot carry their state:
-                # the client's to change, so a client error that names both.
+            except ConfigurationUnsupported as exc:
+                # The served configuration has recurrent layers, or layers
+                # of more than one kind, and this method needs a program
+                # that cannot run them: the client's to change, so a client
+                # error that names both.
                 status = 400
                 self._send_json(400, {"error": {
                     "type": "method_unsupported_for_model",
