@@ -574,30 +574,38 @@ class DecodeEngine:
             item.trace = trace
             item.span = trace.begin(
                 f"engine_{kind}", parent=parent, rows=len(item.requests))
-        # Tokenising every row's prompt for the page accounting happens
-        # under the condition the loop waits on: the span says how long.
         with use_trace(item.trace, item.span), span(
             "engine.enqueue", kind=kind, rows=len(item.requests)
-        ), self._work:
-            if self._stopped:
-                raise RuntimeError("decode engine is closed")
+        ):
+            groups: Dict[Any, List[_Row]] = {}
             if kind == "generate":
-                # Equal prompt tokens within the call make a group; on the
-                # stream path (pages per row) every row is its own.
+                # The prompts' ids for the page accounting, once a distinct
+                # text, and the groups they make (equal prompt tokens within
+                # the call; on the stream path, pages per row, every row is
+                # its own): all of it before the condition the loop waits on
+                # is taken, which is held for the queue alone.
                 streams = self._streams()
-                groups: Dict[Any, List[_Row]] = {}
+                ids_of: Dict[str, Tuple[List[Any], Any]] = {}
                 for i, req in enumerate(item.requests):
-                    row = _Row(item, i, req, self._prompt_token_ids(req))
+                    text = self._prompt_text(req)
+                    if text not in ids_of:
+                        ids = self._tokenize_text(text)
+                        ids_of[text] = ids, tuple(ids)
+                    ids, key = ids_of[text]
+                    row = _Row(item, i, req, ids)
                     if item.trace is not None:
                         row.trace = item.trace
                         row.span = item.trace.begin(
                             "engine_row", parent=item.span, row=i)
-                    key = i if streams else tuple(row.prompt_ids)
-                    groups.setdefault(key, []).append(row)
-                self._gen_backlog.extend(groups.values())
-            else:
-                self._other[kind].append(item)
-            self._work.notify_all()
+                    groups.setdefault(i if streams else key, []).append(row)
+            with self._work:
+                if self._stopped:
+                    raise RuntimeError("decode engine is closed")
+                if kind == "generate":
+                    self._gen_backlog.extend(groups.values())
+                else:
+                    self._other[kind].append(item)
+                self._work.notify_all()
         item.event.wait()
         if item.trace is not None:
             item.trace.end(
@@ -1568,25 +1576,31 @@ class DecodeEngine:
 
     # -- token accounting ----------------------------------------------------
 
-    def _prompt_token_ids(self, request) -> List[Any]:
+    @staticmethod
+    def _prompt_text(request) -> str:
         parts = [
             getattr(request, "system_prompt", None) or "",
             getattr(request, "user_prompt", "") or "",
         ]
-        return self._tokenize_text(" ".join(p for p in parts if p))
+        return " ".join(p for p in parts if p)
 
     def _count_text_tokens(self, text: str) -> int:
         return len(self._tokenize_text(text))
 
     def _tokenize_text(self, text: str) -> List[Any]:
         """Tokens for PAGE accounting and prefix-cache CONTENT KEYS only —
-        never for numerics.  Uses the inner backend's real tokenizer when
-        it has one; the fake backend's whitespace pseudo-tokenizer
-        otherwise."""
-        tok = getattr(self.inner, "tokenizer", None)
-        if tok is not None and hasattr(tok, "encode"):
+        never for numerics.  Asks the inner backend for its ids of the text
+        where it hands them out (``token_ids``: a text it has encoded, for
+        this accounting or for a call, is not encoded again); else uses its
+        real tokenizer when it has one, and the fake backend's whitespace
+        pseudo-tokenizer otherwise."""
+        encode = getattr(self.inner, "token_ids", None)
+        if not callable(encode):
+            encode = getattr(
+                getattr(self.inner, "tokenizer", None), "encode", None)
+        if callable(encode):
             try:
-                return list(tok.encode(text))
+                return list(encode(text))
             except Exception:
                 pass
         pseudo = getattr(self.inner, "_tokenize", None)

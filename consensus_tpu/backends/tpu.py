@@ -32,7 +32,9 @@ reference's server-side seeds.
 
 from __future__ import annotations
 
+import collections
 import functools
+import hashlib
 import logging
 import pathlib
 import threading
@@ -110,6 +112,17 @@ _SESSION_MIN_BUDGET_BYTES = 1 * 1024**3
 #: names and 15 blocks a row that the rows' lengths mask.
 _KINDS_POOL_STEP_PAGES = 256
 _KINDS_TABLE_STEP_BLOCKS = 16
+
+#: Characters of text whose token ids a backend remembers
+#: (``TPUBackend.token_ids``), the least recently asked for going first.  A
+#: statement asks for the same two prompts, 4-5 agent contexts and 32
+#: candidates some 190 times over (the engine's accounting, 32 rows of one
+#: prompt, two score matrices, the embedder): some 60k characters, and a
+#: sweep sends a scenario's prompts again under every seed.  Two million
+#: characters hold every request in flight and the scenarios of a sweep, in
+#: at most 20 MB (a byte tokenizer's id a character); a constant and no
+#: option, because nothing a caller can observe depends on it.
+_TOKEN_MEMO_CHARS = 2 * 1024 * 1024
 
 
 class _SessionBudget:
@@ -432,6 +445,13 @@ class TPUBackend:
             self.mesh_plan = None
 
         self._bias_id_cache: Dict[str, Tuple[int, ...]] = {}
+        # ``token_ids``' memo: text -> ids without BOS, oldest first; the
+        # request threads (through the engine's ``submit``) and the engine's
+        # thread both ask.
+        self._token_memo: "collections.OrderedDict[str, Tuple[int, ...]]" = (
+            collections.OrderedDict())
+        self._token_memo_chars = 0
+        self._token_memo_lock = threading.Lock()
         # obs: padding efficiency per (kind, rows, width) bucket, compile-
         # cache events per padded program shape, H2D/D2H transfer timings —
         # recorded into the process registry (metrics.json / bench extra).
@@ -577,6 +597,43 @@ class TPUBackend:
         return out
 
 
+    def token_ids(
+        self,
+        text: str,
+        add_bos: bool = False,
+        tally: Optional[Dict[str, int]] = None,
+    ) -> List[int]:
+        """The tokenizer's ids of ``text``, encoded once however often they
+        are asked for: every tokenisation of the serving path comes here (a
+        call's rows over one prompt, a score matrix's contexts and
+        candidates, the embedder, the engine's page accounting).  The memo
+        is keyed by the text alone and holds the ids without BOS: both
+        tokenizers put ``bos_id`` in front of what they encode without it,
+        and so does this, so the embedder reuses what the scorer encoded.
+        The list is the caller's own.  ``tally`` (a ``backend.tokenize``
+        span's late attributes) counts under ``encoded`` the texts the
+        tokenizer ran on.  Safe under any threads; two that ask for a new
+        text at once may both encode it."""
+        with self._token_memo_lock:
+            ids = self._token_memo.get(text)
+            if ids is not None:
+                self._token_memo.move_to_end(text)
+        fresh = ids is None
+        if fresh:
+            ids = tuple(self.tokenizer.encode(text))
+            if len(text) <= _TOKEN_MEMO_CHARS:
+                with self._token_memo_lock:
+                    if text not in self._token_memo:
+                        self._token_memo[text] = ids
+                        self._token_memo_chars += len(text)
+                    while self._token_memo_chars > _TOKEN_MEMO_CHARS:
+                        gone, _ = self._token_memo.popitem(last=False)
+                        self._token_memo_chars -= len(gone)
+        self.instruments.record_tokenized(fresh)
+        if tally is not None:
+            tally["encoded"] = tally.get("encoded", 0) + int(fresh)
+        return [self.tokenizer.bos_id, *ids] if add_bos else list(ids)
+
     def _fit(self, ids: List[int], width: int) -> List[int]:
         """``ids`` cut to its most recent ``width`` tokens.  A cut is counted
         and logged, never silent: the caller's prompt lost its beginning."""
@@ -698,32 +755,34 @@ class TPUBackend:
                 vector[token_id] += bias_value
         return vector
 
-    def _fold_seed(self, *parts) -> jax.Array:
+    @staticmethod
+    def _fold_of(*parts) -> int:
         # Stable across processes (Python's hash() is salted per process).
-        import hashlib
-
         digest = hashlib.blake2b(repr(parts).encode(), digest_size=4).digest()
-        fold = int.from_bytes(digest, "big") % (2**31)
-        return jax.random.fold_in(jax.random.PRNGKey(self.base_seed), fold)
+        return int.from_bytes(digest, "big") % (2**31)
+
+    def _fold_seed(self, *parts) -> jax.Array:
+        return jax.random.fold_in(
+            jax.random.PRNGKey(self.base_seed), self._fold_of(*parts))
 
     def _row_keys(self, kind: str, seeds: Sequence[Optional[int]]) -> jnp.ndarray:
         """Per-row PRNG keys. Seeded rows fold only their own seed (batch-
         composition independent, VERDICT r1 #7).  Unseeded rows must stay
         DIVERSE — identical unseeded prompts in one batch (best_of_n drafts,
         habermas candidates) each need a distinct stream — so they fold their
-        row index plus a per-backend nonce instead."""
-        keys = []
+        row index plus a per-backend nonce instead.  The folds are hashed
+        here and folded into the base key by one program for all the rows
+        (``_fold_keys``): row for row what ``_fold_seed`` returns."""
+        folds = []
         for row, seed in enumerate(seeds):
             if seed is None:
                 with self._nonce_lock:
                     self._unseeded_calls += 1
                     nonce = self._unseeded_calls
-                keys.append(
-                    self._fold_seed(kind, "unseeded", row, nonce)
-                )
+                folds.append(self._fold_of(kind, "unseeded", row, nonce))
             else:
-                keys.append(self._fold_seed(kind, seed))
-        return jnp.stack(keys)
+                folds.append(self._fold_of(kind, seed))
+        return _fold_keys(self.base_seed, np.asarray(folds, np.uint32))
 
     # -- generate ------------------------------------------------------------
 
@@ -902,9 +961,10 @@ class TPUBackend:
             return []
 
         if token_lists is None:
-            with span("backend.tokenize", rows=len(requests)):
+            with span("backend.tokenize", rows=len(requests)) as tally:
                 token_lists = [
-                    self.tokenizer.encode(self._render_prompt(r), add_bos=True)
+                    self.token_ids(
+                        self._render_prompt(r), add_bos=True, tally=tally)
                     for r in requests
                 ]
         if self.shared_trunk_generation:
@@ -1199,7 +1259,7 @@ class TPUBackend:
                 if truncated:
                     # Keep token_ids consistent with the truncated text so token
                     # counts/ids downstream match what the caller sees.
-                    ids = self.tokenizer.encode(text)
+                    ids = self.token_ids(text)
                 self.token_counts["generated"] += len(ids)
                 results.append(
                     GenerationResult(text=text, token_ids=tuple(ids), finish_reason=finish)
@@ -1240,19 +1300,19 @@ class TPUBackend:
         if not self.shared_context_scoring:
             return self._sliced(requests, self._score_impl)
         prepared = []
-        # Memoize the prefix encoding: a P-candidate group shares one
-        # identical ~1k-token context — the workload this path dedupes —
-        # so tokenize it once, not P times (ADVICE r2).
+        # A P-candidate group shares one identical ~1k-token context (the
+        # workload this path dedupes): ``token_ids`` encodes it once, and
+        # the group's rows share one list of its ids (ADVICE r2).
         prefix_ids: Dict[str, List[int]] = {}
         for request in requests:
             prefix = self._score_prefix(request)
             if prefix not in prefix_ids:
-                prefix_ids[prefix] = self.tokenizer.encode(prefix, add_bos=True)
+                prefix_ids[prefix] = self.token_ids(prefix, add_bos=True)
             prepared.append(
                 (
                     prefix,
                     prefix_ids[prefix],
-                    self.tokenizer.encode(request.continuation),
+                    self.token_ids(request.continuation),
                 )
             )
         by_prefix: Dict[str, List[int]] = {}
@@ -1412,9 +1472,9 @@ class TPUBackend:
             if prepared is not None:
                 context_ids, continuation_ids = prepared[i]
             else:
-                prefix = self._score_prefix(request)
-                context_ids = self.tokenizer.encode(prefix, add_bos=True)
-                continuation_ids = self.tokenizer.encode(request.continuation)
+                context_ids = self.token_ids(
+                    self._score_prefix(request), add_bos=True)
+                continuation_ids = self.token_ids(request.continuation)
             rows.append(context_ids + continuation_ids)
             spans.append((len(context_ids), len(continuation_ids)))
 
@@ -1546,15 +1606,17 @@ class TPUBackend:
 
         # Tokenize once per unique rendered agent prefix (agents routinely
         # share the issue framing) and once per candidate.
-        with span("backend.tokenize", rows=n_candidates + n_agents):
+        with span("backend.tokenize", rows=n_candidates + n_agents) as tally:
             prefix_ids: Dict[str, List[int]] = {}
             agent_prefixes: List[str] = []
             for agent in request.agents:
                 prefix = self._score_prefix(agent.to_score_request(""))
                 if prefix not in prefix_ids:
-                    prefix_ids[prefix] = self.tokenizer.encode(prefix, add_bos=True)
+                    prefix_ids[prefix] = self.token_ids(
+                        prefix, add_bos=True, tally=tally)
                 agent_prefixes.append(prefix)
-            cont_ids = [self.tokenizer.encode(c) for c in request.candidates]
+            cont_ids = [
+                self.token_ids(c, tally=tally) for c in request.candidates]
         max_cont = max(len(c) for c in cont_ids)
         if any(
             len(ids) + max_cont > self.max_context
@@ -1780,37 +1842,37 @@ class TPUBackend:
         max_n0 = max(shared[p][2] for p in pre)
         chunk = min(256, _bucket(max_n0, minimum=ps))
         n_blocks = self._table_blocks(max(shared[p][1] for p in pre))
+        n_pre = len(pre)
+        placed_at = np.array([shared[p] for p in pre], np.int32)
+        firsts, npgs, n0s = (placed_at[:, i : i + 1] for i in range(3))
+        blocks = np.arange(n_blocks, dtype=np.int32)
         tables = np.full((n_rows, n_blocks), -1, np.int32)
-        for r, p in enumerate(pre):
-            first, npg, _ = shared[p]
-            tables[r, :npg] = np.arange(first, first + npg, dtype=np.int32)
-        tables[len(pre):] = tables[0]
+        tables[:n_pre] = np.where(blocks < npgs, firsts + blocks, -1)
+        tables[n_pre:] = tables[0]
         pad_id = self.tokenizer.pad_id
         for k in range(0, max_n0, chunk):
             with span("backend.layout", rows=n_rows, width=chunk):
+                # Column j of the chunk is position k + j of every context:
+                # live in a row while below the context's page boundary.
+                pos = np.arange(k, k + chunk, dtype=np.int32)
+                live = pos < n0s
                 tokens = np.full((n_rows, chunk), pad_id, np.int32)
                 valid = np.zeros((n_rows, chunk), bool)
                 lengths = np.zeros((n_rows,), np.int32)
                 write_pages = np.full((n_rows, chunk), sink, np.int32)
                 write_offsets = np.zeros((n_rows, chunk), np.int32)
+                valid[:n_pre] = live
+                lengths[:n_pre] = np.minimum(n0s[:, 0], k + chunk)  # n0 at the end
+                write_pages[:n_pre] = np.where(live, firsts + pos // ps, sink)
+                write_offsets[:n_pre] = np.where(live, pos % ps, 0)
                 for r, p in enumerate(pre):
-                    ids = prefix_ids[p]
-                    first, _, n0 = shared[p]
-                    hi = min(n0, k + chunk)
-                    lengths[r] = hi  # == n0 once the row is complete
-                    if hi <= k:
-                        continue
-                    piece = ids[k:hi]
-                    valid[r, : len(piece)] = True
+                    piece = prefix_ids[p][k : lengths[r]]
                     tokens[r, : len(piece)] = piece
-                    for j in range(len(piece)):
-                        write_pages[r, j] = first + (k + j) // ps
-                        write_offsets[r, j] = (k + j) % ps
                 # Pad rows ride row 0's shape (valid positions, table) but
                 # write only to the sink — never a real page.
-                tokens[len(pre):] = tokens[0]
-                valid[len(pre):] = valid[0]
-                lengths[len(pre):] = lengths[0]
+                tokens[n_pre:] = tokens[0]
+                valid[n_pre:] = valid[0]
+                lengths[n_pre:] = lengths[0]
                 self.instruments.record_launch("score_matrix_prefill", (n_rows, chunk))
             # lengths is rank-1: jit's in-program constraint shards it.
             placed = self._place_batch(
@@ -1834,6 +1896,24 @@ class TPUBackend:
         ps = self._SCORE_PAGE_SIZE
         pad_id = self.tokenizer.pad_id
         with span("backend.layout", rows=n_rows, width=width):
+            # Row r of the chunk re-feeds its context's tail (from the page
+            # boundary n0 on) and its candidate less the last token: column
+            # j is position n0 + j of the row's stream, written into the
+            # row's own pages and scored against the stream's next token.
+            n_real = len(chunk)
+            snapshot_of = {p: i for i, p in enumerate(prefix_ids)}
+            plan = np.array(
+                [(*shared[prefix], q_len, n_private)
+                 for prefix, _, q_len, n_private in chunk], np.int32)
+            firsts, npgs, n0s, q_lens, n_privates = (
+                plan[:, i : i + 1] for i in range(5))
+            bases = (shared_total + max_private
+                     * np.arange(n_real, dtype=np.int32))[:, None]
+            cols = np.arange(width, dtype=np.int32)
+            live = cols < q_lens
+            pos = n0s + cols
+            blocks = np.arange(max_blocks, dtype=np.int32)
+
             tokens = np.full((n_rows, width), pad_id, np.int32)
             targets = np.zeros((n_rows, width), np.int32)
             score_mask = np.zeros((n_rows, width), bool)
@@ -1842,33 +1922,26 @@ class TPUBackend:
             lengths = np.zeros((n_rows,), np.int32)
             write_pages = np.full((n_rows, width), sink, np.int32)
             write_offsets = np.zeros((n_rows, width), np.int32)
-            snapshot_of = {p: i for i, p in enumerate(prefix_ids)}
             ssm_rows = np.zeros((n_rows,), np.int32)
-            for r, (prefix, cont, q_len, n_private) in enumerate(chunk):
+            chunk_valid[:n_real] = live
+            lengths[:n_real] = (n0s + q_lens)[:, 0]
+            tables[:n_real] = np.where(
+                blocks < npgs, firsts + blocks,
+                np.where(blocks < npgs + n_privates, bases + blocks - npgs, -1))
+            write_pages[:n_real] = np.where(
+                live, bases + pos // ps - n0s // ps, sink)
+            write_offsets[:n_real] = np.where(live, pos % ps, 0)
+            for r, (prefix, cont, q_len, _) in enumerate(chunk):
                 ids = prefix_ids[prefix]
-                first, npg, n0 = shared[prefix]
+                tail = ids[n0s[r, 0] :] + cont
+                tokens[r, :q_len] = tail[:q_len]
+                target = tail[1 : q_len + 1]  # a column short with no candidate
+                targets[r, : len(target)] = target
+                lo = len(ids) - 1 - n0s[r, 0]
+                score_mask[r, lo : lo + len(cont)] = True
                 ssm_rows[r] = snapshot_of[prefix]
-                stream = ids + cont
-                block = stream[n0 : n0 + q_len]
-                tokens[r, : q_len] = block
-                chunk_valid[r, : q_len] = True
-                lengths[r] = n0 + q_len
-                tables[r, :npg] = np.arange(first, first + npg, dtype=np.int32)
-                base = shared_total + r * max_private
-                tables[r, npg : npg + n_private] = np.arange(
-                    base, base + n_private, dtype=np.int32
-                )
-                for j in range(q_len):
-                    pos = n0 + j
-                    write_pages[r, j] = base + pos // ps - n0 // ps
-                    write_offsets[r, j] = pos % ps
-                    if pos + 1 < len(stream):
-                        targets[r, j] = stream[pos + 1]
-                lo = len(ids) - 1 - n0
-                score_mask[r, lo : lo + len(cont)] = bool(cont)
             # Pad rows duplicate row 0 (well-defined positions/attention) but
             # write to the sink and score nothing.
-            n_real = len(chunk)
             tokens[n_real:] = tokens[0]
             targets[n_real:] = targets[0]
             chunk_valid[n_real:] = chunk_valid[0]
@@ -1876,9 +1949,7 @@ class TPUBackend:
             tables[n_real:] = tables[0]
             ssm_rows[n_real:] = ssm_rows[0]
             self.instruments.record_padding(
-                "score_matrix", n_rows, width,
-                sum(q for (_, _, q, _) in chunk),
-            )
+                "score_matrix", n_rows, width, int(q_lens.sum()))
             self.instruments.record_launch("score_matrix", (n_rows, width))
             if self.config.has_ssm:  # each row starts from its context's state
                 self.instruments.record_state_fork(
@@ -1913,7 +1984,7 @@ class TPUBackend:
         self.token_counts["scored"] += len(requests)
 
         token_lists = [
-            self.tokenizer.encode(self._render_prompt(r), add_bos=True)
+            self.token_ids(self._render_prompt(r), add_bos=True)
             for r in requests
         ]
         # Row bucketing (see _generate_impl): beam/MCTS candidate counts
@@ -2058,8 +2129,9 @@ class TPUBackend:
         self, texts: Sequence[str], rows: Optional[int] = None
     ) -> np.ndarray:
         self.call_counts["embed"] += len(texts)
-        with span("backend.tokenize", rows=len(texts)):
-            token_lists = [self.tokenizer.encode(t, add_bos=True) for t in texts]
+        with span("backend.tokenize", rows=len(texts)) as tally:
+            token_lists = [
+                self.token_ids(t, add_bos=True, tally=tally) for t in texts]
         with span("backend.layout", rows=len(texts)):
             # (A batch held to fewer than 8 rows is padded to those rows.)
             pad_rows = _bucket(
@@ -2077,6 +2149,14 @@ class TPUBackend:
         hidden = self._fetch(pooled)[: len(texts)]
         norms = np.linalg.norm(hidden, axis=1, keepdims=True)
         return hidden / np.maximum(norms, 1e-12)
+
+
+@functools.partial(jax.jit, static_argnames=("base_seed",))
+def _fold_keys(base_seed: int, folds):
+    """``fold_in(PRNGKey(base_seed), fold)`` for every fold, as (rows, 2)
+    ``uint32``: one program for all of a call's rows."""
+    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        jax.random.PRNGKey(base_seed), folds)
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
@@ -2159,7 +2239,7 @@ class _PagedGenerateStream:
         be.call_counts["generate"] += len(requests)
         tok = be.tokenizer
         prompt_ids = [
-            be._fit(tok.encode(be._render_prompt(r), add_bos=True),
+            be._fit(be.token_ids(be._render_prompt(r), add_bos=True),
                     be.max_context)
             for r in requests
         ]
@@ -2432,7 +2512,7 @@ class _PagedGenerateStream:
                     finish = "stop"
                     truncated = True
         if truncated:
-            ids = be.tokenizer.encode(text)
+            ids = be.token_ids(text)
         be.token_counts["generated"] += len(ids)
         return GenerationResult(
             text=text, token_ids=tuple(ids), finish_reason=finish
@@ -2475,7 +2555,7 @@ class TPUTokenSearchSession:
             tok.raw_prompt(a_user, a_system)
             for a_system, a_user in spec.agent_prompts
         ]
-        token_lists = [tok.encode(p, add_bos=True) for p in prefixes]
+        token_lists = [backend.token_ids(p, add_bos=True) for p in prefixes]
         max_prefix = backend.max_context - spec.max_steps
         if max_prefix < 16:
             # A negative/zero budget would flip the slice below into keeping

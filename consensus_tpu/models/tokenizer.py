@@ -42,7 +42,9 @@ class Tokenizer(Protocol):
     bos_id: int
     eos_ids: Tuple[int, ...]
 
-    def encode(self, text: str, add_bos: bool = False) -> List[int]: ...
+    def encode(self, text: str, add_bos: bool = False) -> List[int]:
+        """The ids of ``text``; with ``add_bos``, ``bos_id`` and then the
+        same ids (``TPUBackend.token_ids`` keeps them once for both)."""
 
     def decode(self, ids: Sequence[int]) -> str: ...
 

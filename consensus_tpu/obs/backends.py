@@ -122,6 +122,13 @@ class BackendInstruments:
             "one kind of attention (all, full, window).",
             labels=("backend", "kind"),
         )
+        self._tokenized = reg.counter(
+            "backend_tokenize_texts_total",
+            "Texts whose token ids the backend was asked for: those the "
+            "tokenizer ran on (encoded) and those answered from the ids of "
+            "an earlier asking (reused).",
+            labels=("backend", "outcome"),
+        )
         self._seen_lock = threading.Lock()
         self._seen_shapes: Set[Tuple[str, Tuple[int, ...]]] = set()
 
@@ -141,6 +148,12 @@ class BackendInstruments:
         allocated = rows * width if allocated_tokens is None else allocated_tokens
         self._useful.labels(self.backend, kind, rows, width).inc(useful_tokens)
         self._allocated.labels(self.backend, kind, rows, width).inc(allocated)
+
+    def record_tokenized(self, encoded: bool) -> None:
+        """One text's ids handed out: the tokenizer ran (``encoded``) or an
+        earlier asking's ids were reused."""
+        self._tokenized.labels(
+            self.backend, "encoded" if encoded else "reused").inc()
 
     # -- compile cache -------------------------------------------------------
 

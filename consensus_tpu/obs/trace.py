@@ -396,9 +396,14 @@ def _annotation(name: str, attrs: Dict[str, Any]):
 
 
 @contextlib.contextmanager
-def span(name: str, traces: Optional[_Pairs] = None, **attrs: Any) -> Iterator[None]:
+def span(
+    name: str, traces: Optional[_Pairs] = None, **attrs: Any
+) -> Iterator[Dict[str, Any]]:
     """Name the host work inside the ``with``: ``name`` is one of
-    ``HOST_SPANS``, ``attrs`` are numbers and short strings.
+    ``HOST_SPANS``, ``attrs`` are numbers and short strings.  What the work
+    learns only as it runs goes into the dictionary the ``with`` yields and
+    onto the request's span when it ends (the profiler's annotation carries
+    ``attrs`` alone).
 
     Where this thread works for a request (``use_trace``, or an enclosing
     ``span``), a child span is begun and ended in that request's tree and
@@ -414,14 +419,15 @@ def span(name: str, traces: Optional[_Pairs] = None, **attrs: Any) -> Iterator[N
         (trace, trace.begin(name, parent=parent, **attrs), parent)
         for trace, parent in traces
     ]
+    late: Dict[str, Any] = {}
     try:
         # A span the cap dropped (id 0) leaves its parent in charge.
         with _carry([(t, sid or parent) for t, sid, parent in opened]), \
                 _annotation(name, attrs):
-            yield
+            yield late
     finally:
         for trace, sid, _ in opened:
-            trace.end(sid)
+            trace.end(sid, **late)
 
 
 # ---------------------------------------------------------------------------

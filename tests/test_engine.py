@@ -21,7 +21,11 @@ import time
 
 import pytest
 
-from consensus_tpu.backends.base import GenerationRequest, RequestCancelled
+from consensus_tpu.backends.base import (
+    GenerationRequest,
+    GenerationResult,
+    RequestCancelled,
+)
 from consensus_tpu.backends.batching import BatchingBackend
 from consensus_tpu.backends.engine import DecodeEngine
 from consensus_tpu.backends.fake import FakeBackend
@@ -752,6 +756,83 @@ class TestCohortsFormByPrompt:
                 r.text for r in FakeBackend().generate(requests)]
         assert stats["kv_pages_reserved"] == 0 and stats["slots_occupied"] == 0
         assert engine.pool.in_use == 0
+
+    @pytest.mark.parametrize("inner_kind", ["fake", "tpu"])
+    def test_submit_asks_for_a_prompts_ids_once_and_forms_the_same_groups(
+            self, inner_kind, monkeypatch):
+        """The page accounting tokenises a distinct prompt once a call,
+        before it takes the condition the loop waits on, and the rows of
+        one prompt share the ids and the group: on the fake's
+        pseudo-tokenizer and through ``TPUBackend.token_ids``, where the
+        call then adds two encodings in all (the accounting's text and the
+        rendered prompt) however many rows it has."""
+        from paged_capture import CountingTokenizer
+
+        asked = []
+        if inner_kind == "fake":
+            class Counting(FakeBackend):
+                def _tokenize(self, text):
+                    asked.append(text)
+                    return super()._tokenize(text)
+
+            inner = Counting()
+            ids_of = FakeBackend()._tokenize
+        else:
+            from consensus_tpu.backends.tpu import TPUBackend
+            from consensus_tpu.models.tokenizer import ByteTokenizer
+
+            inner = TPUBackend(model="tiny-gemma2", max_context=256)
+            inner.tokenizer = CountingTokenizer(inner.tokenizer)
+            asked = inner.tokenizer.texts
+            ids_of = ByteTokenizer().encode
+            launched = []
+
+            def no_device(requests, prompt_ids):
+                launched.append((len(requests), list(prompt_ids)))
+                return [GenerationResult(text="x", token_ids=(1,))
+                        for _ in requests]
+
+            monkeypatch.setattr(inner, "_generate_shared", no_device)
+        calls = [_group(PROMPT_A, 32), _group(PROMPT_A, 16) + _group(PROMPT_B, 2, seed=50)]
+        engine = DecodeEngine(
+            inner, slots=8, page_size=4, num_pages=2048, auto_start=False)
+        try:
+            held_while_tokenising = []
+            tokenize_text = engine._tokenize_text
+
+            def noting(text):
+                if threading.current_thread() is not threading.main_thread():
+                    held_while_tokenising.append(engine._work._is_owned())
+                return tokenize_text(text)  # (the loop's own asks: stepped here)
+
+            monkeypatch.setattr(engine, "_tokenize_text", noting)
+            thread, out = _submit_async(engine, calls[0])
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 32)
+            assert asked == [PROMPT_A]
+            (group,) = engine._gen_backlog
+            assert [row.index for row in group] == list(range(32))
+            assert all(row.prompt_ids == ids_of(PROMPT_A) for row in group)
+            for _ in range(40):
+                if out:
+                    break
+                engine.run_iteration()
+            thread.join(timeout=5.0)
+            assert len(out["result"]) == 32
+            if inner_kind == "tpu":
+                rendered = inner.tokenizer.chat_prompt(PROMPT_A, None)
+                assert asked == [PROMPT_A, rendered]
+                assert launched == [(32, ids_of(rendered, add_bos=True))]
+            del asked[:]
+            thread, out = _submit_async(engine, calls[1])
+            assert _wait_until(lambda: engine.stats()["queue_depth"] == 18)
+            # the first call's prompt is the backend's to remember, not the fake's
+            assert asked == ([PROMPT_B] if inner_kind == "tpu" else [PROMPT_A, PROMPT_B])
+            assert [[row.index for row in g] for g in engine._gen_backlog] == [
+                list(range(16)), [16, 17]]
+            assert held_while_tokenising == [False] * 3
+        finally:
+            engine.close()
+        thread.join(timeout=5.0)
 
     @pytest.mark.parametrize("decode_steps, resident_rows, reserved", [
         # one blocking generate: the prompt's 2 pages once, 3 pages a row

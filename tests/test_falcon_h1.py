@@ -713,6 +713,36 @@ def test_best_of_n_answers_through_the_engine(server):
     assert engine.recurrent and engine._state_pages > 0
 
 
+def test_metrics_serves_how_many_texts_were_encoded_and_how_many_reused(server):
+    """``backend_tokenize_texts_total`` over a second request of the same
+    issue and opinions under another seed: its prompts and contexts were
+    encoded for the first, so of the texts it asks for most are reused."""
+    import re
+    import urllib.request
+
+    def counts():
+        with urllib.request.urlopen(server.base_url + "/metrics") as response:
+            text = response.read().decode()
+        found = dict.fromkeys(("encoded", "reused"), 0.0)
+        for outcome, value in re.findall(
+                r'^backend_tokenize_texts_total\{[^}]*outcome="(\w+)"[^}]*\} (\S+)$',
+                text, re.MULTILINE):
+            found[outcome] += float(value)
+        return found
+
+    before = counts()
+    assert before["encoded"] > 0 and before["reused"] > 0
+    status, body = _post(server, {
+        "method": "best_of_n", "issue": "How should the city change transport?",
+        "agent_opinions": _OPINIONS, "seed": 6,
+        "params": {"n": 4, "max_tokens": 12}})
+    assert status == 200, body
+    after = counts()
+    encoded = after["encoded"] - before["encoded"]
+    reused = after["reused"] - before["reused"]
+    assert 0 < encoded <= reused, (before, after)
+
+
 @pytest.mark.parametrize("method", ["beam_search", "mcts"])
 def test_a_token_search_method_answers_a_client_error_that_names_it(server, method):
     status, body = _post(server, {

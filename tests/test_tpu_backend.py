@@ -14,6 +14,7 @@ from consensus_tpu.backends.base import (
     ScoreRequest,
 )
 from consensus_tpu.backends.tpu import TPUBackend
+from consensus_tpu.models.tokenizer import ByteTokenizer
 
 ISSUE = "Should the town build a new playground?"
 
@@ -334,3 +335,161 @@ class TestGenerateChunking:
         chunked = backend.generate(requests)
         assert [r.text for r in whole] == [r.text for r in chunked]
         assert backend.call_counts["generate"] == 12  # 6 + 6, not double-counted
+
+
+class TestATextIsTokenisedOnce:
+    """Every tokenisation of the serving path asks ``TPUBackend.token_ids``:
+    a text is encoded once however many rows, matrices and callers ask for
+    its ids, and what they get is what the tokenizer gives."""
+
+    AGENTS = ["Buses matter most to agent %d, who has more to say. " % a * (a + 1)
+              for a in range(5)]
+    CANDIDATES = ["candidate statement number %d %s" % (c, "and more " * (c % 5))
+                  for c in range(32)]
+
+    @pytest.fixture()
+    def fresh(self, backend):
+        """A backend of its own memo over the module's weights, with a
+        tokenizer that notes what it is asked to encode."""
+        from paged_capture import CountingTokenizer
+
+        fresh = TPUBackend(config=backend.config, params=backend.params,
+                           max_context=1024, base_seed=0)
+        fresh.tokenizer = CountingTokenizer(fresh.tokenizer)
+        return fresh
+
+    @staticmethod
+    def _tokenize_counts():
+        from consensus_tpu.obs.metrics import get_registry
+
+        family = get_registry().snapshot()["families"].get(
+            "backend_tokenize_texts_total", {"series": []})
+        counts = {"encoded": 0, "reused": 0}
+        counts.update({s["labels"]["outcome"]: s["value"]
+                       for s in family["series"] if s["labels"]["backend"] == "tpu"})
+        return counts
+
+    def _matrix(self):
+        from consensus_tpu.backends.score_matrix import (
+            AgentContext,
+            ScoreMatrixRequest,
+        )
+
+        return ScoreMatrixRequest(
+            agents=tuple(AgentContext(context=a, system_prompt="Judge.")
+                         for a in self.AGENTS),
+            candidates=tuple(self.CANDIDATES))
+
+    def test_a_call_of_32_rows_over_one_prompt_encodes_one_text(
+            self, fresh, monkeypatch):
+        plain = ByteTokenizer()
+        launched = []
+
+        def no_device(requests, prompt_ids):
+            launched.append(list(prompt_ids))
+            return [None] * len(requests)
+
+        monkeypatch.setattr(fresh, "_generate_shared", no_device)
+        requests = [GenerationRequest(user_prompt=ISSUE, system_prompt="Be brief.",
+                                      max_tokens=4, seed=i) for i in range(32)]
+        before = self._tokenize_counts()
+        fresh.generate(requests)
+        rendered = plain.chat_prompt(ISSUE, "Be brief.")
+        assert fresh.tokenizer.texts == [rendered]
+        assert launched == [plain.encode(rendered, add_bos=True)]
+        after = self._tokenize_counts()
+        assert after["encoded"] - before["encoded"] == 1
+        assert after["reused"] - before["reused"] == 31
+
+    def test_a_matrix_encodes_each_text_once_and_a_second_matrix_none(
+            self, fresh, monkeypatch):
+        from paged_capture import captured_matrix
+
+        plain = ByteTokenizer()
+        request = self._matrix()
+        seen = captured_matrix(fresh, request, monkeypatch)
+        prefixes = [fresh._score_prefix(a.to_score_request(""))
+                    for a in request.agents]
+        assert sorted(fresh.tokenizer.texts) == sorted(prefixes + self.CANDIDATES)
+        # Row for row what the plain tokenizer gives: a row re-feeds its
+        # context from the last page boundary on, then its candidate less
+        # the last token (candidate-major, an agent a row).
+        page = fresh._SCORE_PAGE_SIZE
+        tokens = np.concatenate([c["tokens"] for c in seen["chunks"]])
+        rows = [(p, c) for c in self.CANDIDATES for p in prefixes]
+        for r in (0, 1, 63, 64, 159):
+            ids = plain.encode(rows[r][0], add_bos=True)
+            stream = (ids + plain.encode(rows[r][1]))[(len(ids) - 1) // page * page:-1]
+            assert tokens[r, : len(stream)].tolist() == stream
+        del fresh.tokenizer.texts[:]
+        captured_matrix(fresh, request, monkeypatch)
+        fresh.embed(self.CANDIDATES[:4])  # with the BOS, from the ids without
+        assert fresh.tokenizer.texts == []
+
+    def test_the_ids_are_the_tokenizers_and_the_callers_own(self, fresh):
+        plain = ByteTokenizer()
+        text = "[USER]What now?[/USER]\n[ASSISTANT] café <eos> and on"
+        for add_bos in (False, True, False):
+            ids = fresh.token_ids(text, add_bos=add_bos)
+            assert ids == plain.encode(text, add_bos=add_bos)
+            ids.append(-1)  # a caller's list is its own
+        assert fresh.tokenizer.texts == [text]
+        tally = {}
+        fresh.token_ids(text, tally=tally)
+        fresh.token_ids(text + "!", tally=tally)
+        assert tally == {"encoded": 1}
+
+    def test_the_memo_stays_within_its_bound(self, fresh, monkeypatch):
+        from consensus_tpu.backends import tpu
+
+        monkeypatch.setattr(tpu, "_TOKEN_MEMO_CHARS", 100)
+        texts = ["text %02d " % i * 3 for i in range(12)]  # 24 characters each
+        for text in texts:
+            fresh.token_ids(text)
+            held = list(fresh._token_memo)
+            assert sum(map(len, held)) == fresh._token_memo_chars <= 100
+        assert held == texts[-4:]  # the least recently asked for went first
+        fresh.token_ids(texts[-4])  # asked again: the last to go now
+        fresh.token_ids(texts[0])   # gone: encoded again, and the oldest goes
+        assert list(fresh._token_memo) == [texts[-2], texts[-1], texts[-4], texts[0]]
+        assert fresh.tokenizer.texts == texts + [texts[0]]
+        fresh.token_ids("x" * 101)  # longer than the bound: never held
+        assert "x" * 101 not in fresh._token_memo
+        assert fresh.token_ids("x" * 101) == ByteTokenizer().encode("x" * 101)
+
+    def test_threads_get_the_same_ids(self, fresh, monkeypatch):
+        """More threads than cores over a memo too small for their texts, so
+        that hits, evictions and double encodings interleave: every answer
+        is the tokenizer's and the books balance."""
+        import sys
+        import threading
+
+        from consensus_tpu.backends import tpu
+
+        monkeypatch.setattr(tpu, "_TOKEN_MEMO_CHARS", 400)
+        plain = ByteTokenizer()
+        texts = ["thread text %02d " % i * 4 for i in range(12)]  # 64 each
+        want = {t: plain.encode(t, add_bos=True) for t in texts}
+        wrong, threads = [], []
+
+        def ask(seed):
+            order = np.random.default_rng(seed).integers(0, len(texts), 300)
+            for i in order:
+                if fresh.token_ids(texts[i], add_bos=True) != want[texts[i]]:
+                    wrong.append(texts[i])
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=ask, args=(s,)) for s in range(16)]
+            for thread in threads:
+                thread.start()
+            for thread in threads:
+                thread.join(timeout=60.0)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(thread.is_alive() for thread in threads)
+        assert not wrong
+        held = list(fresh._token_memo)
+        assert sum(map(len, held)) == fresh._token_memo_chars <= 400
+        assert len(set(held)) == len(held) == 6

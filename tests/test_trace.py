@@ -285,6 +285,16 @@ class TestSpan:
             assert trace_current() == (trace, root)
         assert trace.dropped_spans == 1
 
+    def test_what_the_body_learns_goes_onto_the_span_when_it_ends(self):
+        trace = TraceContext("s-6")
+        root = trace.begin("http_request")
+        with use_trace(trace, root), span("backend.tokenize", rows=3) as late:
+            assert late == {}
+            late["encoded"] = 2
+        with span("backend.tokenize", rows=1) as late:  # no trace: no one reads
+            late["encoded"] = 1
+        assert trace.to_dict()["spans"][1]["attrs"] == {"rows": 3, "encoded": 2}
+
     def test_ends_its_span_when_the_body_raises(self):
         trace = TraceContext("s-5")
         root = trace.begin("http_request")
@@ -803,6 +813,11 @@ class TestRequestSpanTree:
             if row["name"] in HOST_SPANS:
                 for value in row["attrs"].values():  # never a prompt
                     assert isinstance(value, (int, float)) or len(value) < 64
+        # what a tokenising span learnt as it ran: texts the tokenizer ran on
+        tokenised = [s["attrs"] for s in spans if s["name"] == "backend.tokenize"]
+        assert all(0 <= a["encoded"] <= a["rows"] for a in tokenised)
+        if backend == "tpu":  # the two rows of one prompt: one text encoded
+            assert {"rows": 2, "encoded": 1} in tokenised
         path = trace.critical_path()
         assert abs(sum(path["phases"].values()) - path["total_s"]) < 1e-4
         assert path["phases"]["engine_wait"] > 0.0
