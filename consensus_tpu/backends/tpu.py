@@ -461,7 +461,7 @@ class TPUBackend:
             for name, n, heads in self.config.cache_kinds:
                 self.instruments.record_kv_bytes_per_token(
                     name, n * heads * itemsize
-                    * (self.config.head_dim + self.config.value_dim))
+                    * sum(self.config.cache_widths(name)))
             self.instruments.record_kv_bytes_per_token(
                 "all", self.config.kv_bytes_per_token(itemsize))
         self.call_counts = {
@@ -526,9 +526,10 @@ class TPUBackend:
             ("state", "recurrent" if self.config.has_ssm else "pages"),
         ) + ((
             # A pool a kind of attention, each at its own heads and widths:
-            # (kind, layers, key-value heads, key width, value width).
+            # (kind, layers, key-value heads, key width, value width; a
+            # latent pool's value width is 0, it keeps none).
             ("kinds", tuple(
-                (name, n, heads, self.config.head_dim, self.config.value_dim)
+                (name, n, heads) + self.config.cache_widths(name)
                 for name, n, heads in self.config.cache_kinds)),
         ) if self.config.has_layer_kinds else ())
 
@@ -1085,6 +1086,9 @@ class TPUBackend:
                 "generate_shared",
                 (target, width, max_new, int(segmented), int(bias_table is not None)),
             )
+            # The one trunk's prefill, then a step a token over trunk and tail.
+            self._record_latent(1, width, width)
+            self._record_latent(target, 1, width + max_new, steps=max_new)
             if self.config.has_ssm:  # one trunk's state, forked to every row
                 self.instruments.record_state_fork(
                     "generate", target, target * self._recurrent_row_bytes())
@@ -1182,6 +1186,8 @@ class TPUBackend:
                 "generate",
                 (target, width, max_new, int(segmented), int(bias_table is not None)),
             )
+            self._record_latent(target, width, width)
+            self._record_latent(target, 1, width + max_new, steps=max_new)
             token_lists = list(token_lists) + [[]] * pad_rows
             tokens, valid = self._left_pad_batch(token_lists)
             kwargs = dict(
@@ -1217,6 +1223,25 @@ class TPUBackend:
         self.instruments.record_moe(
             held, rows * self.config.experts_per_token,
             passes * self.config.experts_held[1])
+
+    def _record_latent(
+        self, rows: int, queries: int, keys: int, steps: int = 1
+    ) -> None:
+        """A launch of ``rows`` rows x ``queries`` query positions a row over
+        ``keys`` gathered cache positions a row, ``steps`` times, into the
+        latent layers' counters, from those shapes alone: the form is the
+        one ``transformer.latent_form`` gives the program for them.  Nothing
+        without latent layers."""
+        if not self.config.has_latent:
+            return
+        from consensus_tpu.models.transformer import latent_form
+
+        layers = steps * sum(
+            n for name, n, _ in self.config.cache_kinds if name == "latent")
+        form = latent_form(queries)
+        self.instruments.record_mla(
+            form, rows * queries * layers,
+            rows * keys * layers if form == "expanded" else 0)
 
     def _finish_generation(
         self,
@@ -1393,6 +1418,7 @@ class TPUBackend:
         ctx_width = self.max_context
         self.instruments.record_padding("score_trunk", 1, ctx_width, len(ctx_ids))
         self.instruments.record_launch("score_trunk", (1, ctx_width))
+        self._record_latent(1, ctx_width, ctx_width)
         pad = self.tokenizer.pad_id
         ctx_tokens = np.full((1, ctx_width), pad, np.int32)
         ctx_tokens[0, : len(ctx_ids)] = ctx_ids
@@ -1427,6 +1453,7 @@ class TPUBackend:
             "score_shared", n_rows, width, sum(len(c) for c in conts)
         )
         self.instruments.record_launch("score_shared", (n_rows, width))
+        self._record_latent(n_rows, width - 1, self.max_context + width - 1)
         pad = self.tokenizer.pad_id
         cont_tokens = np.full((n_rows, width), pad, np.int32)
         cont_valid = np.zeros((n_rows, width), bool)
@@ -1522,6 +1549,7 @@ class TPUBackend:
             sum(min(len(r), width) for r in rows[: len(requests)]),
         )
         self.instruments.record_launch("score", (len(rows), width))
+        self._record_latent(len(rows), width, width)
         tokens_dev, valid_dev = self._place_batch(tokens, valid)
         logprobs = self._fetch(
             scorer(self.params, self.config, tokens_dev, valid_dev)
@@ -1808,11 +1836,15 @@ class TPUBackend:
             # with the most heads; and a routed layer's block of rows: every
             # assignment's row gathered and returned, its gate, up and their
             # product, and the float32 rows the weighted sum reads.
-            from consensus_tpu.models.transformer import _MOE_BLOCK_ROWS
+            from consensus_tpu.models.transformer import (
+                _MOE_BLOCK_ROWS,
+                latent_form,
+            )
 
-            attention += n_rows * keys * itemsize * (
-                max(kv for _, _, kv in c.cache_kinds)
-                * (c.head_dim + c.value_dim))
+            attention += n_rows * keys * itemsize * max(
+                kv * sum(c.cache_widths(name)) for name, _, kv in c.cache_kinds)
+            if c.has_latent and latent_form(width) == "expanded":
+                attention += n_rows * keys * self._expanded_position_bytes()
             if c.swa_sink:  # the shares beside the sink's, float32 too
                 attention += cells * c.n_heads * keys * 4
             if c.has_moe:
@@ -1874,6 +1906,7 @@ class TPUBackend:
                 valid[n_pre:] = valid[0]
                 lengths[n_pre:] = lengths[0]
                 self.instruments.record_launch("score_matrix_prefill", (n_rows, chunk))
+                self._record_latent(n_rows, chunk, n_blocks * ps)
             # lengths is rank-1: jit's in-program constraint shards it.
             placed = self._place_batch(
                 tokens, valid, tables, write_pages, write_offsets
@@ -1951,6 +1984,7 @@ class TPUBackend:
             self.instruments.record_padding(
                 "score_matrix", n_rows, width, int(q_lens.sum()))
             self.instruments.record_launch("score_matrix", (n_rows, width))
+            self._record_latent(n_rows, width, int(tables.shape[1]) * ps)
             if self.config.has_ssm:  # each row starts from its context's state
                 self.instruments.record_state_fork(
                     "score_matrix", n_real,
@@ -2026,6 +2060,7 @@ class TPUBackend:
             "next_token",
             (len(token_lists), width, k, int(bias_table is not None)),
         )
+        self._record_latent(len(token_lists), width, width)
         # Device-side selection: only (B, k) ids+logprobs cross the wire
         # (VERDICT r1 #6) — never the (B, 256k) logit matrix.
         ids, logprobs = next_token_topk(
@@ -2095,16 +2130,37 @@ class TPUBackend:
         ] or [np.zeros((0, self.config.d_model), np.float32)]
         return np.vstack(pieces)
 
+    def _expanded_position_bytes(self) -> int:
+        """What the expanded form of latent attention holds for one gathered
+        position: every head's ``c W_kvb`` as it is made, then its keys with
+        the rotary key beside them and its values (at 4,096 keys 84 MB a row
+        to keep, 151 MB while they are made)."""
+        c = self.config
+        itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
+        return itemsize * c.n_heads * (
+            c.qk_nope_dim + c.value_dim + c.head_dim + c.value_dim)
+
     def _dense_attention_bytes(self, rows: int, width: int, keys: int) -> int:
         """What the einsum attention of one layer holds at once for ``rows``
         x ``width`` queries over ``keys`` keys: float32 logits and their
         weights in the activations' type, for the query heads it takes at a
         time.  That is all of them, but with layers of more than one kind:
         ``forward`` then attends a key-value group at a time, and the widest
-        group counts."""
+        group counts; with latent attention in the expanded form a head at a
+        time, beside the keys and values made of the latents."""
         c = self.config
         itemsize = jnp.dtype(self.params["embed"].dtype).itemsize
         heads = c.n_heads
+        if c.has_latent:
+            from consensus_tpu.models.transformer import latent_form
+
+            if latent_form(width) == "expanded":
+                # A head is its own key-value group, and every head's keys
+                # and values of the rows' latents stand beside its logits.
+                return rows * keys * (
+                    width * (4 + itemsize) + self._expanded_position_bytes())
+            # Absorbed: one key-value head, every query head over it at once.
+            return rows * width * heads * keys * (4 + itemsize)
         if c.has_layer_kinds:
             heads = c.n_heads // min(kv for _, _, kv in c.cache_kinds)
         return rows * width * heads * keys * (4 + itemsize)
@@ -2144,6 +2200,7 @@ class TPUBackend:
                 sum(min(len(t), width) for t in token_lists[: len(texts)]),
             )
             self.instruments.record_launch("embed", (len(token_lists), width))
+            self._record_latent(len(token_lists), width, width)
         with span("backend.launch", program="_embed_forward"):
             pooled = _embed_forward(self.params, self.config, tokens, valid)
         hidden = self._fetch(pooled)[: len(texts)]

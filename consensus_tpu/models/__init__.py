@@ -32,11 +32,20 @@ MODEL_PHASES = ("prefill", "decode_step")
 #: ``moe_dispatch`` (the rows' assignments sorted and gathered by expert, or
 #: the mask of a decode step's few rows), ``moe_experts`` (the held experts'
 #: three products) and ``moe_combine`` (each row's weighted sum of what its
-#: assignments returned, and the residual).
+#: assignments returned, and the residual); ``moe_shared`` is the expert every
+#: row passes, where the configuration has one.
+#: A latent layer's attention (logits, mask, softmax, values, with the page
+#: gather where there is one) is ``attention_latent``, in either form;
+#: ``mla_absorb`` is the absorbed form's two products with the heads' key and
+#: value matrices (the queries folded before, the weighted latents unfolded
+#: after) and ``mla_expand`` the expanded form's product that makes every
+#: head's keys and values of the gathered latents.  ``kv_write`` stays one
+#: name: a latent layer writes one buffer there.
 MODEL_SCOPES = (
     "embed", "layers", "attn_qkv", "kv_write", "attention", "attn_out", "ffn",
     "final_norm", "vocab_projection", "logsumexp", "sample",
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "state_fork",
     "attention_window", "moe_router", "moe_dispatch", "moe_experts",
     "moe_combine",
+    "mla_absorb", "mla_expand", "attention_latent", "moe_shared",
 ) + MODEL_PHASES

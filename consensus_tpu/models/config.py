@@ -47,7 +47,8 @@ STREAM_NEEDS_STATE = ("the stream path's slots hold pages by position and no "
 #: Why the same programs, the prefix cache, the Pallas attention kernels, the
 #: int8 paths and a tensor-parallel mesh refuse layers of more than one kind.
 NEEDS_ONE_KIND = ("it holds one cache of one shape for every layer, and this "
-                  "configuration keeps a cache for each kind of attention")
+                  "configuration keeps a cache for each kind of attention (a "
+                  "latent kind's is one buffer of latents, not keys and values)")
 KERNEL_NEEDS_PLAIN_HEADS = ("the Pallas attention kernels take no sink logit "
                             "and no value heads narrower than the key heads")
 
@@ -56,7 +57,7 @@ class LayerKind(NamedTuple):
     """What one run of equal layers is, as static data of the layer loop."""
 
     name: str  # the key of the kind's stack of weights under ``layers``
-    attention: str  # "full" or "window": the cache the layer addresses
+    attention: str  # "full", "window" or "latent": the cache it addresses
     kv_heads: int
     rope_theta: float
     window: Optional[int]  # keys a query sees at most; None = all before it
@@ -183,6 +184,29 @@ class ModelConfig:
     experts_per_token: int = 0
     expert_hidden: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    # -- latent attention and a shared expert (JoyAI-LLM-Flash) ---------------
+    # ``kv_lora_rank`` 0: none, as before.  Set: every layer's attention is
+    # latent (``hybrid_layer_pattern`` all zeros).  Queries come through a
+    # bottleneck of ``q_lora_rank`` with a norm inside it; keys and values
+    # from one latent of ``kv_lora_rank`` a token, with a norm, and one
+    # rotary key of ``qk_rope_dim`` shared by all heads.  A head's keys are
+    # ``qk_nope_dim`` columns made from the latent beside the rotary key
+    # (``head_dim`` is their sum), its values ``v_head_dim`` columns made
+    # from the latent.  A token leaves the latent and the rotary key behind
+    # and nothing else (``cache_widths``).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    # The rotary embedding turns adjacent pairs (2i, 2i+1) of a latent
+    # layer's rotary columns, not the pairs (i, i + half).
+    rope_interleave: bool = False
+    # Experts every row passes, beside the routed ones: one gated
+    # feed-forward of ``n_shared_experts`` x ``expert_hidden``.
+    n_shared_experts: int = 0
+    # The routed experts' sum is multiplied by this; the shared expert's
+    # part is not.
+    routed_scaling_factor: Optional[float] = None
 
     def __post_init__(self):
         if self.hybrid_layer_pattern:
@@ -211,6 +235,31 @@ class ModelConfig:
                     "routed layers need n_experts, experts_per_token, "
                     "expert_hidden and experts_held = (first, count) inside "
                     "the router's width")
+        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_dim,
+                  self.qk_rope_dim)
+        if any(latent) or self.rope_interleave:
+            if not all(latent) or not self.v_head_dim:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_dim, qk_rope_dim and v_head_dim, all of them "
+                    "(rope_interleave is a latent layer's)")
+            if self.head_dim != self.qk_nope_dim + self.qk_rope_dim:
+                raise ValueError(
+                    f"head_dim={self.head_dim} is not qk_nope_dim + "
+                    f"qk_rope_dim ({self.qk_nope_dim} + {self.qk_rope_dim})")
+            if (not self.hybrid_layer_pattern or any(self.hybrid_layer_pattern)
+                    or self.rotary_dim is not None or self.qk_rope_dim % 2
+                    or self.value_scale is not None
+                    or self.attn_softcap is not None):
+                raise ValueError(
+                    "latent attention comes as a hybrid_layer_pattern of "
+                    "zeros (every layer latent), with an even qk_rope_dim "
+                    "and no rotary_dim, value_scale or attn_softcap")
+        if (self.n_shared_experts or self.routed_scaling_factor is not None
+                ) and not any(self.moe_layer_freq):
+            raise ValueError(
+                "n_shared_experts and routed_scaling_factor need routed "
+                "layers (moe_layer_freq)")
         if self.ssm_heads and self.ssm_inner != self.ssm_heads * self.ssm_head_dim:
             raise ValueError(
                 f"ssm_inner={self.ssm_inner} is not ssm_heads x ssm_head_dim "
@@ -262,9 +311,30 @@ class ModelConfig:
         return any(self.moe_layer_freq)
 
     @property
+    def has_latent(self) -> bool:
+        """Latent attention: a token leaves one latent and one rotary key
+        behind, in one buffer, and no values of their own."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """What a token of a latent layer leaves behind: [latent | rotary
+        key]."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
     def value_dim(self) -> int:
         """Width of a value head."""
         return self.v_head_dim or self.head_dim
+
+    def cache_widths(self, attention: Optional[str]) -> Tuple[int, int]:
+        """(columns of a head of the key buffer, columns of a head of the
+        value buffer) of a kind of attention's cache.  A latent cache keeps
+        no value buffer: its values are the keys' first ``kv_lora_rank``
+        columns."""
+        if attention == "latent":
+            return self.latent_dim, 0
+        return self.head_dim, self.value_dim
 
     @property
     def layer_kinds(self) -> Tuple[LayerKind, ...]:
@@ -273,11 +343,14 @@ class ModelConfig:
         kinds = []
         for window, moe in zip(self.hybrid_layer_pattern, routed):
             attention = "window" if window else "full"
+            kv_heads = ((self.swa_kv_heads or self.n_kv_heads) if window
+                        else self.n_kv_heads)
+            if self.has_latent:  # one head of ``latent_dim`` in the cache
+                attention, kv_heads = "latent", 1
             kinds.append(LayerKind(
                 name=f"{attention}_{'moe' if moe else 'dense'}",
                 attention=attention,
-                kv_heads=(self.swa_kv_heads or self.n_kv_heads) if window
-                else self.n_kv_heads,
+                kv_heads=kv_heads,
                 rope_theta=float(
                     (self.swa_rope_theta or self.rope_theta) if window
                     else self.rope_theta),
@@ -313,7 +386,8 @@ class ModelConfig:
     def cache_kinds(self) -> Tuple[Tuple[str, int, int], ...]:
         """(kind of attention, its layers, its key-value heads), in order of
         first appearance: the caches a configuration with ``has_layer_kinds``
-        holds.  Without: one cache, named ``None``."""
+        holds (``cache_widths`` has a head's columns; a latent kind is one
+        head and no value buffer).  Without: one cache, named ``None``."""
         if not self.has_layer_kinds:
             return ((None, self.n_layers, self.n_kv_heads),)
         layers, heads = {}, {}
@@ -324,10 +398,11 @@ class ModelConfig:
 
     def kv_bytes_per_token(self, itemsize: float) -> float:
         """Bytes of one position's keys and values over all layers: the sum
-        over the kinds of attention, each at its own heads and widths."""
+        over the kinds of attention, each at its own heads and widths (a
+        latent position once: it has no values of its own)."""
         return sum(
-            n * heads * (self.head_dim + self.value_dim) * itemsize
-            for _, n, heads in self.cache_kinds)
+            n * heads * sum(self.cache_widths(name)) * itemsize
+            for name, n, heads in self.cache_kinds)
 
     @property
     def q_scale(self) -> float:
@@ -503,6 +578,38 @@ MODEL_CONFIGS = {
         experts_per_token=8,
         expert_hidden=32,
         experts_held=(8, 8),
+    ),
+    # JoyAI-LLM-Flash's layers at a test's size: latent attention in every
+    # layer (queries through a bottleneck, one latent and one shared rotary
+    # key a token, adjacent-pair rotary), a leading dense layer and two
+    # routed ones with a shared expert and a factor on the routed sum, every
+    # one of the 8 experts held.  The published sizes live in
+    # benchmark/configs/.
+    "tiny-joyai-flash": _llama3(
+        "tiny-joyai-flash",
+        vocab_size=320,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        head_dim=24,
+        ffn_hidden=128,
+        rope_theta=32e6,
+        rms_eps=1e-6,
+        hybrid_layer_pattern=(0, 0, 0),
+        moe_layer_freq=(0, 1, 1),
+        v_head_dim=16,
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_dim=16,
+        qk_rope_dim=8,
+        rope_interleave=True,
+        n_experts=8,
+        experts_per_token=2,
+        expert_hidden=32,
+        experts_held=(0, 8),
+        n_shared_experts=1,
+        routed_scaling_factor=2.5,
     ),
 }
 

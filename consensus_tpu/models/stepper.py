@@ -693,7 +693,10 @@ class PagedSlotState(NamedTuple):
 
     #: With layers of more than one kind, a pool a kind of attention:
     #: ``{"full": (its layers, pages + 1, page, its KV, hd), "window": ...}``
-    #: (values at the value heads' width), one table of page ids for all.
+    #: (values at the value heads' width), one table of page ids for all.  A
+    #: latent kind's pool is one buffer: ``k_pages["latent"]`` is (its layers,
+    #: pages + 1, page, 1, latent + rotary key) and ``v_pages["latent"]`` is
+    #: None, not a second copy.
     k_pages: jax.Array
     v_pages: jax.Array
     ssm: Optional[SSMState] = None
@@ -810,34 +813,43 @@ def _paged_forward(
     def as_pages(pool):
         return pool.reshape((-1,) + pool.shape[2:])
 
-    def call_paged(window, q, k_pages, v_pages, layer, sink=None):
+    def call_paged(window, q, k_pages, v_pages, layer, sink=None, latent=None):
         if gather_from_a_copy:
-            pools = layer_of(k_pages, layer), layer_of(v_pages, layer)
+            pools = [None if pool is None else layer_of(pool, layer)
+                     for pool in (k_pages, v_pages)]
             tables = block_tables
         else:
-            pools = as_pages(k_pages), as_pages(v_pages)
+            pools = [None if pool is None else as_pages(pool)
+                     for pool in (k_pages, v_pages)]
             tables = jnp.maximum(block_tables, 0) + layer * pages_a_layer
+        own = {} if sink is None else {"sink": sink}
+        if latent is not None:
+            own["latent"] = latent
         return paged_attention(
             q, *pools, tables, lengths, positions,
-            scale=c.q_scale, softcap=c.attn_softcap, window=window,
-            **({} if sink is None else {"sink": sink}),
-        )
+            scale=c.q_scale, softcap=c.attn_softcap, window=window, **own)
 
-    def attend(q, k, v, _, pools, layer, is_local, sink=None):
+    def attend(q, k, v, _, pools, layer, is_local, sink=None, latent=None):
         """This call's K/V scattered into the pages the cursors name; every
-        query attends through its slot's block table."""
+        query attends through its slot's block table.  (A latent layer's
+        pool is one buffer, its value side None: ``latent`` makes keys and
+        values of the pages gathered.)"""
         # Cursor pairs are unique across rows (slots own disjoint pages)
         # except the sink, which is never read, so duplicate-index order
         # doesn't matter.
         with jax.named_scope("kv_write"):
             at = write_pages + layer * pages_a_layer
             k_pages, v_pages = (
+                None if pool is None else
                 as_pages(pool).at[at, write_offsets].set(new).reshape(pool.shape)
                 for pool, new in zip(pools, (k, v)))
-        with attention_scope(is_local):
-            attn = windowed(
-                c, is_local, call_paged, q, k_pages, v_pages, layer,
-                *(() if sink is None else (sink,)))
+        with attention_scope(is_local, latent):
+            if latent is not None:  # no window, no sink: nothing to choose
+                attn = call_paged(None, q, k_pages, None, layer, latent=latent)
+            else:
+                attn = windowed(
+                    c, is_local, call_paged, q, k_pages, v_pages, layer,
+                    *(() if sink is None else (sink,)))
         return attn, None, (k_pages, v_pages)
 
     x, held, written, new_ssm = scan_layers(

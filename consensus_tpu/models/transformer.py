@@ -156,25 +156,51 @@ def _init_kind_params(c: ModelConfig, key: jax.Array, dtype) -> Params:
         return (jax.random.normal(k, shape) * shape[-2] ** -0.5).astype(dtype)
 
     def by_expert(k, n, *shape):
+        # An expert at a time into its place in (layers of the kind, held,
+        # ...): the float32 draw that lives at once is one expert's, whatever
+        # is held.  (All of them under one ``vmap`` and a ``moveaxis`` after
+        # it were 6.4 GB of float32 and a 3.2 GB copy a leaf at 256 held
+        # experts of 4 x 2,048 x 768.)  The bits are the ``vmap``'s.
         first, count = c.experts_held
-        drawn = jax.vmap(
-            lambda e: dense(jax.random.fold_in(k, e), n, *shape)
-        )(first + jnp.arange(count))
-        return jnp.moveaxis(drawn, 0, 1)  # (layers of the kind, held, ...)
+
+        def place(e, stack):
+            drawn = dense(jax.random.fold_in(k, first + e), n, *shape)
+            return jax.lax.dynamic_update_index_in_dim(stack, drawn, e, 1)
+
+        return jax.lax.fori_loop(
+            0, count, place, jnp.zeros((n, count) + shape, dtype))
 
     layers = {}
     for index, (kind, n) in enumerate(c.kind_layers):
         keys = jax.random.split(
             jax.random.fold_in(key, _KIND_KEY_BASE + index), 12)
         kv = kind.kv_heads
-        leaves = {
-            "attn_norm": norm_init((n, d), dtype),
-            "wq": dense(keys[0], n, d, h * hd),
-            "wk": dense(keys[1], n, d, kv * hd),
-            "wv": dense(keys[2], n, d, kv * vd),
-            "wo": dense(keys[3], n, h * vd, d),
-            "ffn_norm": norm_init((n, d), dtype),
-        }
+        if kind.attention == "latent":
+            # Queries through a bottleneck with a norm inside it; one latent
+            # and one rotary key a token (``w_kva``), the latent normed; a
+            # head's [keys without position | values] made from the latent
+            # (``w_kvb``).  The last two of the twelve keys are this kind's.
+            leaves = {
+                "attn_norm": norm_init((n, d), dtype),
+                "w_qa": dense(keys[0], n, d, c.q_lora_rank),
+                "q_norm": norm_init((n, c.q_lora_rank), dtype),
+                "w_qb": dense(keys[1], n, c.q_lora_rank, h * hd),
+                "w_kva": dense(keys[2], n, d, c.latent_dim),
+                "kv_norm": norm_init((n, c.kv_lora_rank), dtype),
+                "w_kvb": dense(keys[10], n, c.kv_lora_rank,
+                               h * (c.qk_nope_dim + vd)),
+                "wo": dense(keys[3], n, h * vd, d),
+                "ffn_norm": norm_init((n, d), dtype),
+            }
+        else:
+            leaves = {
+                "attn_norm": norm_init((n, d), dtype),
+                "wq": dense(keys[0], n, d, h * hd),
+                "wk": dense(keys[1], n, d, kv * hd),
+                "wv": dense(keys[2], n, d, kv * vd),
+                "wo": dense(keys[3], n, h * vd, d),
+                "ffn_norm": norm_init((n, d), dtype),
+            }
         if kind.sink:
             leaves["attn_sink"] = jax.random.normal(keys[4], (n, h))
         if kind.routed:
@@ -188,6 +214,15 @@ def _init_kind_params(c: ModelConfig, key: jax.Array, dtype) -> Params:
                 "experts_up": by_expert(keys[8], n, d, f),
                 "experts_down": by_expert(keys[9], n, f, d),
             })
+            if c.n_shared_experts:
+                fs = c.n_shared_experts * f
+                gate, up, down = (
+                    jax.random.fold_in(keys[11], i) for i in range(3))
+                leaves.update({
+                    "shared_gate": dense(gate, n, d, fs),
+                    "shared_up": dense(up, n, d, fs),
+                    "shared_down": dense(down, n, fs, d),
+                })
         else:
             leaves.update({
                 "w_gate": dense(keys[7], n, d, c.ffn_hidden),
@@ -315,6 +350,95 @@ def rope_heads(
     return jnp.concatenate([turned, x[..., c.rotary_dim :]], axis=-1)
 
 
+def rope_latent(
+    c: ModelConfig, x: jax.Array, positions: jax.Array, theta: float
+) -> jax.Array:
+    """The rotary turn of a latent layer's rotary columns, (B, S, H,
+    ``qk_rope_dim``).  With ``rope_interleave`` the pairs turned are adjacent
+    columns (2i, 2i+1): they are brought side by side first, (i, i + half),
+    and stay so.  A permutation of the rotary columns that queries and keys
+    share changes no score, and nothing but a score reads them."""
+    if c.rope_interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, theta, c.rope_scaling)
+
+
+#: Query positions a row up to which a latent layer attends in the absorbed
+#: form; a call with more takes the expanded form.  Absorbed is 2 x (2 x 512 +
+#: 64) = 2,176 FLOPs a query-key-head after 8.4 MFLOP a *query* and reads a
+#: key's 576 cached numbers as they lie; expanded is 2 x (192 + 128) = 640
+#: FLOPs a query-key-head after 8.4 MFLOP a *key*, 20 KB a key written and
+#: read back.  One query a row (a decode step, ``forward_trunk_tail``'s tail):
+#: absorbed, 0.15 GFLOP a row a layer at 2k keys against 16.8 to expand them.
+#: A paged chunk of 128 or 256 queries over the 1-4k keys of its tables
+#: (the score chunk, the paged prefill): by FLOPs the expanded form (27 GFLOP
+#: a row a layer against 36 at 256 over 2k), and on a v5e the absorbed one is
+#: the faster by 5% of the whole program a layer: the einsum attention is
+#: bound by what it writes, and the expanded keys and values of 16 rows x
+#: 2,048 positions are 1.3 GB written and read a layer.  A prefill over its
+#: own keys (the 4,096-wide trunk, an embedded text of 1,024): as many keys as
+#: queries, so expanding costs what absorbing does and every pair is 3.4 times
+#: cheaper: expanded, by 19% and 11%.  (``scripts/mla_form_bench.py``, one
+#: layer at the published widths; PERF.md 5 has the readings.)
+_MLA_ABSORBED_QUERIES = 256
+
+
+def latent_form(queries: int) -> str:
+    """``"absorbed"`` or ``"expanded"``: the form in which a latent layer
+    attends for a call of ``queries`` query positions a row.  From the
+    call's shapes alone, here and nowhere else."""
+    return "absorbed" if queries <= _MLA_ABSORBED_QUERIES else "expanded"
+
+
+def _kvb_heads(c: ModelConfig, w_kvb: jax.Array) -> jax.Array:
+    """``w_kvb`` a head: (latent, H, [keys without position | values])."""
+    return w_kvb.reshape(
+        c.kv_lora_rank, c.n_heads, c.qk_nope_dim + c.value_dim)
+
+
+def mla_absorb(c: ModelConfig, w_kvb: jax.Array, q: jax.Array) -> jax.Array:
+    """The absorbed form's queries: (..., H, ``head_dim``) -> (..., H,
+    ``latent_dim``), a head's part without position folded through the
+    head's key matrix, ``q_nope (W_UK)^T``, so that its product with a cached
+    [latent | rotary key] is the score; the rotary part passes."""
+    with jax.named_scope("mla_absorb"):
+        w_uk = _kvb_heads(c, w_kvb)[..., : c.qk_nope_dim]
+        folded = jnp.einsum("...hd,rhd->...hr", q[..., : c.qk_nope_dim], w_uk)
+        return jnp.concatenate([folded, q[..., c.qk_nope_dim :]], axis=-1)
+
+
+def mla_absorb_out(c: ModelConfig, w_kvb: jax.Array, attn: jax.Array) -> jax.Array:
+    """The absorbed form's values: a head's weighted sum of latents (..., H,
+    ``kv_lora_rank``) through the head's value matrix, ``o' W_UV``."""
+    with jax.named_scope("mla_absorb"):
+        w_uv = _kvb_heads(c, w_kvb)[..., c.qk_nope_dim :]
+        return jnp.einsum("...hr,rhd->...hd", attn, w_uv)
+
+
+def mla_expand(c: ModelConfig, w_kvb: jax.Array, latents: jax.Array):
+    """The expanded form's keys and values of the positions a call gathered:
+    (..., 1, ``latent_dim``) -> ((..., H, ``head_dim``), (..., H, value
+    width)): ``c W_kvb`` a head, the one rotary key beside every head's
+    keys."""
+    with jax.named_scope("mla_expand"):
+        cached = latents[..., 0, :]
+        made = jnp.einsum(
+            "...r,rhd->...hd", cached[..., : c.kv_lora_rank],
+            _kvb_heads(c, w_kvb))
+        rotary = jnp.broadcast_to(
+            cached[..., None, c.kv_lora_rank :],
+            made.shape[:-1] + (c.qk_rope_dim,))
+        keys = jnp.concatenate([made[..., : c.qk_nope_dim], rotary], axis=-1)
+        return keys, made[..., c.qk_nope_dim :]
+
+
+def latent_view(c: ModelConfig, latents: jax.Array):
+    """The absorbed form's keys and values of the positions a call gathered:
+    the cached [latent | rotary key] themselves, one head, and as values
+    their first ``kv_lora_rank`` columns.  A view, not a second copy."""
+    return latents, latents[..., : c.kv_lora_rank]
+
+
 def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
     if cap is None:
         return x
@@ -413,11 +537,16 @@ MOE_TALLY = ("held", "rows", "layer_passes")
 #: them, every expert on every row: the experts' matrices are read whole
 #: either way, and on a v5e reading one layer's 16 (0.8 GB, 1 ms) costs what
 #: the masked product of 256 rows does (16 x 256 x 50 MFLOP).  Past it the
-#: rows are grouped by expert and each expert multiplies its own.
+#: rows are grouped by expert and each expert multiplies its own.  Both sides
+#: grow with the experts held and with an expert's size, so the number holds
+#: whatever is held: 2 bytes a parameter at 819 GB/s against 2 FLOPs a
+#: parameter a row at 197 TFLOP/s meet at 240 rows (256 held experts of
+#: 3 x 2,048 x 768: 2.4 GB, 2.9 ms to read, 0.6 TFLOP, 3.1 ms at 256 rows).
 _MOE_MASKED_ROWS = 256
 #: Rows a grouped product takes at a time: ``experts_per_token`` assignments
 #: a row are gathered (every one may be to an expert held here), so the
 #: gathered rows of a block are 8 x 4,096 x 4,096 wide x 2 bytes = 268 MB.
+#: The sort's one-hot is (8 x 4,096, held + 1) int32: 34 MB at 256 held.
 _MOE_BLOCK_ROWS = 4096
 
 
@@ -545,7 +674,10 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
     assignment to an absent expert is skipped (what it would have added is
     another chip's to add), none to a held expert is dropped.  The form
     follows the rows, from the shapes: one masked product for a decode step's
-    few, grouped products a block of rows at a time for a span's many.
+    few, grouped products a block of rows at a time for a span's many.  The
+    sum of the routed parts is multiplied by ``routed_scaling_factor`` where
+    the configuration has one, and a shared expert (``shared_*`` of ``lp``)
+    is added beside it, unscaled.
 
     ``lp`` holds the layer's own norm, router and bias, and the experts
     either as the layer's own leaves (``EXPERT_LEAVES``, (held, ...)) or, from
@@ -565,6 +697,11 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
         chosen, weights = route(c, lp, t)
         local = chosen - first
         held = (local >= 0) & (local < count)
+    if c.routed_scaling_factor is not None:
+        # On the routed sum alone, as a factor of the float32 weights that
+        # make it.
+        with jax.named_scope("moe_combine"):
+            weights = weights * c.routed_scaling_factor
     n = t.shape[0]
     if n <= _MOE_MASKED_ROWS:
         part = _experts_masked(stacks, layer, t, local, held, weights)
@@ -583,6 +720,13 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
             lambda block: _experts_grouped(stacks, layer, *block),
             (blocked(t), blocked(local), blocked(held, False), blocked(weights)),
         ).reshape(-1, shape[-1])[:n]
+    if c.n_shared_experts:
+        # The expert every row passes, on the same normed rows; every chip
+        # computes it alike, whatever share of the routed ones it holds.
+        with jax.named_scope("moe_shared"):
+            gate = jax.nn.silu(matmul(t, lp["shared_gate"]))
+            part = part + matmul(gate * matmul(t, lp["shared_up"]),
+                                 lp["shared_down"])
     with jax.named_scope("moe_combine"):
         return x + part.reshape(shape), jnp.stack(
             [jnp.sum(held, dtype=jnp.int32), jnp.int32(n), jnp.int32(1)])
@@ -831,7 +975,9 @@ class KVCache:
     #: (L, B, T, KV, hd) keys and (L, B, T, KV, value width) values.  With
     #: layers of more than one kind, a cache a kind of attention: ``{"full":
     #: (its layers, B, T, its KV, hd), "window": ...}``, the two dictionaries
-    #: alike.
+    #: alike.  A latent kind keeps one buffer: ``k["latent"]`` is (its
+    #: layers, B, T, 1, latent + rotary key) and ``v["latent"]`` is None (the
+    #: values are the keys' first ``kv_lora_rank`` columns).
     k: Any
     v: Any
     key_positions: jax.Array  # (B, T) int32
@@ -857,16 +1003,19 @@ def kv_buffers(
     width)``: the shape of every cache layout (``middle`` is (rows, columns)
     of a dense cache or a tail, (pages, page size) of a pool).  With layers
     of more than one kind each is a dictionary by kind of attention, at that
-    kind's layers and heads (``ModelConfig.cache_kinds``)."""
+    kind's layers, heads and widths (``ModelConfig.cache_kinds``,
+    ``cache_widths``); a latent kind's values are None: one buffer, whose
+    first ``kv_lora_rank`` columns are the values."""
     c = config
 
-    def pair(layers, heads):
-        return (make((layers,) + middle + (heads, c.head_dim), dtype),
-                make((layers,) + middle + (heads, c.value_dim), dtype))
+    def pair(name, layers, heads):
+        return tuple(
+            make((layers,) + middle + (heads, width), dtype) if width else None
+            for width in c.cache_widths(name))
 
     if not c.has_layer_kinds:
-        return pair(c.n_layers, c.n_kv_heads)
-    pairs = {name: pair(n, heads) for name, n, heads in c.cache_kinds}
+        return pair(None, c.n_layers, c.n_kv_heads)
+    pairs = {name: pair(name, n, heads) for name, n, heads in c.cache_kinds}
     return ({name: kv[0] for name, kv in pairs.items()},
             {name: kv[1] for name, kv in pairs.items()})
 
@@ -929,9 +1078,12 @@ def _attention_masks(
     return global_mask, local_mask
 
 
-def attention_scope(is_local):
+def attention_scope(is_local, latent=None):
     """The scope of a layer's attention.  A run of window layers, whose window
-    is known when it is traced, has a name of its own."""
+    is known when it is traced, has a name of its own, and so has a run of
+    latent layers (``latent``: what ``layer_block`` hands their ``attend``)."""
+    if latent is not None:
+        return jax.named_scope("attention_latent")
     if is_local is True:
         return jax.named_scope("attention_window")
     return jax.named_scope("attention")
@@ -994,11 +1146,20 @@ def layer_block(
     feed-forward's place, whose count of assignments to held experts is what
     the layer produces.
 
+    A latent kind hands ``attend`` what its layout keeps, as ``k``: a token's
+    [normed latent | rotary key], one head of ``latent_dim``, and None as
+    ``v``; and ``latent=``, which makes the keys and values of the cached
+    positions the call gathered, in the form the call's shapes choose
+    (``latent_form``): ``latent_view`` beside absorbed queries (one head of
+    ``latent_dim``, the values its first columns, ``mla_absorb_out`` after
+    the softmax), ``mla_expand`` beside the heads' own queries.
+
     Returns (x, what ``attend`` produced, ``written``, ``ssm``)."""
     h, kv, hd, vd = c.n_heads, c.n_kv_heads, c.head_dim, c.value_dim
     theta = c.rope_theta
     if kind is not None:
         kv, theta = kind.kv_heads, kind.rope_theta
+    latent = kind is not None and kind.attention == "latent"
     one = x.ndim == 2
     span_of = (lambda t: t[:, None]) if one else (lambda t: t)
     rows = x.shape[:-1] + ((1,) if one else ())  # (B, S)
@@ -1006,13 +1167,30 @@ def layer_block(
     with jax.named_scope("attn_qkv"):
         attn_in = rms_norm(x, lp["attn_norm"], c.rms_eps, c.rmsnorm_style)
         qkv_in = _times(attn_in, c.attention_in_multiplier)
-        q = matmul(qkv_in, lp["wq"]).reshape(rows + (h, hd))
-        k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
-            rows + (kv, hd))
-        v = _times(matmul(qkv_in, lp["wv"]), c.value_scale).reshape(
-            rows + (kv, vd))
-        q = rope_heads(c, q, span_of(positions), theta)
-        k = rope_heads(c, k, span_of(positions), theta)
+        if latent:
+            q = matmul(
+                rms_norm(matmul(qkv_in, lp["w_qa"]), lp["q_norm"], c.rms_eps,
+                         c.rmsnorm_style),
+                lp["w_qb"]).reshape(rows + (h, hd))
+            left = matmul(qkv_in, lp["w_kva"]).reshape(rows + (1, c.latent_dim))
+            q = jnp.concatenate([
+                q[..., : c.qk_nope_dim],
+                rope_latent(c, q[..., c.qk_nope_dim :], span_of(positions),
+                            theta)], axis=-1)
+            k = jnp.concatenate([
+                rms_norm(left[..., : c.kv_lora_rank], lp["kv_norm"], c.rms_eps,
+                         c.rmsnorm_style),
+                rope_latent(c, left[..., c.kv_lora_rank :], span_of(positions),
+                            theta)], axis=-1)
+            v = None
+        else:
+            q = matmul(qkv_in, lp["wq"]).reshape(rows + (h, hd))
+            k = _times(matmul(qkv_in, lp["wk"]), c.key_multiplier).reshape(
+                rows + (kv, hd))
+            v = _times(matmul(qkv_in, lp["wv"]), c.value_scale).reshape(
+                rows + (kv, vd))
+            q = rope_heads(c, q, span_of(positions), theta)
+            k = rope_heads(c, k, span_of(positions), theta)
     mixed = None
     if c.has_ssm:
         mixed, after = ssm_mixer(
@@ -1022,9 +1200,18 @@ def layer_block(
             ssm = after
         if one:
             mixed = mixed[:, 0]
-    sink = {"sink": lp["attn_sink"]} if kind is not None and kind.sink else {}
+    own = {"sink": lp["attn_sink"]} if kind is not None and kind.sink else {}
+    absorbed = latent and latent_form(rows[1]) == "absorbed"
+    if absorbed:
+        q = mla_absorb(c, lp["w_kvb"], q)
+        own["latent"] = functools.partial(latent_view, c)
+    elif latent:
+        own["latent"] = functools.partial(mla_expand, c, lp["w_kvb"])
     attn, produced, written = attend(
-        q, k, v, operands_l, written, layer, is_local, **sink)
+        q, k, v, operands_l, written, layer, is_local, **own)
+    if absorbed:
+        attn = mla_absorb_out(
+            c, lp["w_kvb"], attn.reshape(rows + (h, c.kv_lora_rank)))
     x = attn_out_block(c, lp, x, attn.reshape(x.shape[:-1] + (h * vd,)), mixed)
     if kind is not None and kind.routed:
         x, produced = moe_block(c, lp, x)
@@ -1151,7 +1338,8 @@ def forward(
         k_valid = jax.lax.dynamic_update_slice(cache.key_valid, valid, (0, write_index))
 
     global_mask, local_mask = _attention_masks(c, positions, valid, k_positions, k_valid)
-    if c.use_flash_attention and (c.swa_sink or c.value_dim != c.head_dim):
+    if c.use_flash_attention and (
+            c.swa_sink or c.value_dim != c.head_dim or c.has_latent):
         raise LayerKindsUnsupported("flash_attention", KERNEL_NEEDS_PLAIN_HEADS)
 
     def call_flash(window, q, keys, values):
@@ -1171,20 +1359,26 @@ def forward(
             causal=True, interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, _, kv_cache, layer, is_local, sink=None):
+    def attend(q, k, v, _, kv_cache, layer, is_local, sink=None, latent=None):
         """Own keys without a cache; with one, this call's K/V written at
-        ``(layer, 0, write_index)`` and the layer's whole buffer attended."""
-        groups = k.shape[2]
-        reps = q.shape[2] // groups
+        ``(layer, 0, write_index)`` and the layer's whole buffer attended.
+        (A latent layer's ``v`` and value buffer are None: ``latent`` makes
+        keys and values of the latents held.)"""
         if kv_cache is None:
             keys, values = k, v
         else:
             with jax.named_scope("kv_write"):
                 kv_cache = tuple(
-                    put_columns(buffer, layer, write_index, new)
+                    None if buffer is None
+                    else put_columns(buffer, layer, write_index, new)
                     for buffer, new in zip(kv_cache, (k, v)))
-            keys, values = (layer_of(buffer, layer) for buffer in kv_cache)
-        with attention_scope(is_local):
+            keys, values = (None if buffer is None else layer_of(buffer, layer)
+                            for buffer in kv_cache)
+        if latent is not None:
+            keys, values = latent(keys)
+        groups = keys.shape[2]
+        reps = q.shape[2] // groups
+        with attention_scope(is_local, latent):
             if c.use_flash_attention and cache is None:
                 # The pallas kernel takes equal q/kv head counts; expand here.
                 attn = windowed(
@@ -1197,7 +1391,7 @@ def forward(
                 # kv head — on the decode path jnp.repeat would re-write the
                 # whole (B, T, H, hd) cache expansion every layer every step,
                 # doubling HBM traffic for nothing.
-                qg = q.reshape(q.shape[:2] + (groups, reps, c.head_dim))
+                qg = q.reshape(q.shape[:2] + (groups, reps, q.shape[-1]))
 
                 def attend_groups(qg, keys, values, sink):
                     """Logits, mask, softmax and values of the key-value
@@ -1415,14 +1609,16 @@ def forward_trunk_tail(
             interpret=jax.default_backend() == "cpu",
         )
 
-    def attend(q, k, v, operands_l, tails, layer, is_local, sink=None):
+    def attend(q, k, v, operands_l, tails, layer, is_local, sink=None,
+               latent=None):
         """This step's K/V written (quantised where the tail is) at the
         tails' ``(layer, 0, write_col)``, one column of the carried
         buffers; then [trunk | frozen blocks | this layer's tail] attended,
-        the trunk broadcast over the slots."""
+        the trunk broadcast over the slots.  (A latent layer's trunk, blocks
+        and tail are one buffer each, their value sides None: ``latent``
+        makes the keys and values of each, the one query a row's absorbed
+        form.)"""
         k_trunk, v_trunk, froz_k, froz_v = operands_l
-        kv, hd = k.shape[-2:]
-        reps = q.shape[-2] // kv
 
         def write_column(tail, new):
             """``new`` (Rows, 1, KV, hd) into every layer's ``tail`` (or its
@@ -1435,13 +1631,20 @@ def forward_trunk_tail(
                 tail, new)
 
         with jax.named_scope("kv_write"):
-            tails = (write_column(tails[0], k), write_column(tails[1], v))
+            tails = (write_column(tails[0], k),
+                     None if v is None else write_column(tails[1], v))
         # This layer's tail, read where it lies: the index goes into the
         # einsums' operand reads.
         new_k_tail, new_v_tail = jax.tree.map(
             lambda buffer: layer_of(buffer, layer), tails)
+        if latent is not None:
+            k_trunk, v_trunk = latent(k_trunk)
+            new_k_tail, new_v_tail = latent(new_k_tail)
+            froz_k, froz_v = zip(*map(latent, froz_k)) if froz_k else ((), ())
+        kv, hd = k_trunk.shape[-2:] if latent is not None else k.shape[-2:]
+        reps = q.shape[-2] // kv
 
-        with attention_scope(is_local):
+        with attention_scope(is_local, latent):
             if (
                 c.use_decode_attention
                 and use_decode_kernel
@@ -1635,17 +1838,22 @@ def forward_shared_trunk(
         # end; what the suffix makes of it is not kept.
         ssm_rows = fork_ssm(cache.ssm, jnp.tile(jnp.arange(n_roles), n_paths))
 
-    def attend(q, ks, vs, trunk_l, _, layer, is_local, sink=None):
+    def attend(q, ks, vs, trunk_l, _, layer, is_local, sink=None, latent=None):
         """The trunk's (R, T) keys broadcast over the paths, beside each
         path's own suffix; nothing is written, so nothing is carried, and
-        the suffix's K/V are produced: stacked by the loop."""
+        the suffix's K/V are produced: stacked by the loop.  (``latent``
+        makes a latent layer's keys and values, of the trunk's latents and
+        of the suffix's.)"""
         k_trunk, v_trunk = trunk_l  # (R, T, kv, hd)
+        if latent is not None:
+            k_trunk, v_trunk = latent(k_trunk)
+            ks, vs = latent(ks)
         kv, hd = ks.shape[-2:]
         reps = q.shape[-2] // kv
         qg = q.reshape(n_paths, n_roles, span, kv, reps, hd)
         ks = ks.reshape(n_paths, n_roles, span, kv, hd)
         vs = vs.reshape(n_paths, n_roles, span, kv, vs.shape[-1])
-        with attention_scope(is_local):
+        with attention_scope(is_local, latent):
             lt = jnp.einsum("prsgmd,rtgd->prgmst", qg, k_trunk).astype(jnp.float32)
             ls = jnp.einsum("prsgmd,prtgd->prgmst", qg, ks).astype(jnp.float32)
             logits = jnp.concatenate([lt, ls], axis=-1) * c.q_scale

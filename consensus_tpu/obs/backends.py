@@ -119,8 +119,26 @@ class BackendInstruments:
         self._kv_bytes_per_token = reg.gauge(
             "backend_kv_bytes_per_token",
             "Bytes of keys and values one position holds over the layers of "
-            "one kind of attention (all, full, window).",
+            "one kind of attention (all, full, window, latent: a latent "
+            "position is one buffer's [latent | rotary key], counted once).",
             labels=("backend", "kind"),
+        )
+        self._mla_queries = reg.counter(
+            "backend_mla_queries_total",
+            "Query positions x latent layers that the launches served in "
+            "each form of latent attention, from the launches' shapes: "
+            "absorbed (queries folded through the heads' key matrices, the "
+            "cached latents read as they lie) or expanded (every head's keys "
+            "and values made of the gathered latents first).",
+            labels=("backend", "form"),
+        )
+        self._mla_keys_expanded = reg.counter(
+            "backend_mla_keys_expanded_total",
+            "Cached latent positions x latent layers that the launches of "
+            "the expanded form turned into every head's keys and values: "
+            "each row's gathered positions, a context that rows share once "
+            "a row.",
+            labels=("backend",),
         )
         self._tokenized = reg.counter(
             "backend_tokenize_texts_total",
@@ -195,6 +213,14 @@ class BackendInstruments:
 
     def record_kv_bytes_per_token(self, kind: str, nbytes: float) -> None:
         self._kv_bytes_per_token.labels(self.backend, kind).set(nbytes)
+
+    def record_mla(self, form: str, queries: int, keys_expanded: int) -> None:
+        """A launch's ``queries`` query positions x latent layers in
+        ``form`` (absorbed, expanded), and the latent positions x layers it
+        expanded to heads."""
+        self._mla_queries.labels(self.backend, form).inc(queries)
+        if keys_expanded:
+            self._mla_keys_expanded.labels(self.backend).inc(keys_expanded)
 
     # -- transfers -----------------------------------------------------------
 

@@ -303,6 +303,7 @@ def paged_attention(
     softcap: Optional[float] = None,
     window: Optional[int] = None,
     sink: Optional[jax.Array] = None,  # (H,) float32: a logit a query head
+    latent=None,  # gathered (B, T, 1, width) latents -> (keys, values)
 ) -> jax.Array:
     """GQA attention over a PAGED KV cache (Kwon et al., SOSP '23 layout).
 
@@ -324,17 +325,27 @@ def paged_attention(
     for the query's head; it takes mass and adds no value.  The value pages
     may be narrower than the key pages.
 
+    A latent pool is one buffer: ``v_pages`` is None, ``k_pages`` holds one
+    head of [latent | rotary key] a token, and ``latent`` makes the keys and
+    values of what was gathered (``transformer.latent_view``: the latents
+    themselves and their first columns, for absorbed queries;
+    ``transformer.mla_expand``: every head's own).
+
     Returns (B, S, H, value width) in q's dtype.
     """
     b, s, h, hd = q.shape
-    page_size, kv = k_pages.shape[1], k_pages.shape[2]
+    page_size = k_pages.shape[1]
     max_blocks = block_tables.shape[1]
-    reps = h // kv
     t_len = max_blocks * page_size
 
     safe_tables = jnp.maximum(block_tables, 0)
-    keys = k_pages[safe_tables].reshape(b, t_len, kv, hd)
-    values = v_pages[safe_tables].reshape(b, t_len, kv, v_pages.shape[-1])
+    keys = k_pages[safe_tables].reshape((b, t_len) + k_pages.shape[2:])
+    if v_pages is None:
+        keys, values = latent(keys)
+    else:
+        values = v_pages[safe_tables].reshape((b, t_len) + v_pages.shape[2:])
+    kv = keys.shape[2]
+    reps = h // kv
 
     kpos = jnp.arange(t_len, dtype=jnp.int32)[None, :]  # (1, T)
     k_valid = kpos < lengths[:, None]  # (B, T)

@@ -105,6 +105,16 @@ PARTITION_RULES: Tuple[Tuple[str, P], ...] = (
     (rf"^layers/{_KIND}(experts_gate|experts_up)$",
      P(None, None, None, MODEL_AXIS)),
     (rf"^layers/{_KIND}experts_down$", P(None, None, MODEL_AXIS, None)),
+    # A latent layer: the norms inside the two bottlenecks and the products
+    # into them ((L, D, q rank), (L, D, latent + rotary key): every shard needs
+    # the whole latent) replicate; the products out of them ((L, rank, H x
+    # columns)) split their heads, as wq.
+    (rf"^layers/{_KIND}(q_norm|kv_norm)$", P(None, None)),
+    (rf"^layers/{_KIND}(w_qa|w_kva)$", P(None, None, None)),
+    (rf"^layers/{_KIND}(w_qb|w_kvb)$", P(None, None, MODEL_AXIS)),
+    # The shared expert (L, D, F) and (L, F, D): as a dense feed-forward.
+    (rf"^layers/{_KIND}(shared_gate|shared_up)$", P(None, None, MODEL_AXIS)),
+    (rf"^layers/{_KIND}shared_down$", P(None, MODEL_AXIS, None)),
     # (V, D): shard vocab rows; logits come out sharded over vocab.
     (r"^(embed|lm_head)$", P(MODEL_AXIS, None)),
     (r"^final_norm$", P(None)),
