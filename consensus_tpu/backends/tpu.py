@@ -1219,10 +1219,10 @@ class TPUBackend:
         None without routed experts) into the backend's counters."""
         if tally is None:
             return
-        held, rows, passes = (int(n) for n in np.asarray(tally))
+        held, rows, passes, reached = (int(n) for n in np.asarray(tally))
         self.instruments.record_moe(
             held, rows * self.config.experts_per_token,
-            passes * self.config.experts_held[1])
+            passes * self.config.experts_held[1], reached)
 
     def _record_latent(
         self, rows: int, queries: int, keys: int, steps: int = 1

@@ -529,20 +529,11 @@ def ffn_block(c: ModelConfig, lp, x: jax.Array) -> jax.Array:
 
 #: What a routed layer counts, summed over layers and launches: assignments
 #: to experts held here, rows routed (each makes ``experts_per_token``
-#: assignments), and passes through a routed layer (each a product of every
-#: held expert).
-MOE_TALLY = ("held", "rows", "layer_passes")
+#: assignments), passes through a routed layer, and held experts that a pass
+#: reached (at least one of its rows was sent there: only their matrices are
+#: read).
+MOE_TALLY = ("held", "rows", "layer_passes", "reached")
 
-#: Rows up to which the held experts run as one masked product over all of
-#: them, every expert on every row: the experts' matrices are read whole
-#: either way, and on a v5e reading one layer's 16 (0.8 GB, 1 ms) costs what
-#: the masked product of 256 rows does (16 x 256 x 50 MFLOP).  Past it the
-#: rows are grouped by expert and each expert multiplies its own.  Both sides
-#: grow with the experts held and with an expert's size, so the number holds
-#: whatever is held: 2 bytes a parameter at 819 GB/s against 2 FLOPs a
-#: parameter a row at 197 TFLOP/s meet at 240 rows (256 held experts of
-#: 3 x 2,048 x 768: 2.4 GB, 2.9 ms to read, 0.6 TFLOP, 3.1 ms at 256 rows).
-_MOE_MASKED_ROWS = 256
 #: Rows a grouped product takes at a time: ``experts_per_token`` assignments
 #: a row are gathered (every one may be to an expert held here), so the
 #: gathered rows of a block are 8 x 4,096 x 4,096 wide x 2 bytes = 268 MB.
@@ -569,38 +560,55 @@ def route(c: ModelConfig, lp, t: jax.Array):
 EXPERT_LEAVES = ("experts_gate", "experts_up", "experts_down")
 
 
-def _experts_masked(stacks, layer, t, local, held, weights):
-    """Few rows: every held expert's gated product on every row, and each
-    row's sum of them under the weight it gave the expert (0 where it was not
-    sent there).  The layer's experts are read where they lie in ``stacks``
-    (layers of the kind, held, ...), at ``layer``: the index goes into the
-    products' operand reads."""
-    gate_w, up_w, down_w = (layer_of(stacks[leaf], layer) for leaf in EXPERT_LEAVES)
-    count = gate_w.shape[0]
-    with jax.named_scope("moe_dispatch"):
-        sent = held[:, :, None] & (
-            local[:, :, None] == jnp.arange(count)[None, None, :])
-        share = jnp.sum(jnp.where(sent, weights[:, :, None], 0.0), axis=1)
-    with jax.named_scope("moe_experts"):
-        gate = jax.nn.silu(jnp.einsum("nd,edf->enf", t, gate_w))
-        up = jnp.einsum("nd,edf->enf", t, up_w)
-        out = jnp.einsum("enf,efd->end", gate * up, down_w)
-    with jax.named_scope("moe_combine"):
-        return jnp.einsum("ne,end->nd", share, out.astype(jnp.float32)).astype(
-            t.dtype)
-
-
-#: Most rows, contracted columns and produced columns of one tile of the
-#: grouped product (a 512-row tile of 1,024 x 1,024 weights is 341 FLOPs a
-#: byte, past a v5e's 240, in 9 MB of its fast memory).
+#: Most rows, contracted columns and produced columns of one tile of a
+#: span's grouped product (a 512-row tile of 1,024 x 1,024 weights is 341
+#: FLOPs a byte, past a v5e's 240, in 9 MB of its fast memory).
 _GROUPED_TILE = (512, 1024, 1024)
+#: Rows of a tile where an expert expects fewer rows than this from the call
+#: (a decode step: 32 rows x 8 of 256 experts give one a row): the fewest
+#: megablox takes for bfloat16 rows (8 compiles too, and was no faster).  A
+#: span's tile would multiply each expert it visits by 512 rows to keep one
+#: or two of them.  At 1-256 rows this is no slower than a product of every
+#: held expert under a mask (32 rows of JoyAI-LLM-Flash's layer: 2.23 ms
+#: against 3.33; PERF.md 5, PR 37), which it replaced.
+_FEW_ROWS_TILE = 16
+#: Bytes that two buffers of a few-rows tile's weight block may take: half of
+#: the 16 MiB of fast memory a v5e kernel is given.
+_WEIGHT_BLOCK_BYTES = 8 << 20
 
 
-def _grouped_dot(rows: jax.Array, stack: jax.Array, layer, sizes: jax.Array):
+def grouped_tiling(m: int, k: int, n: int, run: float, itemsize: int = 2):
+    """The (rows, contracted, produced) tile of a grouped product of ``m``
+    rows of ``k`` into ``n`` columns whose groups expect ``run`` rows each.
+
+    A span's groups fill tiles of ``_GROUPED_TILE``.  Where a group expects
+    fewer than ``_FEW_ROWS_TILE`` rows, the tile has that many rows and the
+    contracted axis whole, with as many produced columns of a power of two's
+    share as let two buffers of the weight block fit ``_WEIGHT_BLOCK_BYTES``
+    (JoyAI-LLM-Flash's 2,048 x 768 whole, 3 MB; MiMo-V2-Flash's 4,096 x 2,048
+    a quarter at a time): the kernel then walks each expert's tiles of rows
+    with the same block of weights, which it does not fetch again, and an
+    expert's matrices are read once a call.  Where no such block fits, the
+    span's columns at the few rows."""
+    if run >= _FEW_ROWS_TILE:
+        return (min(_GROUPED_TILE[0], m), min(_GROUPED_TILE[1], k),
+                min(_GROUPED_TILE[2], n))
+    columns = n
+    while 2 * k * columns * itemsize > _WEIGHT_BLOCK_BYTES and columns % 256 == 0:
+        columns //= 2
+    if 2 * k * columns * itemsize > _WEIGHT_BLOCK_BYTES:
+        return (_FEW_ROWS_TILE, min(_GROUPED_TILE[1], k), min(_GROUPED_TILE[2], n))
+    return (_FEW_ROWS_TILE, k, columns)
+
+
+def _grouped_dot(rows: jax.Array, stack: jax.Array, layer, sizes: jax.Array,
+                 run: float):
     """``rows[group g's run] @ stack[layer, g]`` for every group: ``rows`` (M,
     K) sorted by group, ``stack`` (layers, G, K, N), ``sizes`` (G,) int32 the
-    runs' lengths in order; rows past the last run come back undefined.  Only
-    the tiles that hold a run's rows are computed.
+    runs' lengths in order, ``run`` the rows a group expects
+    (``grouped_tiling``); rows past the last run come back undefined.  Only
+    the tiles that hold a run's rows are computed, and an empty group's
+    matrices are never read.
 
     megablox's grouped matrix product, a Pallas kernel, called from here so
     that its operations carry this call's scope in a profile: what XLA's TPU
@@ -615,8 +623,7 @@ def _grouped_dot(rows: jax.Array, stack: jax.Array, layer, sizes: jax.Array):
 
     m, k = rows.shape
     layers, groups = stack.shape[:2]
-    tile = (min(_GROUPED_TILE[0], m), min(_GROUPED_TILE[1], k),
-            min(_GROUPED_TILE[2], stack.shape[3]))
+    tile = grouped_tiling(m, k, stack.shape[3], run, stack.dtype.itemsize)
     pad = -m % tile[0]
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
@@ -628,12 +635,13 @@ def _grouped_dot(rows: jax.Array, stack: jax.Array, layer, sizes: jax.Array):
     return out[:m] if pad else out
 
 
-def _experts_grouped(stacks, layer, t, local, held, weights):
-    """Many rows: the rows' assignments sorted by expert (those to absent
-    experts last, in no group), each held expert's gated product over its own
-    run of rows (``_grouped_dot``), and each row's weighted sum of what its
-    assignments returned.  No capacity: a group is as long as its expert was
-    chosen often."""
+def _experts_grouped(stacks, layer, run, t, local, held, weights):
+    """The rows' assignments sorted by expert (those to absent experts last,
+    in no group), each held expert's gated product over its own run of rows
+    (``_grouped_dot``; ``run`` the rows an expert expects), and each row's
+    weighted sum of what its assignments returned.  No capacity: a group is
+    as long as its expert was chosen often.  Returns (that sum, the groups'
+    sizes)."""
     n, k = local.shape
     count = stacks["experts_gate"].shape[1]
     with jax.named_scope("moe_dispatch"):
@@ -654,16 +662,17 @@ def _experts_grouped(stacks, layer, t, local, held, weights):
         rows = t[order // k]  # (N * k, D): assignment a's row is a // k
     with jax.named_scope("moe_experts"):
         gate = jax.nn.silu(
-            _grouped_dot(rows, stacks["experts_gate"], layer, sizes))
-        up = _grouped_dot(rows, stacks["experts_up"], layer, sizes)
-        out = _grouped_dot(gate * up, stacks["experts_down"], layer, sizes)
+            _grouped_dot(rows, stacks["experts_gate"], layer, sizes, run))
+        up = _grouped_dot(rows, stacks["experts_up"], layer, sizes, run)
+        out = _grouped_dot(gate * up, stacks["experts_down"], layer, sizes, run)
     with jax.named_scope("moe_combine"):
         back = went.reshape(n, k)  # where each assignment went
         share = jnp.where(held, weights, 0.0)
         # Rows past the last group are no expert's: what the product left
         # there is dropped by the mask, not multiplied by zero.
         returned = jnp.where(held[:, :, None], out[back].astype(jnp.float32), 0.0)
-        return jnp.sum(returned * share[:, :, None], axis=1).astype(t.dtype)
+        return jnp.sum(returned * share[:, :, None], axis=1).astype(
+            t.dtype), sizes
 
 
 def moe_block(c: ModelConfig, lp, x: jax.Array):
@@ -672,12 +681,15 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
     count)`` of the router's ``n_experts``: it routes every row over all of
     them and adds the part of the result that its own experts give; an
     assignment to an absent expert is skipped (what it would have added is
-    another chip's to add), none to a held expert is dropped.  The form
-    follows the rows, from the shapes: one masked product for a decode step's
-    few, grouped products a block of rows at a time for a span's many.  The
-    sum of the routed parts is multiplied by ``routed_scaling_factor`` where
-    the configuration has one, and a shared expert (``shared_*`` of ``lp``)
-    is added beside it, unscaled.
+    another chip's to add), none to a held expert is dropped.  The rows'
+    assignments are grouped by expert and each held expert that any row was
+    sent to multiplies its own (``_experts_grouped``), a block of rows at a
+    time past ``_MOE_BLOCK_ROWS``; the tile follows the rows an expert
+    expects from the call (``grouped_tiling``), so that a decode step's few
+    rows read the experts they reached and no other.  The sum of the routed
+    parts is multiplied by ``routed_scaling_factor`` where the configuration
+    has one, and a shared expert (``shared_*`` of ``lp``) is added beside
+    it, unscaled.
 
     ``lp`` holds the layer's own norm, router and bias, and the experts
     either as the layer's own leaves (``EXPERT_LEAVES``, (held, ...)) or, from
@@ -685,8 +697,8 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
     layer's index): the products read the layer's where they lie.
 
     Returns (x + the held experts' part, the layer's tally: int32
-    (assignments to held experts, rows routed, 1), which the layer loop sums
-    over the routed layers)."""
+    (assignments to held experts, rows routed, 1, held experts reached),
+    which the layer loop sums over the routed layers)."""
     shape = x.shape
     first, count = c.experts_held
     stacks, layer = lp.get("experts") or (
@@ -703,10 +715,10 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
         with jax.named_scope("moe_combine"):
             weights = weights * c.routed_scaling_factor
     n = t.shape[0]
-    if n <= _MOE_MASKED_ROWS:
-        part = _experts_masked(stacks, layer, t, local, held, weights)
-    elif n <= _MOE_BLOCK_ROWS:
-        part = _experts_grouped(stacks, layer, t, local, held, weights)
+    block = min(n, _MOE_BLOCK_ROWS)
+    run = block * c.experts_per_token / c.n_experts
+    if n <= _MOE_BLOCK_ROWS:
+        part, sizes = _experts_grouped(stacks, layer, run, t, local, held, weights)
     else:
         blocks = -(-n // _MOE_BLOCK_ROWS)
         pad = blocks * _MOE_BLOCK_ROWS - n  # padding rows are sent nowhere
@@ -716,10 +728,11 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
                         constant_values=fill)
             return a.reshape((blocks, _MOE_BLOCK_ROWS) + a.shape[1:])
 
-        part = jax.lax.map(
-            lambda block: _experts_grouped(stacks, layer, *block),
-            (blocked(t), blocked(local), blocked(held, False), blocked(weights)),
-        ).reshape(-1, shape[-1])[:n]
+        part, sizes = jax.lax.map(
+            lambda block: _experts_grouped(stacks, layer, run, *block),
+            (blocked(t), blocked(local), blocked(held, False), blocked(weights)))
+        part = part.reshape(-1, shape[-1])[:n]
+        sizes = jnp.sum(sizes, axis=0)
     if c.n_shared_experts:
         # The expert every row passes, on the same normed rows; every chip
         # computes it alike, whatever share of the routed ones it holds.
@@ -729,7 +742,8 @@ def moe_block(c: ModelConfig, lp, x: jax.Array):
                                  lp["shared_down"])
     with jax.named_scope("moe_combine"):
         return x + part.reshape(shape), jnp.stack(
-            [jnp.sum(held, dtype=jnp.int32), jnp.int32(n), jnp.int32(1)])
+            [jnp.sum(held, dtype=jnp.int32), jnp.int32(n), jnp.int32(1),
+             jnp.sum(sizes > 0, dtype=jnp.int32)])
 
 
 def final_norm(params: Params, c: ModelConfig, x: jax.Array) -> jax.Array:

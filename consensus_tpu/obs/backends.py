@@ -116,6 +116,15 @@ class BackendInstruments:
             "product sees.",
             labels=("backend",),
         )
+        self._moe_experts_read = reg.counter(
+            "backend_moe_experts_read_total",
+            "Products of a held expert in those launches that ran on at least "
+            "one row: held experts that a pass's rows reached, whose "
+            "matrices alone were read, summed over routed layers and passes. "
+            " Over backend_moe_expert_calls_total this is the share of the "
+            "held experts read.",
+            labels=("backend",),
+        )
         self._kv_bytes_per_token = reg.gauge(
             "backend_kv_bytes_per_token",
             "Bytes of keys and values one position holds over the layers of "
@@ -202,14 +211,17 @@ class BackendInstruments:
 
     # -- routed experts, caches by kind --------------------------------------
 
-    def record_moe(self, held: int, assignments: int, expert_calls: int) -> None:
+    def record_moe(
+        self, held: int, assignments: int, expert_calls: int, experts_read: int
+    ) -> None:
         """Launches that made ``assignments`` assignments of rows to experts,
         ``held`` of them to experts held here, in ``expert_calls`` products
-        of a held expert."""
+        of a held expert, ``experts_read`` of which some row reached."""
         self._moe_assignments.labels(self.backend, "held").inc(held)
         self._moe_assignments.labels(self.backend, "absent").inc(
             assignments - held)
         self._moe_expert_calls.labels(self.backend).inc(expert_calls)
+        self._moe_experts_read.labels(self.backend).inc(experts_read)
 
     def record_kv_bytes_per_token(self, kind: str, nbytes: float) -> None:
         self._kv_bytes_per_token.labels(self.backend, kind).set(nbytes)

@@ -369,8 +369,9 @@ def test_classic_generation_decodes_what_the_reference_puts_first(params, progra
     for prompt, row in zip(PROMPTS, generated):
         assert max(_greedy_gaps(params, prompt, row)) < GAP_TOL
     # 16 steps x 2 routed layers x 3 rows, 2 assignments each, every one held.
-    held, rows, passes = (int(n) for n in np.asarray(out.moe_held))
+    held, rows, passes, reached = (int(n) for n in np.asarray(out.moe_held))
     assert (held, rows, passes) == (16 * 2 * 3 * 2, 16 * 2 * 3, 16 * 2)
+    assert passes <= reached <= passes * 6  # three rows' six assignments a pass
 
 
 @pytest.mark.parametrize("program", ["monolithic", "segmented"])
@@ -460,7 +461,7 @@ def test_the_latent_pool_is_one_buffer_and_a_token_is_counted_once():
 # -- (f) the share and the model -------------------------------------------------------
 
 
-@pytest.mark.parametrize("rows", [40, 700])  # the masked form, the grouped form
+@pytest.mark.parametrize("rows", [40, 700])  # the few-rows tile, the span's
 def test_the_shares_sum_to_the_uncut_layer_with_the_shared_expert_once(rows):
     """The routed parts under ``experts_held`` (0, 4) and (4, 4), each times
     the factor, with the shared expert (which every chip computes alike) and
@@ -488,6 +489,48 @@ def test_the_shares_sum_to_the_uncut_layer_with_the_shared_expert_once(rows):
     plain = dataclasses.replace(CONFIG, routed_scaling_factor=None)
     out, _ = jax.jit(tf.moe_block, static_argnums=0)(plain, lp, x)
     assert np.abs(np.asarray(out) - want).max() > 0.1
+
+
+@pytest.mark.parametrize("m, k, n, run, tile", [
+    # A decode step of 32 rows, 8 of 256 experts a row: one row an expert.
+    (256, 2048, 768, 1.0, (16, 2048, 768)),  # JoyAI-LLM-Flash, gate and up
+    (256, 768, 2048, 1.0, (16, 768, 2048)),  # its down, the block whole
+    (256, 4096, 2048, 1.0, (16, 4096, 512)),  # MiMo-V2-Flash: a quarter
+    (256, 2048, 4096, 1.0, (16, 2048, 1024)),
+    # A score chunk's block of 4,096 rows and a paged prefill's 2,048.
+    (32768, 2048, 768, 128.0, (512, 1024, 768)),
+    (32768, 4096, 2048, 128.0, tf._GROUPED_TILE),
+    (16384, 2048, 4096, 64.0, tf._GROUPED_TILE),
+])
+def test_the_tile_follows_the_rows_an_expert_expects(m, k, n, run, tile):
+    """At the published widths: a decode step's few rows an expert take the
+    few-rows tile with as much of the weight block as fits twice in half the
+    kernel's fast memory; a span keeps the tile it had."""
+    got = tf.grouped_tiling(m, k, n, run)
+    assert got == tile
+    if got[0] == tf._FEW_ROWS_TILE:
+        assert 2 * got[1] * got[2] * 2 <= tf._WEIGHT_BLOCK_BYTES
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32])
+def test_a_decode_steps_rows_give_the_references_experts(rows):
+    """At a decode step's rows the grouped products run at the few-rows tile
+    and give the float32 reference's layer, every expert held, the shared
+    one and the factor in; the tally counts the experts the rows reached."""
+    lp = jax.tree.map(
+        lambda a: a[1],
+        init_params(CONFIG, jax.random.PRNGKey(11), jnp.float32)["layers"]["latent_moe"])
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, CONFIG.d_model))
+    k = CONFIG.experts_per_token
+    assert tf.grouped_tiling(rows * k, CONFIG.d_model, CONFIG.expert_hidden,
+                             rows * k / CONFIG.n_experts)[0] == tf._FEW_ROWS_TILE
+    t = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + CONFIG.rms_eps)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(x + ref.experts(CONFIG, lp, t))
+        chosen = np.asarray(ref.routing(CONFIG, lp, t)[0])
+    out, tally = jax.jit(tf.moe_block, static_argnums=0)(CONFIG, lp, x)
+    np.testing.assert_allclose(np.asarray(out), want, atol=LOGIT_TOL, rtol=0)
+    assert list(map(int, tally)) == [rows * k, rows, 1, len(set(chosen.ravel()))]
 
 
 # -- (d) the backend: paged prefill in chunks, the fused score matrix -----------------
@@ -539,6 +582,8 @@ def _counters():
         "backend_mla_keys_expanded_total", {"series": []})["series"])
     out["expert_calls"] = sum(s["value"] for s in families.get(
         "backend_moe_expert_calls_total", {"series": []})["series"])
+    out["experts_read"] = sum(s["value"] for s in families.get(
+        "backend_moe_experts_read_total", {"series": []})["series"])
     return out
 
 
@@ -613,6 +658,27 @@ def test_the_backend_generates_and_embeds_and_counts_both_forms(backend):
     gauges = {s["labels"]["kind"]: s["value"] for s in get_registry().snapshot()[
         "families"]["backend_kv_bytes_per_token"]["series"]}
     assert gauges["latent"] == gauges["all"] == 4 * 3 * 40
+
+
+def test_a_generation_launch_counts_the_experts_it_read(backend, monkeypatch):
+    """``backend_moe_experts_read_total`` grows by the tally's held experts
+    reached, and ``backend_moe_expert_calls_total`` by every held expert of
+    every routed pass, as before: read over calls is the share read."""
+    from consensus_tpu.backends.base import GenerationRequest
+
+    tallies = []
+    record = backend._record_moe
+    monkeypatch.setattr(backend, "_record_moe",
+                        lambda tally: (tallies.append(np.asarray(tally)), record(tally)))
+    counted = _counters()
+    backend.generate([GenerationRequest(user_prompt="one row", max_tokens=8,
+                                        temperature=0.7, seed=5)])
+    after = _counters()
+    (tally,) = tallies
+    held, rows, passes, reached = (int(n) for n in tally)
+    assert after["experts_read"] - counted["experts_read"] == reached
+    assert after["expert_calls"] - counted["expert_calls"] == passes * 8
+    assert passes <= reached <= passes * 8
 
 
 # -- budgets -------------------------------------------------------------------------------
@@ -805,7 +871,15 @@ def test_the_programs_name_the_new_scopes():
 #: ``tiny-mimo-v2/jit-init`` pins are of ``init_params`` under one ``jit``, as
 #: ``benchmark/lib/harness.make_params`` wraps it: the experts are drawn one
 #: at a time into their place since that PR, and under the ``jit`` that serves
-#: them they are the parent's bits.
+#: them they are the parent's bits.  The two ``tiny-mimo-v2`` program pins
+#: are PR 37's: a decode step's routed product went from the masked form to
+#: the grouped one at a few-rows tile, and the routed layers' tally gained
+#: its fourth entry (``reached``).  The pinned chunk is 8 x 32 = 256 rows,
+#: which the masked form took too; a chunk past 256 rows (8 x 64) lowers to
+#: its parent's text but for the tally's sum of the groups reached.  The two
+#: ``tiny-llama3`` pins are of PR 35's commit, this PR's parent (the same text as
+#: ``tests/test_falcon_h1.py``'s dense pins of PR 31): a configuration with
+#: no routed layer, as SmolLM2's cell is, lowers to what it did before.
 PARENT = {
     "tiny-falcon-h1/generate_tokens_shared_trunk":
         "963efd219ff76c21cbb9865dc22b2ccd68a12a3b1a8c376ea2fb1dbc95ff1232",
@@ -816,13 +890,17 @@ PARENT = {
     "tiny-falcon-h1/init/bfloat16":
         "c81aab2e937d8bda6cb377cb4238a9b40f7742f4ed596d40a3cc818daec9b7eb",
     "tiny-mimo-v2/generate_tokens_shared_trunk":
-        "360b63de384a89cb44f79514e8f11bf7411a251ac48ef86053d90c6a46980345",
+        "a8bdb3c984526d721d45a1f808e9ad918b44f87269db388ae61451bf0ee33ac5",
     "tiny-mimo-v2/paged_score_chunk":
-        "8ae8adbff9595ecde0423d2a1f12ceec4261399c8358d3c76134773efd518ac2",
+        "8e42e6d2d568230ac8c935ea9552e076258cb4623e7c8fe8618d5b64537f8d19",
     "tiny-mimo-v2/jit-init/float32":
         "6124e444913f6b1bddc67a413397dead809adb6f97318392818cb92efcf3de2a",
     "tiny-mimo-v2/jit-init/bfloat16":
         "e7326b1bff1a4b96827d93be130a75bff9130949ce4a8408919f9ed9a46847a8",
+    "tiny-llama3/generate_tokens_shared_trunk":
+        "2b529415ac0ad18d2a77e82dcebfdfa0c49b5e5b7dc7ce2ec6d4c44ad85b89c5",
+    "tiny-llama3/paged_score_chunk":
+        "8c0d22e16f9d9e2d057cb8a3ba95a2a3fc068cb547772ad527505174d94d6947",
 }
 
 
@@ -853,7 +931,7 @@ def _tree_digest(tree):
     return digest.hexdigest()
 
 
-@pytest.mark.parametrize("model", ["tiny-falcon-h1", "tiny-mimo-v2"])
+@pytest.mark.parametrize("model", ["tiny-falcon-h1", "tiny-mimo-v2", "tiny-llama3"])
 @pytest.mark.parametrize("program", ["generate_tokens_shared_trunk",
                                      "paged_score_chunk"])
 def test_an_accepted_configurations_lowered_text_is_the_parents(model, program):

@@ -209,7 +209,7 @@ def _skewed(lp, favourite=3, by=4.0):
     return {**lp, "router_bias": bias}
 
 
-@pytest.mark.parametrize("rows", [40, 700])  # the masked form, the grouped form
+@pytest.mark.parametrize("rows", [40, 700])  # the few-rows tile, the span's
 def test_the_shares_of_all_ranks_sum_to_the_uncut_layer(rows):
     """The routed layer's result summed over the shares of all four ranks (8
     experts each of 32) is the uncut reference's for the whole layer, no row
@@ -237,21 +237,79 @@ def test_the_shares_of_all_ranks_sum_to_the_uncut_layer(rows):
     np.testing.assert_allclose(total, want, atol=LOGIT_TOL, rtol=0)
 
 
-def test_the_span_form_and_the_decode_form_agree(params, monkeypatch):
-    """The held experts' result of the grouped products, of the grouped
-    products a block of rows at a time, and of the masked product."""
+def _tiled(monkeypatch, run):
+    """``grouped_tiling`` as if every group expected ``run`` rows: 0 gives
+    the few-rows tile of a decode step, infinity the span's."""
+    rule = tf.grouped_tiling
+    monkeypatch.setattr(tf, "grouped_tiling",
+                        lambda m, k, n, _, *rest: rule(m, k, n, run, *rest))
+
+
+@pytest.mark.parametrize("form", ["span_tile", "blocked"])
+def test_the_span_forms_and_the_decode_tiling_agree(params, monkeypatch, form):
+    """The held experts' result of the grouped products at a decode step's
+    tile, and at the span's tile or a block of rows at a time."""
     lp = _skewed(jax.tree.map(lambda a: a[1], params["layers"]["window_moe"]), 11)
     x = jax.random.normal(jax.random.PRNGKey(9), (200, CONFIG.d_model))
-    masked, tally = tf.moe_block(CONFIG, lp, x)  # 200 rows: the masked form
+    _tiled(monkeypatch, 0.0)
+    decode, tally = tf.moe_block(CONFIG, lp, x)
     assert int(tally[0]) > 200  # most rows reach the favourite, held here
-    monkeypatch.setattr(tf, "_MOE_MASKED_ROWS", 16)
-    grouped, tally_grouped = tf.moe_block(CONFIG, lp, x)
-    monkeypatch.setattr(tf, "_MOE_BLOCK_ROWS", 64)  # four blocks, the last padded
-    blocked, tally_blocked = tf.moe_block(CONFIG, lp, x)
-    np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(blocked), np.asarray(masked), atol=2e-5)
-    assert list(map(int, tally)) == list(map(int, tally_grouped)) == list(
-        map(int, tally_blocked))
+    _tiled(monkeypatch, float("inf"))
+    if form == "blocked":
+        monkeypatch.setattr(tf, "_MOE_BLOCK_ROWS", 64)  # four blocks, the last padded
+    other, tally_other = tf.moe_block(CONFIG, lp, x)
+    np.testing.assert_allclose(np.asarray(other), np.asarray(decode), atol=2e-5)
+    assert list(map(int, tally)) == list(map(int, tally_other))
+
+
+def _held_reference(lp, x, held=CONFIG.experts_held):
+    t = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + CONFIG.rms_eps)
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(x + ref.experts(CONFIG, lp, t, held=held)), t
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32])
+def test_a_decode_steps_rows_give_the_references_experts(params, rows):
+    """At a decode step's rows the grouped products run at the few-rows tile
+    and give the float32 reference's held part, some experts held."""
+    lp = jax.tree.map(lambda a: a[2], params["layers"]["window_moe"])
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, CONFIG.d_model))
+    assert tf.grouped_tiling(rows * 8, CONFIG.d_model, CONFIG.expert_hidden,
+                             rows * 8 / CONFIG.n_experts)[0] == tf._FEW_ROWS_TILE
+    want, t = _held_reference(lp, x)
+    out, tally = jax.jit(tf.moe_block, static_argnums=0)(CONFIG, lp, x)
+    np.testing.assert_allclose(np.asarray(out), want, atol=LOGIT_TOL, rtol=0)
+    chosen, _ = ref.routing(CONFIG, lp, t)
+    first, count = CONFIG.experts_held
+    reached = {int(e) for e in np.asarray(chosen).ravel() if first <= e < first + count}
+    assert list(map(int, tally)) == [
+        int(np.sum((np.asarray(chosen) >= first) & (np.asarray(chosen) < first + count))),
+        rows, 1, len(reached)]
+
+
+@pytest.mark.parametrize("rows", [8, 32])
+def test_an_expert_no_row_reached_is_never_read(params, rows):
+    """A held expert that no row was sent to has NaN for its three matrices:
+    the result is finite and the one its true matrices give (a product over
+    every held expert under a mask would carry NaN x 0 = NaN into every
+    row), and the tally counts the held experts the rows did reach."""
+    first, count = CONFIG.experts_held
+    unreached = first + 5
+    lp = jax.tree.map(lambda a: a[2], params["layers"]["window_moe"])
+    lp = _skewed(lp, unreached, -100.0)  # no row's top 8 takes it
+    x = jax.random.normal(jax.random.PRNGKey(40 + rows), (rows, CONFIG.d_model))
+    poisoned = {**lp, **{leaf: lp[leaf].at[5].set(jnp.nan) for leaf in tf.EXPERT_LEAVES}}
+    block = jax.jit(tf.moe_block, static_argnums=0)
+    want, tally_want = block(CONFIG, lp, x)
+    got, tally = block(CONFIG, poisoned, x)
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _, t = _held_reference(lp, x)
+    chosen = np.asarray(ref.routing(CONFIG, lp, t)[0])
+    assert unreached not in chosen
+    reached = {int(e) for e in chosen.ravel() if first <= e < first + count}
+    assert 0 < len(reached) < count
+    assert int(tally[3]) == int(tally_want[3]) == len(reached)
 
 
 # -- prefill, then decode through the caches by kind --------------------------------
@@ -284,9 +342,11 @@ def test_classic_generation_decodes_what_the_reference_puts_first(params, progra
     for prompt, row in zip(PROMPTS, generated):
         assert max(_greedy_gaps(params, prompt, row)) < GAP_TOL
     # 16 steps x 6 routed layers x 3 rows, 8 assignments each, a quarter held.
-    held, rows, passes = (int(n) for n in np.asarray(out.moe_held))
+    held, rows, passes, reached = (int(n) for n in np.asarray(out.moe_held))
     assert (rows, passes) == (16 * 6 * 3, 16 * 6)
     assert 0.1 < held / (rows * 8) < 0.45
+    # Three rows' 24 assignments reach some of the 8 held experts a pass.
+    assert 0 < reached < passes * 8
 
 
 @pytest.mark.parametrize("program", ["monolithic", "segmented"])
@@ -334,7 +394,7 @@ def test_the_caches_are_by_kind_at_each_kinds_heads_and_widths():
     state = make_page_state(CONFIG, 10, 16, jnp.float32)
     assert state.k_pages["window"].shape == (5, 11, 16, 4, 24)
     assert state.v_pages["full"].shape == (2, 11, 16, 2, 16)
-    assert state.moe_held.shape == (3,)
+    assert state.moe_held.shape == (len(tf.MOE_TALLY),) == (4,)
     # 2 x 2 x (24 + 16) + 5 x 4 x (24 + 16) values a token.
     assert CONFIG.kv_bytes_per_token(4) == 4 * (2 * 2 * 40 + 5 * 4 * 40)
     dense = get_model_config("tiny-llama3")
