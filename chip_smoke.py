@@ -191,8 +191,9 @@ class Counters:
                 yield dict(labels), int(grown)
 
     def programs(self) -> Dict[str, Dict[str, int]]:
-        """Padded device programs by kind: shapes first seen (``compiled``)
-        and launches in all, from the backend's own instruments."""
+        """Padded device programs by kind: buckets first seen
+        (``compiled``) and launches in all, from the backend's own
+        instruments."""
         out: Dict[str, Dict[str, int]] = {}
         for labels, n in self._increases("backend_bucket_compiles_total"):
             entry = out.setdefault(labels["kind"], {"compiled": 0, "launches": 0})

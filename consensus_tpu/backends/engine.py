@@ -376,18 +376,6 @@ class DecodeEngine:
             "Occupied fraction of the decode engine's slot table at the "
             "latest iteration.",
         )
-        self._m_mesh_dp = reg.gauge(
-            "engine_mesh_dp",
-            "Data-parallel width of the mesh this engine partitions its "
-            "slots and page pools over (1 = single device).",
-        )
-        self._m_mesh_tp = reg.gauge(
-            "engine_mesh_tp",
-            "Tensor-parallel width of the mesh under this engine's inner "
-            "backend (1 = unsharded params).",
-        )
-        self._m_mesh_dp.set(self.mesh_dp)
-        self._m_mesh_tp.set(self.mesh_tp)
         self._m_tokens_iter = reg.histogram(
             "engine_tokens_per_iteration",
             "Generated tokens retired per decode-cohort iteration.",

@@ -452,9 +452,10 @@ class TPUBackend:
             collections.OrderedDict())
         self._token_memo_chars = 0
         self._token_memo_lock = threading.Lock()
-        # obs: padding efficiency per (kind, rows, width) bucket, compile-
-        # cache events per padded program shape, H2D/D2H transfer timings —
-        # recorded into the process registry (metrics.json / bench extra).
+        # obs: padding efficiency and first sightings per (kind, rows,
+        # width) bucket, H2D/D2H transfer timings — recorded into the
+        # process registry (metrics.json / bench extra).  What JAX compiled
+        # is the compile record's, installed by enable_compile_cache().
         self.instruments = BackendInstruments("tpu")
         if self.config.has_layer_kinds:
             itemsize = jnp.dtype(jax_dtype).itemsize

@@ -13,8 +13,9 @@ goes* a first-class subsystem:
   ``timing.json`` backward compatible;
 * :mod:`consensus_tpu.obs.backends` — the shared instrument set backends
   record into: padding efficiency (useful vs. allocated tokens per
-  row/width bucket), compile-cache events (first-compile vs. cache hit per
-  padded program shape), and host↔device transfer timings.
+  row/width bucket), first sightings of a padded bucket, host↔device
+  transfer timings, and the process's compile record (JAX's own compile
+  events by jitted function and stage).
 
 Artifacts: ``experiment.py`` snapshots the registry delta + span tree into
 ``run_dir/metrics.json`` (and the cumulative process registry into

@@ -7,13 +7,23 @@ through one :class:`BackendInstruments` handle:
   processed, how many were real?  Recorded per (kind, rows, width) bucket
   so a lopsided bucket ladder shows up as one bad cell, not a blended
   average.
-* **Compile cache** — was this padded program shape seen before?  First
-  sightings count as compiles, repeats as cache hits; the compile/launch
-  ratio is the recompile pressure the bucket ladder is supposed to bound.
+* **Padded buckets** — was this padded ``(kind, rows, width)`` seen before
+  in this backend?  First sightings and repeats are counted; the ratio is
+  the pressure the bucket ladder is supposed to bound.  A bucket is not a
+  program: JAX compiles per program and per shape of every argument (a
+  score matrix's page pool among them), which :class:`CompileRecord`
+  counts.
 * **Host↔device transfer** — time spent placing batches (H2D) and fetching
   results (D2H).  Note: on asynchronous-dispatch runtimes the D2H fetch
   blocks on device execution, so ``backend_d2h_seconds`` is an upper bound
   that includes device time still in flight.
+
+One process-wide :class:`CompileRecord` (``install_compile_record``, called
+by ``utils/compile_cache.enable_compile_cache``) hears JAX's own events for
+every stage of making an executable (trace, lower, compile or read from the
+persistent cache), by jitted function: counters on ``GET /metrics``, the
+``compiles`` block of ``/healthz``, and a ``backend.compile`` span for each
+stage.
 
 ``padding_efficiency`` / ``bucket_recompiles`` reduce a registry snapshot
 to the two headline numbers ``bench.py`` and ``metrics.json`` report.
@@ -22,15 +32,19 @@ to the two headline numbers ``bench.py`` and ``metrics.json`` report.
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
-from typing import Any, Iterator, Mapping, Optional, Set, Tuple
+from typing import Any, Dict, Iterator, List, Mapping, Optional, Set, Tuple
 
 from consensus_tpu.obs.metrics import (
     DEFAULT_TIME_BUCKETS,
     Registry,
     get_registry,
 )
+from consensus_tpu.obs.trace import span
+
+logger = logging.getLogger(__name__)
 
 
 class BackendInstruments:
@@ -57,13 +71,15 @@ class BackendInstruments:
         )
         self._compiles = reg.counter(
             "backend_bucket_compiles_total",
-            "First sighting of a padded program shape (a compile, or a "
-            "compile-cache load).",
+            "First sightings of a padded (kind, rows, width) bucket in this "
+            "backend; not compiles: backend_compile_programs_total counts "
+            "those.",
             labels=("backend", "kind"),
         )
         self._cache_hits = reg.counter(
             "backend_bucket_cache_hits_total",
-            "Launches whose padded program shape was already compiled.",
+            "Launches whose padded (kind, rows, width) bucket this backend "
+            "had launched before.",
             labels=("backend", "kind"),
         )
         self._h2d = reg.histogram(
@@ -182,11 +198,12 @@ class BackendInstruments:
         self._tokenized.labels(
             self.backend, "encoded" if encoded else "reused").inc()
 
-    # -- compile cache -------------------------------------------------------
+    # -- padded buckets ------------------------------------------------------
 
     def record_launch(self, kind: str, shape: Tuple[int, ...]) -> bool:
-        """Count a program launch; returns True on the shape's first
-        sighting (a compile), False on a cache hit."""
+        """Count a program launch; returns True on the padded bucket's first
+        sighting in this backend, False on a repeat.  What JAX compiled is
+        :class:`CompileRecord`'s."""
         key = (kind, tuple(int(d) for d in shape))
         with self._seen_lock:
             first = key not in self._seen_shapes
@@ -285,5 +302,229 @@ def padding_efficiency(
 def bucket_recompiles(
     snapshot: Mapping[str, Any], backend: Optional[str] = None
 ) -> int:
-    """Distinct padded program shapes compiled in ``snapshot``'s window."""
+    """Padded (kind, rows, width) buckets first met in ``snapshot``'s
+    window; the programs JAX compiled are ``backend_compile_programs_total``."""
     return int(_sum_series(snapshot, "backend_bucket_compiles_total", backend))
+
+
+# -- what JAX paid to make this process's executables -----------------------
+
+#: JAX's stages of making an executable, by the event that announces each
+#: (``jax._src.dispatch.LogElapsedTimeContextManager``: ``record_scalar`` at
+#: the start, ``record_event_time_span`` at the end, also when the stage
+#: raises).  ``compile`` wraps ``compile_or_get_cached``: a read from the
+#: persistent cache is inside it.
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ_TIME = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _program_name(fun_name: Any) -> str:
+    """A jitted function's name: ``jit(f)`` (lowering and compiling) and
+    ``f`` (tracing) are one program."""
+    name = str(fun_name or "")
+    head, paren, rest = name.partition("(")
+    if paren and head.isidentifier() and rest.endswith(")"):
+        name = rest[:-1]
+    return name[:63]
+
+
+class _Stage:
+    """One open stage on one thread."""
+
+    __slots__ = ("stage", "program", "folded", "nested_s", "span", "late",
+                 "cache_read")
+
+    def __init__(self, stage: str, program: str, folded: bool) -> None:
+        self.stage = stage
+        self.program = program
+        #: A trace inside another open stage: the jitted function is inlined
+        #: into the program that stage makes, and its time is that stage's.
+        self.folded = folded
+        #: Seconds of the recorded stages inside this one.
+        self.nested_s = 0.0
+        self.span: Any = None
+        self.late: Dict[str, Any] = {}
+        self.cache_read = False
+
+
+class CompileRecord:
+    """Seconds by stage, meetings and persistent-cache reads, by jitted
+    function, from JAX's own compile events.
+
+    A meeting is one backend-compile event: an executable compiled, or read
+    from the persistent cache (outcome ``cache_read``).  Seconds are counted
+    once: a stage's own seconds are its span less the recorded stages inside
+    it on the same thread, and a trace inside another open stage (a jitted
+    function called in another's trace) is that stage's time, not a program
+    of its own.  Each recorded stage is a ``backend.compile`` span on the
+    thread that compiles: a child in the request's tree where that thread
+    works for one, and a ``TraceAnnotation`` on the profiler's host plane.
+    A stage's listeners never raise into JAX: a fault is logged."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._totals: Dict[str, float] = {
+            "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache_read_s": 0.0, "programs": 0, "cache_reads": 0}
+        self._by_program: Dict[str, Dict[str, float]] = {}
+
+    def _open(self) -> List[_Stage]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def _families(self) -> Tuple[Any, Any]:
+        # Looked up at every stage's end: a registry reset in between leaves
+        # no stale handle behind (a process compiles some hundreds of times).
+        reg = get_registry()
+        seconds = reg.counter(
+            "backend_compile_seconds_total",
+            "Seconds this process spent making executables, by jitted "
+            "function and stage (trace, lower, compile; a persistent-cache "
+            "read is inside compile), each second counted once.",
+            labels=("program", "stage"),
+        )
+        programs = reg.counter(
+            "backend_compile_programs_total",
+            "Executables this process made, by jitted function and outcome "
+            "(compiled, or cache_read from the persistent cache).",
+            labels=("program", "outcome"),
+        )
+        return seconds, programs
+
+    def _program_row(self, program: str) -> Dict[str, float]:
+        row = self._by_program.get(program)
+        if row is None:
+            row = self._by_program[program] = {
+                "trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                "cache_read_s": 0.0, "meetings": 0, "cache_reads": 0}
+        return row
+
+    # -- jax.monitoring listeners ---------------------------------------------
+
+    def on_stage_start(self, event: str, value: float, **kwargs: Any) -> None:
+        stage = COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        try:
+            stack = self._open()
+            entry = _Stage(stage, _program_name(kwargs.get("fun_name")),
+                           folded=stage == "trace" and bool(stack))
+            if not entry.folded:
+                entry.span = span("backend.compile", program=entry.program,
+                                  stage=stage)
+                entry.late = entry.span.__enter__()
+            stack.append(entry)
+        except Exception:
+            logger.exception("compile record: the start of %s", event)
+
+    def on_stage_end(self, event: str, start: float, end: float,
+                     **kwargs: Any) -> None:
+        stage = COMPILE_STAGES.get(event)
+        if stage is None:
+            return
+        try:
+            self._close(stage, _program_name(kwargs.get("fun_name")),
+                        max(0.0, float(end) - float(start)))
+        except Exception:
+            logger.exception("compile record: the end of %s", event)
+
+    def _close(self, stage: str, program: str, elapsed: float) -> None:
+        stack = self._open()
+        # Stages are context managers: the one ending is the innermost open
+        # (none where its start came before the record was installed).
+        entry = None
+        if stack and (stack[-1].stage, stack[-1].program) == (stage, program):
+            entry = stack.pop()
+        if entry is not None and entry.folded:
+            return
+        own = max(0.0, elapsed - (entry.nested_s if entry else 0.0))
+        for outer in reversed(stack):
+            if not outer.folded:
+                outer.nested_s += elapsed
+                break
+        cache_read = bool(entry and entry.cache_read)
+        with self._lock:
+            row = self._program_row(program)
+            row[f"{stage}_s"] += own
+            self._totals[f"{stage}_s"] += own
+            if stage == "compile":
+                row["meetings"] += 1
+                self._totals["programs"] += 1
+                if cache_read:
+                    row["cache_reads"] += 1
+                    self._totals["cache_reads"] += 1
+        seconds, programs = self._families()
+        seconds.labels(program, stage).inc(own)
+        if stage == "compile":
+            programs.labels(
+                program, "cache_read" if cache_read else "compiled").inc()
+        if entry is not None and entry.span is not None:
+            if stage == "compile":
+                entry.late["cache_read"] = cache_read
+            entry.span.__exit__(None, None, None)
+
+    def _open_compile(self) -> Optional[_Stage]:
+        return next((s for s in reversed(self._open())
+                     if s.stage == "compile"), None)
+
+    def on_event(self, event: str, **kwargs: Any) -> None:
+        if event != _CACHE_HIT:
+            return
+        entry = self._open_compile()
+        if entry is not None:
+            entry.cache_read = True
+
+    def on_duration(self, event: str, seconds: float, **kwargs: Any) -> None:
+        if event != _CACHE_READ_TIME:
+            return
+        entry = self._open_compile()
+        with self._lock:
+            self._totals["cache_read_s"] += float(seconds)
+            if entry is not None:
+                self._program_row(entry.program)["cache_read_s"] += float(seconds)
+
+    # -- readings ---------------------------------------------------------------
+
+    def snapshot(self) -> Dict[str, Any]:
+        """The ``/healthz`` ``compiles`` block: totals since the record was
+        installed, and ``by_program``."""
+        with self._lock:
+            out: Dict[str, Any] = dict(self._totals)
+            out["by_program"] = {name: dict(row) for name, row in
+                                 sorted(self._by_program.items())}
+        return out
+
+
+_RECORD: Optional[CompileRecord] = None
+_RECORD_LOCK = threading.Lock()
+
+
+def install_compile_record() -> CompileRecord:
+    """The process's compile record, its listeners registered with
+    ``jax.monitoring`` on the first call and never again."""
+    global _RECORD
+    with _RECORD_LOCK:
+        if _RECORD is None:
+            import jax.monitoring as monitoring
+
+            record = CompileRecord()
+            monitoring.register_scalar_listener(record.on_stage_start)
+            monitoring.register_event_time_span_listener(record.on_stage_end)
+            monitoring.register_event_listener(record.on_event)
+            monitoring.register_event_duration_secs_listener(record.on_duration)
+            _RECORD = record
+        return _RECORD
+
+
+def compile_record() -> Optional[CompileRecord]:
+    """The installed record, or None in a process that never enabled the
+    compile cache (and so never made an executable on purpose)."""
+    return _RECORD

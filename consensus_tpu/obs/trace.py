@@ -93,7 +93,7 @@ HOST_SPANS = (
     "engine.enqueue", "engine.idle", "engine.iteration", "engine.admit",
     "engine.prefill", "engine.cohort", "engine.dispatch", "engine.merge",
     "backend.tokenize", "backend.layout", "backend.h2d", "backend.launch",
-    "backend.d2h", "backend.detokenize",
+    "backend.compile", "backend.d2h", "backend.detokenize",
 )
 
 #: The engine's spans (``engine_<kind>``) of calls that are scored, not

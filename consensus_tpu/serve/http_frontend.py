@@ -15,7 +15,9 @@ dependencies) in front of :class:`RequestScheduler`:
   graceful degradation trades answer quality for availability, never the
   other way around.
 * ``GET /healthz`` — queue depth, in-flight count, drain state, backend
-  liveness, device-batch accounting (the coalescing proof surface).
+  liveness, device-batch accounting (the coalescing proof surface), and
+  where the process made executables, the ``compiles`` block of its
+  compile record (``obs/backends.py``).
 * ``GET /metrics`` — Prometheus text exposition straight from the obs
   registry (the ``serve_*`` families plus everything the backends record).
   With welfare telemetry on a fleet, the snapshot is federated first
@@ -47,6 +49,7 @@ import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
+from consensus_tpu.obs.backends import compile_record
 from consensus_tpu.obs.metrics import Registry, get_registry
 from consensus_tpu.obs.trace import (
     TraceContext,
@@ -378,6 +381,10 @@ class ConsensusRequestHandler(BaseHTTPRequestHandler):
         device = backend_device_info(inner)
         if device is not None:
             stats["backend"]["device"] = device
+        record = compile_record()
+        if record is not None:
+            # What making executables has cost this process so far.
+            stats["compiles"] = record.snapshot()
         engine = self.server.slo_engine
         if engine is not None:
             engine.evaluate()

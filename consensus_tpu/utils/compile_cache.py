@@ -22,9 +22,14 @@ def enable_compile_cache() -> str:
     return it.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
     reads it and no directory is configured here.  Otherwise the cache goes to
     :data:`DEFAULT_CACHE_DIR`, and the thresholds are lowered so that the
-    many small programs are kept as well as the few large ones."""
+    many small programs are kept as well as the few large ones.  It also
+    installs the process's compile record (``obs/backends.py``), before
+    anything compiles: every caller enables the cache first."""
     import jax
 
+    from consensus_tpu.obs.backends import install_compile_record
+
+    install_compile_record()
     # An executable is kept under its program AND the names its operations
     # carry (``jax.named_scope``, the jitted functions' names): JAX's
     # default leaves the names out of the key, so a cache that an older
